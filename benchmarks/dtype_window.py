@@ -42,13 +42,14 @@ from . import ir_parity
 
 # The headline configuration: star(3,2) on a 256^3 grid, one operand
 # resident, unpipelined window (pure ring arithmetic, no prefetch slabs).
-# The budget sits in the window where trapezoid-f32 depth 3 (255,616 B)
-# no longer fits but ring-bf16 depth 4 (254,912 B) still does — both
-# thresholds are exact outputs of the pure-arithmetic cost model, so the
-# gate is deterministic, not timing-dependent.
+# The budget sits in the window (525,000-548,000 B, every buffer charged
+# at its DMA-grain rounded size) where trapezoid-f32 depth 3 no longer
+# fits but ring-bf16 depth 4 still does — both thresholds are exact
+# outputs of the pure-arithmetic cost model, so the gate is
+# deterministic, not timing-dependent.
 SHAPE = (256, 256, 256)
 T = 4
-BUDGET = 255_300
+BUDGET = 536_000
 BF16_CHAIN = ["bfloat16", "bfloat16", "bfloat16", "float32"]
 
 # Same-dtype sweep for the depth table (pipelined f32, two operands).

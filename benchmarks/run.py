@@ -19,6 +19,9 @@ def main() -> None:
     argv = sys.argv[1:]
     quick = "--full" not in argv
     force_cpu_devices()
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if "--json" in argv:
         from . import quant_race
         from .common import gates_ok

@@ -73,7 +73,12 @@ from .isoperimetric import lower_bound_loads
 
 __all__ = [
     "TileChoice",
+    "DEFAULT_VMEM_BUDGET",
+    "TARGET_DEVICE_KIND",
+    "TARGET_VMEM_BYTES",
+    "VMEM_CAPACITY_BYTES",
     "WINDOW_KINDS",
+    "axis_grain",
     "candidate_tiles",
     "chain_flops",
     "chain_halo",
@@ -81,17 +86,48 @@ __all__ = [
     "fused_halo",
     "fused_stage_bytes",
     "halo_from_offsets",
+    "kernel_vmem_bytes",
     "stage_suffix_halos",
     "sublane_unit",
     "tile_traffic_bytes",
     "tile_vmem_bytes",
     "surface_to_volume",
     "select_tile",
+    "vmem_capacity_bytes",
+    "window_extents",
 ]
 
-VMEM_BYTES_V5E = 128 * 1024 * 1024  # v5e VMEM per core (target hardware)
+# VMEM per TensorCore, keyed by ``jax.Device.device_kind`` (Google Cloud
+# TPU documentation, "TPU v5e": 128 MiB of VMEM per core).  A device kind
+# missing here is an error, never a guess: add its row before running on
+# it.
+VMEM_CAPACITY_BYTES = {
+    "TPU v5 lite": 128 * 1024 * 1024,
+}
+# The chip the planner sizes for when no TPU is attached: interpret mode
+# on the CPU emulates this device's kernels.
+TARGET_DEVICE_KIND = "TPU v5 lite"
 LANE = 128
 SUBLANE = 8
+
+
+def vmem_capacity_bytes(device_kind: str) -> int:
+    """VMEM bytes of one core of ``device_kind`` (from the table above)."""
+    try:
+        return VMEM_CAPACITY_BYTES[str(device_kind)]
+    except KeyError:
+        raise ValueError(
+            f"no VMEM capacity known for device kind {device_kind!r}; "
+            f"known kinds: {sorted(VMEM_CAPACITY_BYTES)}"
+        ) from None
+
+
+TARGET_VMEM_BYTES = vmem_capacity_bytes(TARGET_DEVICE_KIND)
+# The planner's default budget: half of the target core's VMEM, leaving
+# the other half to the kernel's in-body temporaries and the compiler.
+DEFAULT_VMEM_BUDGET = TARGET_VMEM_BYTES // 2
+# Compiler-internal scratch on top of the modeled kernel footprint.
+KERNEL_VMEM_SLACK = 8 * 1024 * 1024
 
 # Staged-intermediate window layouts (DESIGN.md §14): the §8/§9 trapezoid
 # keeps stage j's full suffix-halo extent resident; the ring keeps only the
@@ -126,6 +162,41 @@ def sublane_unit(dtype_bytes: int) -> int:
     sublane multiples of 16 and int8 of 32 (f32 stays at 8).  The lane
     grain is always :data:`LANE`."""
     return SUBLANE * max(1, 4 // max(int(dtype_bytes), 1))
+
+
+def axis_grain(axis: int, d: int, dtype_bytes: int) -> int:
+    """Layout grain of one axis of a d-dim array: the chip tiles the last
+    two axes as (sublane, lane) = (:func:`sublane_unit`, :data:`LANE`);
+    leading axes are untiled (grain 1).  A DMA's offsets and extents must
+    be multiples of the grain on every axis."""
+    if axis == d - 1:
+        return LANE
+    if axis == d - 2:
+        return sublane_unit(dtype_bytes)
+    return 1
+
+
+def window_extents(
+    tile: Sequence[int],
+    halo: Sequence[tuple[int, int]],
+    dtype_bytes: int,
+    aligned: bool = True,
+) -> tuple[int, ...]:
+    """Per-dim extent of the VMEM window the sweep kernel DMAs for one
+    tile: ``tile + lo + hi``, rounded up to :func:`axis_grain` when
+    ``aligned`` (what the chip's DMA engine can move).  The logical window
+    sits at the window's origin; the round-up is trailing slack the taps
+    never read.  The kernel allocates exactly this and the planner
+    charges exactly this, so the two cannot diverge."""
+    d = len(tile)
+    ext = []
+    for i, (t, (lo, hi)) in enumerate(zip(tile, halo)):
+        e = int(t) + int(lo) + int(hi)
+        if aligned:
+            g = axis_grain(i, d, dtype_bytes)
+            e = -(-e // g) * g
+        ext.append(e)
+    return tuple(ext)
 
 
 def halo_from_offsets(
@@ -352,9 +423,12 @@ def tile_vmem_bytes(
     prefetch: bool = True,
     time_steps: int = 1,
     stage_halos: Sequence[Sequence[tuple[int, int]]] | None = None,
+    aligned: bool = False,
 ) -> int:
     """Per-operand VMEM footprint: the halo'd window, plus — when sweeping
     with prefetch — two landing slabs for the double-buffered next-tile DMA.
+    ``aligned`` charges the window as :func:`window_extents` rounds it for
+    the chip's DMA grain.
 
     With ``time_steps=T > 1`` the window (and slabs) carry the T×-grown
     halo; ``stage_halos`` carries a heterogeneous chain's summed halo
@@ -369,14 +443,11 @@ def tile_vmem_bytes(
         if stage_halos is not None
         else fused_halo(halo, time_steps)
     )
-    window = prod(t + lo + hi for t, (lo, hi) in zip(tile, full))
+    ext = window_extents(tile, full, dtype_bytes, aligned)
+    window = prod(ext)
     slabs = 0
     if sweep_axis is not None and prefetch:
-        cross = prod(
-            t + lo + hi
-            for i, (t, (lo, hi)) in enumerate(zip(tile, full))
-            if i != sweep_axis
-        )
+        cross = prod(e for i, e in enumerate(ext) if i != sweep_axis)
         slabs = 2 * tile[sweep_axis] * cross
     return (window + slabs) * dtype_bytes
 
@@ -390,6 +461,7 @@ def fused_stage_bytes(
     window_kind: str = "trapezoid",
     sweep_axis: int | None = None,
     stage_dtype_bytes: Sequence[int] | None = None,
+    aligned: bool = False,
 ) -> int:
     """Bytes of the T−1 staged intermediates, shared per launch.
 
@@ -407,7 +479,9 @@ def fused_stage_bytes(
     stream to renormalize along, so it prices the trapezoid.
 
     ``stage_dtype_bytes[j]`` sizes the frontier holding stage j's output
-    (0-indexed; default ``dtype_bytes`` for every stage)."""
+    (0-indexed; default ``dtype_bytes`` for every stage).  ``aligned``
+    charges each buffer at its allocated size: the chip pads the last two
+    axes to the frontier dtype's (sublane, lane) grain."""
     if window_kind not in WINDOW_KINDS:
         raise ValueError(
             f"window_kind {window_kind!r} not in {WINDOW_KINDS}"
@@ -426,8 +500,69 @@ def fused_stage_bytes(
             ext[s] = (
                 tile[s] + stage_halos[j][s][0] + stage_halos[j][s][1]
             )
-        total += int(stage_dtype_bytes[j - 1]) * prod(ext)
+        sdb = int(stage_dtype_bytes[j - 1])
+        ext = window_extents(ext, [(0, 0)] * len(ext), sdb, aligned)
+        total += sdb * prod(ext)
     return total
+
+
+def _allocated_bytes(shape: Sequence[int], dtype_bytes: int) -> int:
+    """Bytes of a VMEM buffer as allocated: the last two axes padded to
+    the dtype's (sublane, lane) grain."""
+    ext = window_extents(shape, [(0, 0)] * len(shape), dtype_bytes)
+    return prod(ext) * int(dtype_bytes)
+
+
+def kernel_vmem_bytes(
+    tile: Sequence[int],
+    halo: Sequence[tuple[int, int]],
+    dtype_bytes: int,
+    sweep_axis: int | None = None,
+    prefetch: bool = True,
+    n_inputs: int = 1,
+    time_steps: int = 1,
+    stage_halos: Sequence[Sequence[tuple[int, int]]] | None = None,
+    window_kind: str = "trapezoid",
+    stage_dtype_bytes: Sequence[int] | None = None,
+) -> int:
+    """Scoped VMEM one compiled launch of the sweep kernel holds, all as
+    allocated: each input's window and prefetch slabs, the staged
+    frontiers, the double-buffered output block, the body's f32 values —
+    the window cast to f32 (twice: Mosaic keeps relayout copies of
+    unaligned slices), and a tap slice, the accumulator, a mask and a
+    stored copy per stage extent — and the compiler's slack.
+
+    Calibrated against the v5e compiler's smallest accepted
+    ``vmem_limit_bytes`` for the 512^3 single application, the 256^3
+    fused f32 and bf16 rings, the 16384^2 Jacobi and the 4-shard 512^3
+    launches (6-64 MiB needed; this model gives more in every case).  The
+    launcher passes it as the limit, and the planner rejects tiles whose
+    figure exceeds the target core's VMEM, so a planned launch fits."""
+    s = 0 if sweep_axis is None else sweep_axis  # the kernel's grid order
+    if stage_halos is None:
+        stage_halos = [list(halo)] * max(int(time_steps), 1)
+    depth = len(stage_halos)
+    if stage_dtype_bytes is None:
+        stage_dtype_bytes = [dtype_bytes] * depth
+    window = window_extents(tile, chain_halo(stage_halos), dtype_bytes)
+    total = n_inputs * tile_vmem_bytes(
+        tile, halo, dtype_bytes, s, prefetch, stage_halos=stage_halos,
+        aligned=True,
+    )
+    if depth > 1:
+        total += fused_stage_bytes(
+            tile, halo, dtype_bytes, depth, stage_halos=stage_halos,
+            window_kind=window_kind, sweep_axis=s,
+            stage_dtype_bytes=stage_dtype_bytes, aligned=True,
+        )
+    total += 2 * _allocated_bytes(tile, stage_dtype_bytes[-1])
+    exts = [
+        [t + lo + hi for t, (lo, hi) in zip(tile, sfx)]
+        for sfx in stage_suffix_halos(stage_halos)
+    ]
+    total += 2 * _allocated_bytes(window, 4)
+    total += 4 * sum(_allocated_bytes(e, 4) for e in exts)
+    return total + KERNEL_VMEM_SLACK
 
 
 def chain_flops(
@@ -480,7 +615,7 @@ def select_tile(
     shape: Sequence[int],
     halo: Sequence[tuple[int, int]],
     dtype_bytes: int = 4,
-    vmem_budget: int = VMEM_BYTES_V5E // 2,
+    vmem_budget: int = DEFAULT_VMEM_BUDGET,
     n_operands: int = 2,
     sweep_axis: int | None | str = "auto",
     aligned: bool = True,
@@ -571,7 +706,7 @@ def select_tile(
         for tile in cands:
             vmem = tile_vmem_bytes(
                 tile, halo, dtype_bytes, axis, prefetch, time_steps,
-                stage_halos=stage_halos,
+                stage_halos=stage_halos, aligned=aligned,
             )
             if vmem > budget:
                 continue
@@ -585,9 +720,16 @@ def select_tile(
                     window_kind=window_kind,
                     sweep_axis=axis,
                     stage_dtype_bytes=stage_dtype_bytes,
+                    aligned=aligned,
                 )
                 if vmem * max(n_operands, 1) + stages > vmem_budget:
                     continue
+            if aligned and kernel_vmem_bytes(
+                tile, halo, dtype_bytes, axis, prefetch,
+                max(n_operands - 1, 1), time_steps, stage_halos,
+                window_kind, stage_dtype_bytes,
+            ) > TARGET_VMEM_BYTES:
+                continue  # the compiled kernel would not fit the core
             traffic = tile_traffic_bytes(
                 shape, tile, halo, dtype_bytes, axis, time_steps,
                 stage_halos=stage_halos,

@@ -1,38 +1,35 @@
-"""Backend-safety helpers shared by the Pallas kernel frontends.
+"""Backend resolution shared by the Pallas kernel frontends.
 
 The kernels in this package are written against ``pallas.tpu``: they
 compile through Mosaic on a TPU backend and run under the Pallas
-interpreter everywhere else.  The seed resolved ``interpret=None`` as
-``backend == "cpu"``, which left any *other* backend (gpu, rocm, plugin
-devices) with ``interpret=False`` and a crash deep inside Mosaic lowering.
-``resolve_interpret`` centralizes the decision: TPU compiles, everything
-else interprets.
+interpreter on the CPU, which is the test path.  ``resolve_interpret``
+makes that decision in one place.  Any other backend (gpu, rocm, plugin
+devices) is refused with an error: interpreting there would run the chip's
+kernels at host speed while the caller believes it is on an accelerator.
 
-An unsupported backend logs a WARNING the first time it is seen (DEBUG
-thereafter — a long-lived server must not drown in repeats, but also must
-not go silent after kernel #1, which is what the seed's once-per-process
-``warnings.warn`` did) and *always* records an ``interpret_fallback``
-obs counter + event, so every fallback is countable per kernel even when
-logging is filtered.
+A kernel that still runs interpreted on a non-CPU backend (an explicit
+``interpret=True`` on a TPU) records the ``interpret_fallback`` obs
+counter and event, so a measurement can assert that none of its launches
+left the chip's compiler.
+
+``checked_vmem_limit`` checks a compiled kernel's ``vmem_limit_bytes``
+against the attached device's VMEM from the capacity table in
+:mod:`repro.core.tiling`; an unknown device kind is an error there,
+never a default.
 """
 
 from __future__ import annotations
 
-import logging
-
 import jax
 
 from .. import obs
+from ..core.tiling import vmem_capacity_bytes
 
-__all__ = ["resolve_interpret"]
+__all__ = ["checked_vmem_limit", "device_kind", "resolve_interpret"]
 
-logger = logging.getLogger(__name__)
 
-# Backends the pltpu kernels handle natively: TPU compiles through Mosaic,
-# CPU is the documented interpret-mode CI path (no warning needed).
-_NATIVE = ("tpu", "cpu")
-
-_seen_backends: set[str] = set()
+class UnsupportedBackendError(RuntimeError):
+    """The active JAX backend can neither compile nor test the kernels."""
 
 
 def resolve_interpret(
@@ -40,37 +37,44 @@ def resolve_interpret(
 ) -> bool:
     """Resolve the ``interpret=None`` default against the active backend.
 
-    * explicit True/False is always honored (escape hatch);
     * TPU -> compiled kernels (``False``);
-    * CPU -> interpreter (``True``), the CI path;
-    * anything else (gpu, plugin backends) -> interpreter, logged at
-      WARNING on first sight of the backend (DEBUG after), and counted
-      via the ``interpret_fallback`` obs counter every single time.
+    * CPU -> interpreter (``True``), the test path;
+    * anything else -> :class:`UnsupportedBackendError`.
 
-    ``kernel`` names the calling frontend (``"stencil"``, ``"conv1d"``)
-    for the log line and the obs event.
+    An explicit True/False is honored on the TPU and the CPU.  ``kernel``
+    names the calling frontend (``"stencil"``, ``"conv1d"``) for the
+    error and the obs event.
     """
-    if interpret is not None:
-        return bool(interpret)
     backend = jax.default_backend()
-    if backend == "tpu":
-        return False
-    if backend not in _NATIVE:
-        level = (
-            logging.DEBUG if backend in _seen_backends else logging.WARNING
+    name = kernel or "<unnamed>"
+    if backend not in ("tpu", "cpu"):
+        raise UnsupportedBackendError(
+            f"backend {backend!r} cannot run the Pallas TPU kernel {name}: "
+            "it compiles only for a TPU, and interprets only on the CPU "
+            "(set JAX_PLATFORMS=cpu to test there)"
         )
-        _seen_backends.add(backend)
-        logger.log(
-            level,
-            "backend %r cannot compile Pallas TPU kernels; falling back to "
-            "interpret mode for kernel %s (correct but slow). Pass "
-            "interpret=False to force compilation anyway.",
-            backend, kernel or "<unnamed>",
-        )
+    resolved = (backend == "cpu") if interpret is None else bool(interpret)
+    if resolved and backend != "cpu":
         obs.add("interpret_fallback")
         if obs.enabled():
-            obs.event(
-                "interpret_fallback", backend=backend,
-                kernel=kernel or "<unnamed>",
-            )
-    return True
+            obs.event("interpret_fallback", backend=backend, kernel=name)
+    return resolved
+
+
+def device_kind() -> str:
+    """``device_kind`` of the first attached device."""
+    return jax.devices()[0].device_kind
+
+
+def checked_vmem_limit(need: int) -> int:
+    """``vmem_limit_bytes`` for a compiled kernel that needs ``need``
+    bytes (``core.tiling.kernel_vmem_bytes``): refused here, with its
+    size, when the attached core has less VMEM, rather than by the
+    compiler."""
+    cap = vmem_capacity_bytes(device_kind())
+    if need > cap:
+        raise ValueError(
+            f"kernel needs ~{need} B of VMEM, more than the {cap} B of a "
+            f"{device_kind()} core; plan a smaller tile"
+        )
+    return int(need)

@@ -35,19 +35,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._backend import resolve_interpret
+from ..core.tiling import kernel_vmem_bytes, window_extents
+from ._backend import checked_vmem_limit, resolve_interpret
 
 __all__ = ["causal_conv1d"]
 
 
+def _window_rows(tile_s, halo, channels, itemsize):
+    """Sequence rows of the VMEM window: ``tile_s + halo`` rounded up to
+    the sublane grain every DMA on the chip moves whole (the stencil
+    engine's ``window_extents``; the trailing slack is never read)."""
+    return window_extents(
+        (tile_s, channels), [(halo, 0), (0, 0)], itemsize
+    )[0]
+
+
 @functools.partial(jax.jit, static_argnames=("tile_s", "interpret"))
 def _conv_call(xp, conv_w, conv_b, tile_s, interpret):
-    """xp: (B, halo + padded S, C) — halo rows already prepended.  Sweeps
-    tiles of ``tile_s`` tokens with halo reuse + double-buffered prefetch."""
+    """xp: (B, halo + padded S + slack, C) — halo rows prepended, grain
+    slack appended (:func:`_prepend_halo`).  Sweeps tiles of ``tile_s``
+    tokens with halo reuse + double-buffered prefetch."""
     b, sp, c = xp.shape
     width = conv_w.shape[0]
     halo = width - 1
-    pad_s = sp - halo
+    rows = _window_rows(tile_s, halo, c, xp.dtype.itemsize)
+    keep = rows - tile_s  # rows each sweep step carries over
+    pad_s = sp - rows + tile_s
     nswp = pad_s // tile_s
     pipelined = nswp > 1 and halo > 0
 
@@ -61,14 +74,14 @@ def _conv_call(xp, conv_w, conv_b, tile_s, interpret):
 
         def slab_copy(kk, slot):
             return pltpu.make_async_copy(
-                x_hbm.at[i, pl.ds(kk * tile_s + halo, tile_s)],
+                x_hbm.at[i, pl.ds(kk * tile_s + keep, tile_s)],
                 slab.at[slot],
                 ssem.at[slot],
             )
 
         if not pipelined:
             cp = pltpu.make_async_copy(
-                x_hbm.at[i, pl.ds(k * tile_s, tile_s + halo)], win, wsem
+                x_hbm.at[i, pl.ds(k * tile_s, rows)], win, wsem
             )
             cp.start()
             cp.wait()
@@ -76,7 +89,7 @@ def _conv_call(xp, conv_w, conv_b, tile_s, interpret):
             @pl.when(k == 0)
             def _():
                 cp = pltpu.make_async_copy(
-                    x_hbm.at[i, pl.ds(0, tile_s + halo)], win, wsem
+                    x_hbm.at[i, pl.ds(0, rows)], win, wsem
                 )
                 cp.start()
                 slab_copy(1, 1 % 2).start()
@@ -84,13 +97,13 @@ def _conv_call(xp, conv_w, conv_b, tile_s, interpret):
 
             @pl.when(k > 0)
             def _():
-                win[0:halo, :] = win[tile_s : tile_s + halo, :]
+                win[0:keep, :] = win[tile_s:rows, :]
                 slab_copy(k, k % 2).wait()
 
                 @pl.when(k + 1 < nswp)
                 def _():
                     slab_copy(k + 1, (k + 1) % 2).start()
-                win[halo : halo + tile_s, :] = slab[k % 2]
+                win[keep:rows, :] = slab[k % 2]
 
         acc = jnp.zeros((tile_s, c), jnp.float32)
         for t in range(width):
@@ -98,42 +111,57 @@ def _conv_call(xp, conv_w, conv_b, tile_s, interpret):
         acc = acc + b_ref[...]
         o_ref[...] = jax.nn.silu(acc).astype(o_ref.dtype)[None]
 
-    scratch = [pltpu.VMEM((tile_s + halo, c), xp.dtype)]
+    scratch = [pltpu.VMEM((rows, c), xp.dtype)]
     if pipelined:
         scratch.append(pltpu.VMEM((2, tile_s, c), xp.dtype))
     scratch.append(pltpu.SemaphoreType.DMA)
     if pipelined:
         scratch.append(pltpu.SemaphoreType.DMA((2,)))
 
+    # The stencil engine's kernel model: a (tile_s, C) tile with halo
+    # (W-1, 0) on the swept sequence axis (the weight and bias blocks fit
+    # in its slack).
+    vmem_limit = None if interpret else checked_vmem_limit(
+        kernel_vmem_bytes(
+            (tile_s, c), [(halo, 0), (0, 0)], xp.dtype.itemsize, 0,
+            pipelined,
+        )
+    )
     out = pl.pallas_call(
         body,
         grid=(b, nswp),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((width, c), lambda i, k: (0, 0)),
             pl.BlockSpec((c,), lambda i, k: (0,)),
         ],
         out_specs=pl.BlockSpec((1, tile_s, c), lambda i, k: (i, k, 0)),
         out_shape=jax.ShapeDtypeStruct((b, pad_s, c), xp.dtype),
         scratch_shapes=scratch,
+        compiler_params=(
+            None if interpret
+            else pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)
+        ),
         interpret=interpret,
     )(xp, conv_w, conv_b)
     return out
 
 
 def _prepend_halo(x, conv_w, state, tile_s):
-    """Concat the W-1 halo (zeros or the previous tail) and round S up."""
+    """Concat the W-1 halo (zeros or the previous tail), round S up to
+    whole tiles and append the last window's grain slack."""
     b, s, c = x.shape
     width = conv_w.shape[0]
     halo = width - 1
     tile_s = min(tile_s, s)
     pad_s = -(-s // tile_s) * tile_s
+    slack = _window_rows(tile_s, halo, c, x.dtype.itemsize) - tile_s - halo
     if state is None:
         head = jnp.zeros((b, halo, c), x.dtype)
     else:
         head = state.astype(x.dtype)
     xp = jnp.concatenate(
-        [head, x, jnp.zeros((b, pad_s - s, c), x.dtype)], axis=1
+        [head, x, jnp.zeros((b, pad_s - s + slack, c), x.dtype)], axis=1
     )
     return xp, tile_s
 

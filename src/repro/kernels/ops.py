@@ -17,7 +17,7 @@ import numpy as np
 
 from repro.core.tiling import (
     TileChoice,
-    VMEM_BYTES_V5E,
+    DEFAULT_VMEM_BUDGET,
     select_tile,
 )
 
@@ -41,7 +41,7 @@ def plan_tiles(
     r: int,
     dtype_bytes: int = 4,
     n_operands: int = 2,
-    vmem_budget: int = VMEM_BYTES_V5E // 2,
+    vmem_budget: int = DEFAULT_VMEM_BUDGET,
     sweep_axis: int | None | str = "auto",
 ) -> TileChoice:
     """Expose the cache-fitting tile decision (for logging / benchmarks)."""
@@ -56,7 +56,7 @@ def traffic_report(
     shape: Sequence[int],
     r: int,
     dtype_bytes: int = 4,
-    vmem_budget: int = VMEM_BYTES_V5E // 2,
+    vmem_budget: int = DEFAULT_VMEM_BUDGET,
     n_operands: int = 2,
     aligned: bool = True,
 ) -> dict:

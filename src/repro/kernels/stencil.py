@@ -55,9 +55,24 @@ stage written back — at the stage dtype, while every stage still
 accumulates in f32.  A bf16 input window halves the streamed bytes (and
 the dtype-aware planner doubles the sublane grain to match).
 
-Boundary semantics match ``kernels.ref.stencil_ref``: zero fill, via a
-host-side ``jnp.pad`` that also rounds each extent up to the tile (grids
-not divisible by the tile take this round-up path).
+Boundary semantics match ``kernels.ref.stencil_ref``: the launch embeds
+each input in a zero-filled buffer (``launch_pads``/``embed_inputs``)
+with the window's low halo in front and, behind, the high halo plus the
+round-up to whole tiles (grids not divisible by the tile take this path).
+§13 boundary ops other than zero fill become in-kernel correction taps;
+only §15 periodic wrap fills ghost cells in the buffer.
+
+**On the chip** (Mosaic): every DMA moves whole (sublane, lane) grains —
+8/16/32 sublanes for 4/2/1-byte dtypes, 128 lanes — at offsets that are
+grain multiples.  So the VMEM window is the halo'd tile rounded up to the
+grain on the last two axes (``core.tiling.window_extents``, the same
+extents the planner charges), the logical window sits at its origin, and
+the launch buffer carries the trailing slack the last window reads; the
+taps never read the slack.  A tile off the grain with more than one tile
+along that axis is refused before compiling.  Each launch passes a
+scoped-VMEM limit derived from its buffers, the double-buffered output
+block and the body's f32 values, capped by the device's VMEM
+(``core.tiling.VMEM_CAPACITY_BYTES``).
 
 **Multi-core sharding** (DESIGN.md §10): sweep columns are independent
 even with frontier state (each column warms its own rings at ``k == 0``),
@@ -84,15 +99,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.tiling import (  # shared with the planner
+    axis_grain,
     chain_halo,
     dtype_itemsize,
     fused_stage_bytes,
     halo_from_offsets,
+    kernel_vmem_bytes,
     stage_suffix_halos,
+    window_extents,
 )
 
 from .. import ir, obs
-from ._backend import resolve_interpret
+from ._backend import checked_vmem_limit, resolve_interpret
 
 if TYPE_CHECKING:
     from repro.plan import StencilPlan
@@ -199,15 +217,21 @@ def _sweep_kernel(
     t_s = tile[sweep]
     h_s = lo_w[sweep] + hi_w[sweep]  # total sweep-axis window halo
     reuse = h_s > 0 and nswp > 1
+    # The window as allocated: the logical halo'd tile at the origin plus
+    # trailing slack up to the DMA grain (``window_extents``).  Every DMA
+    # moves whole grains, so its offsets are tile multiples and its
+    # extents these rounded ones; each sweep step keeps ``keep`` rows and
+    # lands the next ``t_s`` behind them.
+    win_ext = tuple(windows[0].shape)
+    w_s = win_ext[sweep]
+    keep = w_s - t_s
 
     def src_index(kk, start, size):
         """HBM index tuple for rows [kk*t_s+start, +size) of the sweep axis
-        and the full halo'd cross extents of the current tile."""
+        and the full window cross extents of the current tile."""
         idx = [None] * d
         for j, i in enumerate(cross_axes):
-            idx[i] = pl.ds(
-                gids[j] * tile[i], tile[i] + lo_w[i] + hi_w[i]
-            )
+            idx[i] = pl.ds(gids[j] * tile[i], win_ext[i])
         idx[sweep] = pl.ds(kk * t_s + start, size)
         return tuple(idx)
 
@@ -219,7 +243,7 @@ def _sweep_kernel(
     def window_load(kk):
         copies = [
             pltpu.make_async_copy(
-                x_hbm[a].at[src_index(kk, 0, t_s + h_s)],
+                x_hbm[a].at[src_index(kk, 0, w_s)],
                 windows[a],
                 win_sem.at[a],
             )
@@ -231,7 +255,7 @@ def _sweep_kernel(
 
     def slab_copy(a, kk, slot):
         return pltpu.make_async_copy(
-            x_hbm[a].at[src_index(kk, h_s, t_s)],
+            x_hbm[a].at[src_index(kk, keep, t_s)],
             slabs[a].at[slot],
             slab_sem.at[a, slot],
         )
@@ -253,11 +277,12 @@ def _sweep_kernel(
 
         @pl.when(k > 0)
         def _():
-            # Scanning-face reuse: the trailing h_s rows of the previous
-            # window become the leading halo of this one — a VMEM-internal
-            # shift, no HBM traffic.
+            # Scanning-face reuse: the trailing ``keep`` rows of the
+            # previous window (its h_s overlap plus any grain slack) become
+            # the leading rows of this one — a VMEM-internal shift, no HBM
+            # traffic.
             for a in range(p):
-                windows[a][win_part(0, h_s)] = windows[a][win_part(t_s, h_s)]
+                windows[a][win_part(0, keep)] = windows[a][win_part(t_s, keep)]
             if pipelined:
                 for a in range(p):
                     slab_copy(a, k, k % 2).wait()
@@ -267,12 +292,12 @@ def _sweep_kernel(
                     for a in range(p):
                         slab_copy(a, k + 1, (k + 1) % 2).start()
                 for a in range(p):
-                    windows[a][win_part(h_s, t_s)] = slabs[a][k % 2]
+                    windows[a][win_part(keep, t_s)] = slabs[a][k % 2]
             else:
                 copies = [
                     pltpu.make_async_copy(
-                        x_hbm[a].at[src_index(k, h_s, t_s)],
-                        windows[a].at[win_part(h_s, t_s)],
+                        x_hbm[a].at[src_index(k, keep, t_s)],
+                        windows[a].at[win_part(keep, t_s)],
                         win_sem.at[a],
                     )
                     for a in range(p)
@@ -651,15 +676,19 @@ def _padded_call(ins, dom, offsets, weights, stages, lo_w, hi_w, tile,
     p = len(ins)
     T = 1 if stages is None else len(stages)
     u0 = ins[0]
-    ntiles = tuple(
-        (u0.shape[i] - lo_w[i] - hi_w[i]) // tile[i] for i in range(d)
+    window_shape = window_extents(
+        tile, list(zip(lo_w, hi_w)), u0.dtype.itemsize
     )
+    ntiles = tuple(
+        (u0.shape[i] - window_shape[i]) // tile[i] + 1 for i in range(d)
+    )
+    if not interpret:
+        _check_dma_grain(tile, ntiles, u0.dtype.itemsize)
     nswp = ntiles[sweep]
     cross_axes = [i for i in range(d) if i != sweep]
     grid = tuple(ntiles[i] for i in cross_axes) + (nswp,)
     pipelined = bool(pipelined) and nswp > 1 and (lo_w[sweep] + hi_w[sweep]) > 0
 
-    window_shape = tuple(t + l + h for t, l, h in zip(tile, lo_w, hi_w))
     slab_shape = tuple(
         tile[sweep] if i == sweep else window_shape[i] for i in range(d)
     )
@@ -702,14 +731,68 @@ def _padded_call(ins, dom, offsets, weights, stages, lo_w, hi_w, tile,
         ),
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
-        + [pl.BlockSpec(memory_space=pltpu.ANY) for _ in ins],
+        + [pl.BlockSpec(memory_space=pl.ANY) for _ in ins],
         out_specs=pl.BlockSpec(tile, out_index_map),
         out_shape=jax.ShapeDtypeStruct(
             tuple(k * t for k, t in zip(ntiles, tile)), out_dtype
         ),
         scratch_shapes=scratch,
+        compiler_params=(
+            None if interpret else pltpu.CompilerParams(
+                vmem_limit_bytes=_vmem_limit(
+                    tile, lo_w, hi_w, u0.dtype.itemsize, sweep, pipelined,
+                    p, stages, window_kind,
+                )
+            )
+        ),
         interpret=interpret,
     )(dom, *ins)
+
+
+def _vmem_limit(tile, lo_w, hi_w, itemsize, sweep, pipelined, p, stages,
+                window_kind):
+    """The launch's scoped-VMEM limit: the planner's own kernel model
+    (``core.tiling.kernel_vmem_bytes``) at this launch's geometry."""
+    halo = list(zip(lo_w, hi_w))
+    stage_halos = stage_dbs = None
+    if stages is not None:
+        stage_halos = [list(zip(st.lo, st.hi)) for st in stages]
+        stage_dbs = [
+            jnp.dtype(st.dtype).itemsize if st.dtype else itemsize
+            for st in stages
+        ]
+    return checked_vmem_limit(kernel_vmem_bytes(
+        tile, halo, itemsize, sweep, pipelined, p,
+        stage_halos=stage_halos, window_kind=window_kind,
+        stage_dtype_bytes=stage_dbs,
+    ))
+
+
+def _check_dma_grain(tile, ntiles, itemsize):
+    """Refuse, before compiling, a tile the chip's DMA cannot address:
+    with more than one tile along an axis, the tile offsets must be
+    multiples of that axis's (sublane, lane) grain."""
+    d = len(tile)
+    for i, (t, n) in enumerate(zip(tile, ntiles)):
+        g = axis_grain(i, d, itemsize)
+        if n > 1 and t % g:
+            raise ValueError(
+                f"tile {tuple(tile)} is not a multiple of the {g}-element "
+                f"grain on axis {i} ({n} tiles along it): the chip's DMA "
+                "cannot address it; use an aligned tile or interpret mode"
+            )
+
+
+def launch_pads(shape, tile, lo_w, hi_w, itemsize):
+    """Per-dim ``(lo, hi)`` extension of an array into its launch buffer:
+    the window's low halo in front, and behind the content enough for
+    the last tile's whole DMA window (``window_extents``) — the high halo,
+    the round-up to whole tiles and the grain slack."""
+    ext = window_extents(tile, list(zip(lo_w, hi_w)), itemsize)
+    return [
+        (lo, _round_up(int(n), t) - t + e - lo - int(n))
+        for n, t, lo, e in zip(shape, tile, lo_w, ext)
+    ]
 
 
 def embed_inputs(us, pads, pad_free=False, wrap=None, fill=0):
@@ -806,12 +889,7 @@ def _stencil_call(us, offsets_w, tile, sweep, pipelined, interpret,
     offsets, weights, stages, lo_w, hi_w = _launch_geometry(
         offsets_w, stages_w, tile, bcs_w, dtypes_w, quants_w
     )
-    padded_shape = tuple(_round_up(n, t) for n, t in zip(u0.shape, tile))
-    # lo halo on the low side, hi + round-up slack on the high.
-    pads = [
-        (l, h + ps - n)
-        for l, h, ps, n in zip(lo_w, hi_w, padded_shape, u0.shape)
-    ]
+    pads = launch_pads(u0.shape, tile, lo_w, hi_w, u0.dtype.itemsize)
     periodic = bcs_w is not None and any(
         bc is not None and bc[0] == "periodic" for bc in bcs_w
     )

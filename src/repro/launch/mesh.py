@@ -19,6 +19,7 @@ the dry-run sees 512 placeholder devices).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -39,7 +40,9 @@ def make_production_mesh(*, multi_pod: bool = False):
             "dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before any jax import"
         )
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return jax.make_mesh(
+        shape, axes, devices=devs[:n], axis_types=_auto(len(axes))
+    )
 
 
 def make_column_mesh(num_shards: int, axis_name: str = "columns",
@@ -62,12 +65,22 @@ def make_column_mesh(num_shards: int, axis_name: str = "columns",
             f"{num_shards} before any jax import"
         )
     return jax.make_mesh((num_shards,), (axis_name,),
-                         devices=devs[:num_shards])
+                         devices=devs[:num_shards], axis_types=_auto(1))
 
 
 def make_test_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over however many (CPU) devices exist — for unit tests."""
-    n = len(jax.devices())
-    data = min(data, n)
-    model = min(model, n // data)
-    return jax.make_mesh((data, model), ("data", "model"))
+    devs = jax.devices()
+    data = min(data, len(devs))
+    model = min(model, len(devs) // data)
+    return jax.make_mesh(
+        (data, model), ("data", "model"), devices=devs[: data * model],
+        axis_types=_auto(2),
+    )
+
+
+def _auto(n: int) -> tuple:
+    """Auto axis types: shardings are propagated by the compiler and the
+    logical-axis constraints, as every mesh consumer here expects (JAX
+    makes Explicit axes by default)."""
+    return (AxisType.Auto,) * n

@@ -47,8 +47,7 @@ from math import prod
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import obs
 
@@ -190,14 +189,15 @@ def _build_sharded(mesh, a, tile, sweep, pipelined, interpret, offsets_w,
     configuration — meshes and the offset/stage/boundary specs are
     hashable, so repeated shapes re-enter the compiled function
     directly."""
+    from repro.core.tiling import window_extents
     from repro.kernels.stencil import (
         _launch_geometry,
         _padded_call,
-        _round_up,
         embed_inputs,
+        launch_pads,
     )
 
-    del dtype  # part of the cache key only (shapes close over `pads`)
+    itemsize = jnp.dtype(dtype).itemsize
     d = len(shape)
     axis_name = mesh.axis_names[0]
     S = int(mesh.shape[axis_name])
@@ -214,15 +214,17 @@ def _build_sharded(mesh, a, tile, sweep, pipelined, interpret, offsets_w,
     # trimmed, like the single-device pad path.
     k = max(-(-ncols // S), -(-lo_a // t_a), -(-hi_a // t_a), 1)
     C = k * t_a
-    padded = [_round_up(n, t) for n, t in zip(shape, tile)]
-    padded[a] = S * C
-    # Host pad: window halo on every dim except the shard axis, whose
-    # boundary rows come from the exchange (or its zero fill at the ends).
-    pads = [
-        (0, padded[i] - shape[i]) if i == a
-        else (lo_w[i], hi_w[i] + padded[i] - shape[i])
-        for i in range(d)
-    ]
+    # Host pad: the launch pads on every dim except the shard axis, whose
+    # boundary rows come from the exchange (or its zero fill at the ends);
+    # it only rounds up to whole shards.
+    pads = launch_pads(shape, tile, lo_w, hi_w, itemsize)
+    pads[a] = (0, S * C - shape[a])
+    # Behind the received hi band, each local slab needs the DMA grain's
+    # slack for its last window (``window_extents``).
+    slack_a = (
+        window_extents(tile, list(zip(lo_w, hi_w)), itemsize)[a]
+        - t_a - lo_a - hi_a
+    )
     # Periodic wrap (§15): the ghost fill on non-shard axes happens in
     # the embed below; on the shard axis the exchange ring closes —
     # extra ppermute links (S−1 → 0 forward, 0 → S−1 backward) carry the
@@ -284,6 +286,10 @@ def _build_sharded(mesh, a, tile, sweep, pipelined, interpret, offsets_w,
                 parts.append(
                     jnp.zeros_like(recv_hi) if ragged else recv_hi
                 )
+            if slack_a:
+                ext = list(b.shape)
+                ext[a] = slack_a
+                parts.append(jnp.zeros(ext, b.dtype))
             loc = jnp.concatenate(parts, axis=a) if len(parts) > 1 else b
             if ragged and hi_a:
                 pos = [0] * d
@@ -302,9 +308,9 @@ def _build_sharded(mesh, a, tile, sweep, pipelined, interpret, offsets_w,
         )
 
     spec = P(*[axis_name if i == a else None for i in range(d)])
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_fn, mesh=mesh, in_specs=(spec,) * p, out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
 
     pad_free = bcs_w is not None and any(bc is not None for bc in bcs_w)
@@ -316,9 +322,14 @@ def _build_sharded(mesh, a, tile, sweep, pipelined, interpret, offsets_w,
     )
     fill = int(in_quant[1]) if in_quant is not None else 0
 
+    sharding = NamedSharding(mesh, spec)
+
     def run(*arrays):
         ins = embed_inputs(arrays, pads, pad_free=pad_free, wrap=wrap,
                            fill=fill)
+        # Build the launch buffers shard by shard, each on its own device,
+        # rather than whole on one device before the split.
+        ins = [jax.lax.with_sharding_constraint(x, sharding) for x in ins]
         out = sharded(*ins)
         return out[tuple(slice(0, n) for n in shape)]
 

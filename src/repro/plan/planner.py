@@ -63,12 +63,14 @@ from repro.core.lattice import (
 from repro.core.padding import hyperbola_index, pad_grid
 from repro.core.tiling import (
     LANE,
+    TARGET_VMEM_BYTES,
     TileChoice,
     chain_flops,
     chain_halo,
     dtype_itemsize,
     fused_stage_bytes,
     halo_from_offsets,
+    kernel_vmem_bytes,
     select_tile,
     sublane_unit,
     tile_traffic_bytes,
@@ -117,7 +119,9 @@ def _fit_to_budget(tile, shape, halo, dtype_bytes, budget, aligned):
     d = len(tile)
     sub = sublane_unit(dtype_bytes)
     for _ in range(64):
-        if tile_vmem_bytes(tile, halo, dtype_bytes, None, False) <= budget:
+        if tile_vmem_bytes(
+            tile, halo, dtype_bytes, None, False, aligned=aligned
+        ) <= budget:
             return tuple(tile)
         i = max(range(d), key=lambda j: tile[j])
         if tile[i] <= 1:
@@ -601,7 +605,7 @@ class Planner:
                 launch = stage_halos[i : i + depth]
                 vmem = tile_vmem_bytes(
                     c.tile, halo, db, c.sweep_axis, request.pipelined,
-                    stage_halos=launch,
+                    stage_halos=launch, aligned=request.aligned,
                 )
                 if vmem > per_op_budget:
                     return None
@@ -613,9 +617,19 @@ class Planner:
                         stage_dtype_bytes=(
                             stage_dbs[i : i + depth] if stage_dbs else None
                         ),
+                        aligned=request.aligned,
                     )
                     if vmem * n_ops + staged > request.vmem_budget:
                         return None
+                if request.aligned and kernel_vmem_bytes(
+                    c.tile, halo, db, c.sweep_axis, request.pipelined,
+                    max(n_ops - 1, 1), stage_halos=launch,
+                    window_kind=window_kind or chosen["wk"],
+                    stage_dtype_bytes=(
+                        stage_dbs[i : i + depth] if stage_dbs else None
+                    ),
+                ) > TARGET_VMEM_BYTES:
+                    return None
                 traffic += tile_traffic_bytes(
                     work, c.tile, halo, db, c.sweep_axis, stage_halos=launch,
                 )

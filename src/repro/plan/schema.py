@@ -22,6 +22,8 @@ from typing import Sequence
 
 import numpy as np
 
+from repro.core.tiling import DEFAULT_VMEM_BUDGET
+
 __all__ = [
     "PLANNER_VERSION",
     "PlanMismatchError",
@@ -33,7 +35,13 @@ __all__ = [
     "validate_plan_call",
 ]
 
-# v7: the quantized compute path (DESIGN.md §15) — stage dtypes now
+# v8: windows are charged as the chip's DMA moves them — every aligned
+# request prices each window, slab and frontier at its (sublane, lane)
+# grain-rounded extent (``core.tiling.window_extents``) and rejects tiles
+# whose compiled kernel, f32 body values included, would not fit the
+# target core's VMEM (``core.tiling.kernel_vmem_bytes``); both change
+# which tiles fit, and v7 plans were priced on bare extents.
+# (v7: the quantized compute path (DESIGN.md §15) — stage dtypes now
 # include int8 (``StageSpec.dtype="int8"``: 1-byte frontiers/handoffs,
 # f32 MACs), and the §15 boundary menu grew periodic and robin kinds,
 # both of which reach request ``bcs`` and change the lowered launch.
@@ -44,7 +52,7 @@ __all__ = [
 # measured winners are invalidated wholesale rather than mis-compared.
 # Stage dtypes that restate the chain input's dtype None-normalize at
 # ``PlanRequest.make`` (an f32 chain spelled ["bf16", "f32"] keys the
-# same as ["bf16", None]), matching the launch's derivation.
+# same as ["bf16", None]), matching the launch's derivation.)
 # (v6: ring windows + mixed precision (DESIGN.md §14) — every request
 # carried ``window_kind`` (``auto``/``ring``/``trapezoid``: how staged
 # frontiers are sized) and every :class:`StageSpec` an optional output
@@ -64,15 +72,11 @@ __all__ = [
 # flop fields plus the per-depth score table.)
 # (v2: temporal blocking — ``time_steps`` joined the request and the plan
 # gained ``fused_depth``/``single_pass_traffic_bytes``.)
-PLANNER_VERSION = 7
+PLANNER_VERSION = 8
 
 # Frontier window layouts a request may ask for (DESIGN.md §14); "auto"
 # lets the planner race both and keep the modeled winner.
 _WINDOW_KINDS = ("auto", "ring", "trapezoid")
-
-# Default VMEM budget mirrors core.tiling (import-free to keep this module
-# pure data): half of a v5e core's VMEM.
-_DEFAULT_VMEM_BUDGET = (128 * 1024 * 1024) // 2
 
 
 def _int_tuple(xs) -> tuple[int, ...]:
@@ -260,7 +264,7 @@ class PlanRequest:
     shape: tuple[int, ...]
     offsets: tuple[tuple[tuple[int, ...], ...], ...]
     dtype_bytes: int = 4
-    vmem_budget: int = _DEFAULT_VMEM_BUDGET
+    vmem_budget: int = DEFAULT_VMEM_BUDGET
     n_operands: int = 2
     geometry: tuple[int, int, int] | None = None
     aligned: bool = True
@@ -406,7 +410,7 @@ class PlanRequest:
                 a, z, w = geometry
                 vmem_budget = a * z * w * int(dtype_bytes)  # S words
             else:
-                vmem_budget = _DEFAULT_VMEM_BUDGET
+                vmem_budget = DEFAULT_VMEM_BUDGET
         norm_bcs = _bcs_tuple(bcs, len(specs))
         if norm_bcs and not specs:
             raise ValueError(

@@ -141,7 +141,7 @@ def test_ring_depth_beyond_trapezoid_budget(planner):
     on top; that gate is ``test_mixed_precision_plan_beats_f32_depth``.)"""
     shape = (64, 48, 128)
     offs = star_stencil(3, 1)
-    budget = 250_000
+    budget = 360_000  # windows charged at their DMA-grain rounded size
     kw = dict(shape=shape, offsets=offs, time_steps=6, vmem_budget=budget,
               n_operands=1, aligned=True)
     trap = planner.plan(window_kind="trapezoid", **kw)
@@ -471,10 +471,12 @@ def test_single_step_plans_have_no_frontier(planner):
 def test_mixed_precision_plan_beats_f32_depth(planner):
     """bf16 windows double the legal lane grain: at a budget that caps
     the f32 trapezoid at depth 2, the bf16 ring chain reaches depth 4
-    (the BENCH_PR9 headline, pinned as a test)."""
+    (the BENCH_PR9 headline, pinned as a test).  The budget sits inside
+    the 525,000-548,000 B window where that holds with every buffer
+    charged at its DMA-grain rounded size."""
     offs = star_stencil(3, 2)
     kw = dict(shape=(256, 256, 256), offsets=offs, time_steps=4,
-              vmem_budget=255_300, n_operands=1, pipelined=False,
+              vmem_budget=536_000, n_operands=1, pipelined=False,
               aligned=True)
     trap = planner.plan(window_kind="trapezoid", **kw)
     ring = planner.plan(

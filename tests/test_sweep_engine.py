@@ -14,11 +14,15 @@ import pytest
 
 from repro.core.cache_fitting import star_stencil
 from repro.core.tiling import (
-    select_tile, surface_to_volume, tile_traffic_bytes, tile_vmem_bytes,
+    TARGET_VMEM_BYTES, axis_grain, kernel_vmem_bytes, select_tile,
+    surface_to_volume, tile_traffic_bytes, tile_vmem_bytes,
+    vmem_capacity_bytes, window_extents,
 )
 from repro.kernels.ops import apply_stencil, traffic_report
 from repro.kernels.ref import stencil_ref
-from repro.kernels.stencil import halo_from_offsets, multi_stencil_pallas
+from repro.kernels.stencil import (
+    halo_from_offsets, launch_pads, multi_stencil_pallas,
+)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -194,3 +198,82 @@ def test_traffic_report_ratio():
                          aligned=False)
     assert rep["traffic_ratio"] >= 1.5  # the PR's acceptance floor
     assert rep["sweep_reuse"]["traffic_bytes"] >= rep["lower_bound_bytes"]
+
+
+# ---------------------------------------------------------------------------
+# The chip's DMA grain and VMEM (DESIGN.md §16).
+# ---------------------------------------------------------------------------
+
+def test_window_extents_round_last_two_axes_to_dtype_grain():
+    halo = [(2, 2)] * 3
+    assert window_extents((1, 256, 256), halo, 4) == (5, 264, 384)
+    assert window_extents((1, 256, 256), halo, 2) == (5, 272, 384)
+    assert window_extents((1, 256, 256), halo, 1) == (5, 288, 384)
+    assert window_extents((1, 256, 256), halo, 4, aligned=False) == (
+        5, 260, 260)
+    assert [axis_grain(i, 3, 2) for i in range(3)] == [1, 16, 128]
+
+
+@pytest.mark.parametrize("shape,tile,halo,itemsize", [
+    ((512, 512, 512), (1, 512, 512), [(2, 2)] * 3, 4),
+    ((33, 129), (8, 64), [(2, 2), (2, 2)], 4),
+    ((70,), (16,), [(3, 0)], 2),
+    ((21, 45), (6, 17), [(1, 1), (1, 1)], 1),
+])
+def test_launch_pads_hold_every_grain_rounded_window(
+    shape, tile, halo, itemsize
+):
+    """The launch buffer holds the logical window at each tile's origin
+    and the whole grain-rounded DMA window of the last tile."""
+    lo_w = [lo for lo, _ in halo]
+    hi_w = [hi for _, hi in halo]
+    pads = launch_pads(shape, tile, lo_w, hi_w, itemsize)
+    ext = window_extents(tile, halo, itemsize)
+    for n, t, (lo, hi), e, (p_lo, p_hi) in zip(shape, tile, halo, ext,
+                                               pads):
+        ntiles = -(-n // t)
+        assert p_lo == lo
+        assert n + p_lo + p_hi == (ntiles - 1) * t + e
+        assert e >= t + lo + hi
+
+
+def test_vmem_capacity_table_refuses_unknown_kinds():
+    assert vmem_capacity_bytes("TPU v5 lite") == 128 * 1024 * 1024
+    with pytest.raises(ValueError, match="no VMEM capacity"):
+        vmem_capacity_bytes("TPU v99")
+
+
+def test_launch_over_device_vmem_refused(monkeypatch):
+    from repro.kernels import _backend
+
+    monkeypatch.setattr(_backend, "device_kind", lambda: "TPU v5 lite")
+    assert _backend.checked_vmem_limit(1 << 20) == 1 << 20
+    with pytest.raises(ValueError, match="more than the"):
+        _backend.checked_vmem_limit(TARGET_VMEM_BYTES + 1)
+
+
+@pytest.mark.parametrize("kw", [
+    dict(shape=(512, 512, 512), time_steps=4, dtype_bytes=2,
+         dtypes=["bfloat16"] * 3 + ["float32"]),
+    dict(shape=(256, 1024, 1024), time_steps=4),
+])
+def test_default_plans_fit_the_target_core(kw):
+    """With the kernel's f32 body values charged, a default-budget plan
+    never needs more VMEM than the chip has (these two did before)."""
+    from repro.kernels.ref import star_weights_2nd_order
+    from repro.plan import PlanCache, Planner
+
+    offs, _ = star_weights_2nd_order(3, 2)
+    plan = Planner(cache=PlanCache(persistent=False)).plan(
+        offsets=offs, **kw)
+    halo = halo_from_offsets([offs], 3)
+    depth = plan.fused_depth
+    sdb = [2] * 3 + [4] if "dtypes" in kw else None
+    need = kernel_vmem_bytes(
+        plan.tile, halo, kw.get("dtype_bytes", 4), plan.sweep_axis,
+        prefetch=True, stage_halos=[halo] * depth,
+        window_kind=plan.window_kind,
+        stage_dtype_bytes=sdb[:depth] if sdb else None,
+    )
+    assert depth >= 2
+    assert need <= TARGET_VMEM_BYTES

@@ -308,54 +308,44 @@ def test_matching_plan_accepted(planner):
         np.asarray(out), np.asarray(stencil_ref(u, offs, w)), atol=1e-5)
 
 
-def test_unsupported_backend_falls_back_to_interpret(monkeypatch, caplog):
-    """A non-TPU, non-CPU backend must interpret (logged WARNING on first
-    sight, DEBUG after), not crash inside Mosaic lowering."""
-    import logging
-
+def test_unsupported_backend_falls_back_to_interpret(monkeypatch):
+    """A non-TPU, non-CPU backend no longer falls back to interpret mode:
+    resolution refuses it, for the default and for explicit values
+    alike.  The CPU keeps the interpreter (the test path) and honors
+    explicit values."""
     from repro.kernels import _backend
 
     monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
-    monkeypatch.setattr(_backend, "_seen_backends", set())
-    with caplog.at_level(logging.DEBUG, logger=_backend.logger.name):
-        assert _backend.resolve_interpret(None) is True
-        assert _backend.resolve_interpret(None) is True
-    fallbacks = [
-        r for r in caplog.records if "interpret mode" in r.getMessage()
-    ]
-    assert len(fallbacks) == 2  # every fallback is reported...
-    assert fallbacks[0].levelno == logging.WARNING  # ...loudly once
-    assert fallbacks[1].levelno == logging.DEBUG    # ...quietly after
-    # explicit values are always honored, no log line
+    for interpret in (None, True, False):
+        with pytest.raises(_backend.UnsupportedBackendError, match="gpu"):
+            _backend.resolve_interpret(interpret)
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert _backend.resolve_interpret(None) is True
     assert _backend.resolve_interpret(False) is False
     assert _backend.resolve_interpret(True) is True
 
 
 def test_unsupported_backend_kernel_end_to_end(monkeypatch):
-    """The full kernel path on a 'gpu' backend: interpret fallback keeps
-    the numerics (the interpreter runs on the host regardless)."""
+    """The full kernel path on a 'gpu' backend raises the backend error
+    before any launch, naming the kernel."""
     from repro.kernels import _backend
 
     monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
-    monkeypatch.setattr(_backend, "_seen_backends", set())
     offs = star_stencil(2, 1)
     w = [0.1, 0.2, 0.3, 0.4, -0.5]
     u = jax.random.normal(KEY, (24, 32), jnp.float32)
-    out = stencil_pallas(u, offs, w, tile=(8, 16), sweep_axis=0)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(stencil_ref(u, offs, w)), atol=1e-5)
+    with pytest.raises(_backend.UnsupportedBackendError, match="stencil"):
+        stencil_pallas(u, offs, w, tile=(8, 16), sweep_axis=0)
 
 
 def test_conv1d_backend_fallback(monkeypatch):
+    """conv1d shares the resolution: a 'rocm' backend is refused too."""
     from repro.kernels import _backend
     from repro.kernels.conv1d import causal_conv1d
-    from repro.models.ssm import _causal_conv
 
     monkeypatch.setattr(jax, "default_backend", lambda: "rocm")
-    monkeypatch.setattr(_backend, "_seen_backends", set())
     x = jax.random.normal(KEY, (2, 32, 8), jnp.float32)
     cw = jax.random.normal(jax.random.PRNGKey(1), (4, 8), jnp.float32) * 0.3
     cb = jnp.zeros((8,), jnp.float32)
-    out = causal_conv1d(x, cw, cb, tile_s=16)
-    ref, _ = _causal_conv(x, cw, cb, None)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+    with pytest.raises(_backend.UnsupportedBackendError, match="conv1d"):
+        causal_conv1d(x, cw, cb, tile_s=16)

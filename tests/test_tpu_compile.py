@@ -1,0 +1,156 @@
+"""Compiles of the main path for a described TPU v5e, with no chip attached.
+
+Each case lowers a public entry point with ``interpret=False`` for one
+chip (or all four) of a described ``v5e:2x2`` topology and compiles it
+with the chip's own compiler, which refuses what interpret mode lets
+through: DMAs off the (sublane, lane) grain, more VMEM than the core has,
+a program that does not fit the device.  Each case asserts that the
+compiled program holds the Mosaic kernel (``tpu_custom_call``).  The
+sizes are those of ``chip_smoke.py``'s phases.
+
+The topology is described inside a module fixture, never at import time:
+only one process may load the TPU library, and the test workers must
+all collect the same tests.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from repro import obs
+from repro.core.cache_fitting import star_stencil
+from repro.kernels.ref import star_weights_2nd_order
+from repro.kernels.stencil import stencil_iterate, stencil_pallas
+from repro.launch.mesh import make_column_mesh
+
+STAR, STAR_W = star_weights_2nd_order(3, 2)
+JACOBI_2D = star_stencil(2, 1)
+JACOBI_2D_W = [0.0, 0.25, 0.25, 0.25, 0.25]
+BF16_CHAIN = ["bfloat16", "bfloat16", "bfloat16", "float32"]
+
+# name -> (shape, dtype, entry point with interpret=False)
+ONE_CHIP = {
+    "star_512_single": (
+        (512, 512, 512), jnp.float32,
+        lambda u: stencil_pallas(u, STAR, STAR_W, interpret=False),
+    ),
+    "star_256_fused_T3": (
+        (256, 256, 256), jnp.float32,
+        lambda u: stencil_iterate(u, STAR, STAR_W, 3, interpret=False),
+    ),
+    "star_256_bf16_ring_T4": (
+        (256, 256, 256), jnp.bfloat16,
+        lambda u: stencil_iterate(
+            u, STAR, STAR_W, 4, interpret=False, dtypes=BF16_CHAIN
+        ),
+    ),
+    "jacobi_2d_16384": (
+        (16384, 16384), jnp.float32,
+        lambda u: stencil_pallas(u, JACOBI_2D, JACOBI_2D_W, interpret=False),
+    ),
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture
+def chip(topo, monkeypatch):
+    """The described chip, with the launcher's VMEM-capacity lookup
+    steered to its device kind (``jax.devices()`` is the CPU here)."""
+    from repro.kernels import _backend
+
+    monkeypatch.setattr(
+        _backend, "device_kind", lambda: topo.devices[0].device_kind
+    )
+    return topo
+
+
+def _compile(fn, arg):
+    with obs.recording() as rec:
+        compiled = jax.jit(fn).lower(arg).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    launches = [s for s in rec.spans if s.name == "kernel_launch"]
+    assert launches and not any(s.args["interpret"] for s in launches)
+    return text, launches
+
+
+@pytest.mark.parametrize("case", sorted(ONE_CHIP))
+def test_one_chip_phase_compiles(chip, case):
+    shape, dtype, fn = ONE_CHIP[case]
+    arg = jax.ShapeDtypeStruct(
+        shape, dtype, sharding=SingleDeviceSharding(chip.devices[0])
+    )
+    _, launches = _compile(fn, arg)
+    if case.startswith("star_256"):
+        # The fused stage-chain kernel really runs: one launch, depth >= 2.
+        assert len(launches) == 1
+        assert launches[0].args["fused_depth"] >= 2
+
+
+# The fused T=3 sharded launch compiles in ~2 min at 512^3 (its per-step
+# tile is 8x the single-device one), so the test takes it at 256^3;
+# chip_smoke.py --chips 4 runs both at 512^3.
+@pytest.mark.parametrize("time_steps,n", [(1, 512), (3, 256)])
+def test_four_chip_column_sharded_compiles(chip, time_steps, n):
+    mesh = make_column_mesh(4, devices=chip.devices)
+    arg = jax.ShapeDtypeStruct(
+        (n, n, n), jnp.float32,
+        sharding=NamedSharding(mesh, P("columns")),
+    )
+    text, launches = _compile(
+        lambda u: stencil_iterate(
+            u, STAR, STAR_W, time_steps, mesh=mesh, interpret=False
+        ),
+        arg,
+    )
+    assert all(s.args["num_shards"] == 4 for s in launches)
+    assert "collective-permute" in text  # the shard-boundary halo exchange
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_conv1d_compiles(chip, dtype):
+    """The causal conv's sweep shares the grain-rounded window: a
+    Mamba2-width conv (5120 channels, W=4) compiles at a 256-token tile."""
+    from repro.kernels.conv1d import causal_conv1d
+
+    one = SingleDeviceSharding(chip.devices[0])
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+        for shape in ((2, 4096, 5120), (4, 5120), (5120,))
+    ]
+    compiled = jax.jit(
+        lambda x, w, b: causal_conv1d(x, w, b, tile_s=256, interpret=False)
+    ).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_unaligned_explicit_tile_refused_before_compile(chip):
+    """A tile off the lane grain with several tiles along that axis
+    cannot be DMA'd on the chip: the launcher says so, not Mosaic."""
+    arg = jax.ShapeDtypeStruct(
+        (64, 256), jnp.float32, sharding=SingleDeviceSharding(chip.devices[0])
+    )
+    offs = star_stencil(2, 1)
+    w = np.full(len(offs), 0.2).tolist()
+    with pytest.raises(ValueError, match="grain on axis 1"):
+        jax.jit(
+            lambda u: stencil_pallas(
+                u, offs, w, tile=(8, 64), sweep_axis=0, interpret=False
+            )
+        ).lower(arg)
