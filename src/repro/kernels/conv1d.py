@@ -143,6 +143,7 @@ def _conv_call(xp, conv_w, conv_b, tile_s, interpret):
             else pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)
         ),
         interpret=interpret,
+        name="conv1d_sweep",
     )(xp, conv_w, conv_b)
     return out
 

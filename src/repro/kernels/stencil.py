@@ -746,6 +746,7 @@ def _padded_call(ins, dom, offsets, weights, stages, lo_w, hi_w, tile,
             )
         ),
         interpret=interpret,
+        name="stencil_sweep",
     )(dom, *ins)
 
 
@@ -811,25 +812,30 @@ def embed_inputs(us, pads, pad_free=False, wrap=None, fill=0):
     each ghost band from the far side of the domain instead of leaving
     it at the fill value; ``fill`` sets the background (the int8 zero
     point for a quantized inter-launch handoff, so the slack dequantizes
-    to exact zeros)."""
-    if not pad_free:
-        bufs = (
-            [jnp.pad(u, pads, constant_values=fill) for u in us]
-            if fill else [jnp.pad(u, pads) for u in us]
-        )
-    else:
-        shape = tuple(
-            int(n) + lo + hi for (lo, hi), n in zip(pads, us[0].shape)
-        )
-        starts = tuple(lo for lo, _ in pads)
-        bufs = [
-            jax.lax.dynamic_update_slice(
-                jnp.full(shape, fill, u.dtype) if fill
-                else jnp.zeros(shape, u.dtype),
-                u, starts,
+    to exact zeros).
+
+    The ops carry the scopes ``stencil_embed`` (the buffer) and
+    ``stencil_wrap`` (the ghost fill) in their metadata, which a profiler
+    trace keeps, so that the launch's copies can be told apart there."""
+    with jax.named_scope("stencil_embed"):
+        if not pad_free:
+            bufs = (
+                [jnp.pad(u, pads, constant_values=fill) for u in us]
+                if fill else [jnp.pad(u, pads) for u in us]
             )
-            for u in us
-        ]
+        else:
+            shape = tuple(
+                int(n) + lo + hi for (lo, hi), n in zip(pads, us[0].shape)
+            )
+            starts = tuple(lo for lo, _ in pads)
+            bufs = [
+                jax.lax.dynamic_update_slice(
+                    jnp.full(shape, fill, u.dtype) if fill
+                    else jnp.zeros(shape, u.dtype),
+                    u, starts,
+                )
+                for u in us
+            ]
     if wrap is None:
         return bufs
 
@@ -856,7 +862,8 @@ def embed_inputs(us, pads, pad_free=False, wrap=None, fill=0):
                 buf = buf.at[tuple(dst)].set(buf[tuple(src)])
         return buf
 
-    return [wrap_fill(buf, u.shape) for buf, u in zip(bufs, us)]
+    with jax.named_scope("stencil_wrap"):
+        return [wrap_fill(buf, u.shape) for buf, u in zip(bufs, us)]
 
 
 @functools.partial(
@@ -904,7 +911,8 @@ def _stencil_call(us, offsets_w, tile, sweep, pipelined, interpret,
         hi_w, tile, sweep, pipelined, interpret, u0.shape,
         window_kind=window_kind, in_quant=in_quant,
     )
-    return out[tuple(slice(0, n) for n in u0.shape)]
+    with jax.named_scope("stencil_trim"):
+        return out[tuple(slice(0, n) for n in u0.shape)]
 
 
 def _auto_tile(shape, offsets_list, dtype_bytes, n_arrays, vmem_budget=None,
@@ -1146,32 +1154,28 @@ def multi_stencil_pallas(
     the plan's choice, else ring) — an execution knob, bit-wise neutral.
 
     ``trace="path.json"`` records this call into a Chrome ``trace_event``
-    file (see :mod:`repro.obs`)."""
+    file (see :mod:`repro.obs`).
+
+    Each call is one ``stencil_call`` span (numbered ``call=<n>``) over
+    ``ir_lower`` (program build, lowering, summary), the planner's
+    ``plan`` and one ``kernel_launch`` per launch, recorded by a
+    :mod:`repro.obs` recorder or, with none, sent to a collecting
+    ``jax.profiler`` session."""
+    args = (us, offsets_list, weights_list, tile, interpret, vmem_budget,
+            sweep_axis, pipelined, plan, time_steps, stages, num_shards,
+            shard_axis, mesh, tune, program, dtypes, window_kind)
     if trace is not None:
-        with obs.recording(trace):
-            return multi_stencil_pallas(
-                us, offsets_list, weights_list, tile=tile,
-                interpret=interpret, vmem_budget=vmem_budget,
-                sweep_axis=sweep_axis, pipelined=pipelined, plan=plan,
-                time_steps=time_steps, stages=stages,
-                num_shards=num_shards, shard_axis=shard_axis, mesh=mesh,
-                tune=tune, program=program, dtypes=dtypes,
-                window_kind=window_kind,
-            )
-    if window_kind is not None and window_kind not in ("ring", "trapezoid"):
-        raise ValueError(
-            f"window_kind must be 'ring' or 'trapezoid', got {window_kind!r}"
-        )
-    if dtypes is not None:
-        dtypes = tuple(
-            str(jnp.dtype(dt).name) if dt is not None else None
-            for dt in dtypes
-        )
-    us = tuple(us)
-    assert len({u.shape for u in us}) == 1, "RHS arrays must share a shape"
+        with obs.recording(trace), obs.call_span("stencil_call"):
+            return _stencil_entry(*args)
+    with obs.call_span("stencil_call"):
+        return _stencil_entry(*args)
+
+
+def _build_program(us, offsets_list, weights_list, time_steps, stages,
+                   program, dtypes):
+    """The :class:`repro.ir.Program` of one call, from an explicit
+    ``program`` or the legacy spellings (§13)."""
     d = us[0].ndim
-    shape = tuple(int(n) for n in us[0].shape)
-    # -- build the stencil program (§13) -----------------------------------
     if program is not None:
         if (offsets_list is not None or weights_list is not None
                 or stages is not None):
@@ -1184,11 +1188,11 @@ def multi_stencil_pallas(
                 "dtypes= belongs to the legacy spellings; a program "
                 "carries per-stage dtypes on its apply ops"
             )
-        prog = (
+        return (
             ir.Program.from_json(program) if isinstance(program, str)
             else program
         )
-    elif stages is not None:
+    if stages is not None:
         if offsets_list is not None or weights_list is not None:
             raise ValueError(
                 "pass (offsets_list, weights_list) or stages, not both"
@@ -1206,32 +1210,51 @@ def multi_stencil_pallas(
                     f"stage has {len(offs)} offsets but {len(tuple(ws))} "
                     "weights"
                 )
-        prog = ir.chain_program(list(stages), d, dtypes=dtypes)
-    else:
-        T = int(time_steps)
-        if T < 1:
-            raise ValueError(f"time_steps must be >= 1, got {T}")
-        if T > 1 and len(us) != 1:
-            raise ValueError(
-                "temporal fusion (time_steps > 1) requires a single RHS; "
-                f"got {len(us)} arrays"
-            )
-        if len(us) == 1:
-            # The canonical form: every single-RHS call IS a (possibly
-            # repeated) stage chain.
-            prog = ir.stencil_program(
-                offsets_list[0], weights_list[0], time_steps=T, d=d,
-                dtypes=dtypes,
-            )
-        else:
-            if dtypes is not None:
-                raise ValueError(
-                    "dtypes= requires a single-RHS stage chain"
-                )
-            prog = ir.rhs_program(offsets_list, weights_list, d=d)
-    # -- verify + lower onto the engine's launch form ----------------------
-    lowered = ir.lower(prog, shape)
-    prog_summary = ir.summarize_program(prog)
+        return ir.chain_program(list(stages), d, dtypes=dtypes)
+    T = int(time_steps)
+    if T < 1:
+        raise ValueError(f"time_steps must be >= 1, got {T}")
+    if T > 1 and len(us) != 1:
+        raise ValueError(
+            "temporal fusion (time_steps > 1) requires a single RHS; "
+            f"got {len(us)} arrays"
+        )
+    if len(us) == 1:
+        # The canonical form: every single-RHS call IS a (possibly
+        # repeated) stage chain.
+        return ir.stencil_program(
+            offsets_list[0], weights_list[0], time_steps=T, d=d,
+            dtypes=dtypes,
+        )
+    if dtypes is not None:
+        raise ValueError("dtypes= requires a single-RHS stage chain")
+    return ir.rhs_program(offsets_list, weights_list, d=d)
+
+
+def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
+                   vmem_budget, sweep_axis, pipelined, plan, time_steps,
+                   stages, num_shards, shard_axis, mesh, tune, program,
+                   dtypes, window_kind):
+    """The body of :func:`multi_stencil_pallas`: lower, plan, launch."""
+    if window_kind is not None and window_kind not in ("ring", "trapezoid"):
+        raise ValueError(
+            f"window_kind must be 'ring' or 'trapezoid', got {window_kind!r}"
+        )
+    if dtypes is not None:
+        dtypes = tuple(
+            str(jnp.dtype(dt).name) if dt is not None else None
+            for dt in dtypes
+        )
+    us = tuple(us)
+    assert len({u.shape for u in us}) == 1, "RHS arrays must share a shape"
+    d = us[0].ndim
+    shape = tuple(int(n) for n in us[0].shape)
+    # -- build the stencil program (§13), verify + lower it ----------------
+    with obs.span("ir_lower"):
+        prog = _build_program(us, offsets_list, weights_list, time_steps,
+                              stages, program, dtypes)
+        lowered = ir.lower(prog, shape)
+        prog_summary = ir.summarize_program(prog)
     if lowered.kind == "chain":
         if len(us) != 1:
             raise ValueError(
@@ -1384,23 +1407,29 @@ def multi_stencil_pallas(
 
             shard_axis = pick_shard_axis(us[0].shape, tile, sweep_axis)
 
-    if num_shards > 1 or mesh is not None:
-        from repro.parallel.shard_columns import column_launcher
+    def launcher():
+        if num_shards > 1 or mesh is not None:
+            from repro.parallel.shard_columns import column_launcher
 
-        launcher = column_launcher(
-            num_shards=num_shards, shard_axis=shard_axis, mesh=mesh,
-        )
-    else:
-        launcher = _stencil_call
+            return column_launcher(
+                num_shards=num_shards, shard_axis=shard_axis, mesh=mesh,
+            )
+        return _stencil_call
 
     def static_spec(op):
         offs, wts = op
         return (tuple(map(tuple, np.asarray(offs).tolist())), tuple(wts))
 
     def launch_span(n_run, run=None, run_dts=None, run_qs=None):
-        # Only called with recording on: prices this launch's slice of
-        # the plan's whole-chain model (n_run of T stages) and bumps the
-        # counters the report CLI reconciles against the spans.
+        """The ``kernel_launch`` span of one launch: it times the host's
+        side only — the launcher lookup (the sharded path's mesh and
+        jitted launch) and the jitted call until it returns the unready
+        array, not the device's run.  With a recorder it also prices
+        this launch's slice of the plan's whole-chain model (n_run of T
+        stages) and bumps the counters the report CLI reconciles against
+        the spans; a profiler alone gets the bare span."""
+        if not obs.enabled():
+            return obs.span("kernel_launch")
         p = resolved_plan
         if p is not None:
             chain_bytes = (
@@ -1437,8 +1466,6 @@ def multi_stencil_pallas(
         obs.add("modeled_bytes", mb)
         obs.add("modeled_flops", mf)
         obs.add("ring_vmem_bytes", rvb)
-        if quantized:
-            obs.add("quantized_launches")
         return obs.span(
             "kernel_launch",
             plan_key=plan_key, tile=list(tile), sweep_axis=sweep_axis,
@@ -1458,8 +1485,8 @@ def multi_stencil_pallas(
             static_spec((o, tuple(float(w) for w in ws)))
             for o, ws in zip(offsets_list, weights_list)
         )
-        with launch_span(1) if obs.enabled() else obs.NULL_SPAN:
-            return launcher(
+        with launch_span(1):
+            return launcher()(
                 us, offsets_w, tile, sweep_axis, pipelined, interpret,
             )
     arrays = us
@@ -1473,11 +1500,8 @@ def multi_stencil_pallas(
         )
         run_qs = tuple(chain_quants[pos : pos + len(run)])
         pos += len(run)
-        span = (
-            launch_span(len(run), run, run_dts, run_qs)
-            if obs.enabled() else obs.NULL_SPAN
-        )
-        with span:
+        with launch_span(len(run), run, run_dts, run_qs):
+            launch = launcher()
             if any(bc is not None for bc in run_bcs) or run_dts is not None:
                 # §13 boundary-op / §14 mixed-dtype / §15 quantized
                 # launch: always the stage-chain form (even for one
@@ -1487,7 +1511,7 @@ def multi_stencil_pallas(
                 # the chain forces eff non-None (its dtype is int8), so
                 # every launch of such a chain takes this branch and the
                 # quantized inter-launch handoff (``in_q``) is threaded.
-                result = launcher(
+                result = launch(
                     arrays, (static_spec(run[0]),), tile, sweep_axis,
                     pipelined, interpret,
                     stages_w=tuple(static_spec(op) for op in run),
@@ -1502,12 +1526,12 @@ def multi_stencil_pallas(
                     in_quant=in_q,
                 )
             elif len(run) == 1:
-                result = launcher(
+                result = launch(
                     arrays, (static_spec(run[0]),), tile, sweep_axis,
                     pipelined, interpret,
                 )
             else:
-                result = launcher(
+                result = launch(
                     arrays, (static_spec(run[0]),), tile, sweep_axis,
                     pipelined, interpret,
                     stages_w=tuple(static_spec(op) for op in run),

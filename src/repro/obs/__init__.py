@@ -4,7 +4,11 @@ Public API (all safe to call with recording disabled — one predicate
 check, no allocation):
 
 * ``obs.enabled()`` / ``obs.active()`` — is a recorder installed?
-* ``obs.span(name, **args)`` — context-managed timed region,
+* ``obs.tracing()`` — is a recorder installed or a ``jax.profiler``
+  session collecting?
+* ``obs.span(name, **args)`` — context-managed timed region (a bare
+  ``jax.profiler.TraceAnnotation`` while only a profiler collects),
+* ``obs.call_span(name)`` — the root span of one call, numbered,
 * ``obs.add(name, value)`` — bump a counter,
 * ``obs.event(name, **args)`` — instant event,
 * ``obs.recording(path)`` — scoped recorder, trace written on exit,
@@ -23,10 +27,12 @@ from .recorder import (  # noqa: F401
     _activate_from_env,
     active,
     add,
+    call_span,
     enabled,
     event,
     recording,
     span,
+    tracing,
 )
 from .trace_event import (  # noqa: F401
     load_trace,
@@ -41,12 +47,14 @@ __all__ = [
     "Span",
     "active",
     "add",
+    "call_span",
     "enabled",
     "event",
     "load_trace",
     "recording",
     "span",
     "to_trace_events",
+    "tracing",
     "validate_trace",
     "write_trace",
 ]

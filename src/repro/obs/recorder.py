@@ -20,6 +20,15 @@ launch wrapper — pay a pointer compare.  Hot callers that would build a
 kwargs dict for span arguments guard with ``if obs.enabled():`` first,
 keeping even that allocation off the disabled path.
 
+**A profiler session sees the spans too.**  While a ``jax.profiler``
+session is collecting and no recorder is installed, :func:`span` returns
+a bare ``jax.profiler.TraceAnnotation``: the span reaches the profiler's
+own trace, on the caller's thread, and nothing is stored or counted in
+Python.  The session is detected with one call of the profiler's own
+``TraceAnnotation.is_enabled`` (:func:`tracing`); :func:`add` and
+:func:`event` stay recorder-only, and sites guarded by :func:`enabled`
+(per-launch pricing, cache lookups) stay off the profiled path.
+
 Enabling, in precedence order (innermost wins; recorders nest):
 
 * ``REPRO_TRACE=path.json`` in the environment — a process-wide recorder
@@ -38,6 +47,7 @@ activates when jax is *already* imported by someone else.
 from __future__ import annotations
 
 import atexit
+import itertools
 import os
 import sys
 import threading
@@ -49,10 +59,12 @@ __all__ = [
     "Span",
     "active",
     "add",
+    "call_span",
     "enabled",
     "event",
     "recording",
     "span",
+    "tracing",
 ]
 
 _ENV = "REPRO_TRACE"
@@ -60,6 +72,9 @@ _ENV = "REPRO_TRACE"
 # The single module global every disabled-path check reads.  ``None``
 # means recording is off and all helpers are no-ops.
 _active: "Recorder | None" = None
+
+# The per-process number of each traced call's root span (call_span).
+_calls = itertools.count(1)
 
 
 def _now_us() -> float:
@@ -84,6 +99,50 @@ class _NullSpan:
 
 
 NULL_SPAN = _NullSpan()
+
+
+def _resolve_profiling() -> bool:
+    """Whether a ``jax.profiler`` session is collecting.  Until jax is
+    imported by someone else there is none; from then on the check is the
+    profiler's own ``TraceAnnotation.is_enabled``, installed here as
+    :data:`_profiling` so that later calls go to it directly."""
+    global _profiling
+    if "jax" not in sys.modules:
+        return False
+    from jax.profiler import TraceAnnotation
+
+    _profiling = TraceAnnotation.is_enabled
+    return _profiling()
+
+
+# ``() -> bool``: is a profiler session collecting?  Starts as the
+# resolver, which replaces itself once jax is importable.
+_profiling = _resolve_profiling
+
+
+class _Annotation:
+    """A span with no recorder while a profiler session collects: a bare
+    ``jax.profiler.TraceAnnotation`` whose arguments become the event's
+    metadata.  Nothing is kept in Python."""
+
+    __slots__ = ("_me",)
+
+    def __init__(self, name: str, args: dict):
+        from jax.profiler import TraceAnnotation
+
+        self._me = TraceAnnotation(name, **args)
+
+    def __enter__(self) -> "_Annotation":
+        self._me.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._me.__exit__(*exc)
+        return False
+
+    def set(self, **args) -> "_Annotation":
+        self._me.set_metadata(**args)
+        return self
 
 
 class Span:
@@ -217,14 +276,34 @@ def enabled() -> bool:
     return _active is not None
 
 
+def tracing() -> bool:
+    """Whether a span would reach a trace: a recorder is installed, or a
+    ``jax.profiler`` session is collecting.  The guard for span arguments
+    that are cheap enough to send to the profiler."""
+    return _active is not None or _profiling()
+
+
 def span(name: str, cat: str = "repro", **args):
-    """A span on the active recorder, or the shared null span when
-    recording is disabled (no allocation on that path when called with
-    no keyword args — hot callers guard kwargs with :func:`enabled`)."""
+    """A span on the active recorder; with none, a bare profiler
+    annotation while a ``jax.profiler`` session collects, else the shared
+    null span (no allocation on that path when called with no keyword
+    args — hot callers guard kwargs with :func:`enabled` or
+    :func:`tracing`)."""
     rec = _active
-    if rec is None:
+    if rec is not None:
+        return rec.span(name, cat, **args)
+    if _profiling():
+        return _Annotation(name, args)
+    return NULL_SPAN
+
+
+def call_span(name: str):
+    """:func:`span` for the root of one call, carrying ``call=<n>``: a
+    per-process number, drawn only while tracing, that joins all spans of
+    one call.  The shared null span when nothing is tracing."""
+    if not tracing():
         return NULL_SPAN
-    return rec.span(name, cat, **args)
+    return span(name, call=next(_calls))
 
 
 def add(name: str, value: int = 1) -> None:

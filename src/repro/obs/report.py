@@ -4,8 +4,11 @@ Reads a ``trace_event`` JSON file written by :mod:`repro.obs` and prints
 the evidence trail the paper's model promises (DESIGN.md §12):
 
 * a per-launch reconciliation table — plan key, fused depth, shard
-  count, tile, **modeled bytes vs measured wall time vs achieved GB/s**
-  — one row per ``kernel_launch`` span;
+  count, tile, window kind, modeled bytes — one row per
+  ``kernel_launch`` span.  That span times the host's enqueue, not the
+  device's run, so the table shows no wall time or bandwidth: device
+  time comes from a ``jax.profiler`` trace, read by the benchmark's
+  ``bench/trace_program.py``;
 * the tune-race outcome (candidate ranks, measured medians, winner);
 * the counter totals (cache hits/misses, fallbacks, modeled totals).
 
@@ -58,7 +61,6 @@ def summarize(doc: dict) -> dict[str, Any]:
     launches = []
     for ev in _spans(doc, "kernel_launch"):
         args = ev.get("args") or {}
-        dur_us = float(ev.get("dur", 0.0))
         modeled = int(args.get("modeled_bytes", 0))
         launches.append({
             "plan_key": str(args.get("plan_key", "?")),
@@ -72,8 +74,6 @@ def summarize(doc: dict) -> dict[str, Any]:
             "window_kind": args.get("window_kind"),
             "stage_dtypes": args.get("stage_dtypes"),
             "ring_vmem_bytes": int(args.get("ring_vmem_bytes", 0)),
-            "dur_us": dur_us,
-            "gb_per_s": (modeled / (dur_us * 1e3)) if dur_us > 0 else 0.0,
         })
     races = []
     for ev in _spans(doc, "tune_race"):
@@ -106,7 +106,6 @@ def summarize(doc: dict) -> dict[str, Any]:
         "measure_ns_total": int(
             sum((m.get("args") or {}).get("measured_ns", 0) for m in measures)
         ),
-        "n_exchange_spans": len(_spans(doc, "halo_exchange")),
     }
 
 
@@ -162,7 +161,7 @@ def render(summary: dict[str, Any]) -> str:
         hdr = (
             f"{'#':>3}  {'plan key':<14} {'T':>3} {'shards':>6} "
             f"{'tile':<14} {'win':<5} {'ring vmem':>10} "
-            f"{'modeled':>12} {'wall ms':>9} {'GB/s':>8}"
+            f"{'modeled':>12}"
         )
         lines += [hdr, "-" * len(hdr)]
         for i, l in enumerate(launches):
@@ -173,8 +172,7 @@ def render(summary: dict[str, Any]) -> str:
                 f"{l['fused_depth'] or 1:>3} {l['num_shards'] or 1:>6} "
                 f"{tile:<14} {wk:<5} "
                 f"{_fmt_bytes(l['ring_vmem_bytes']):>10} "
-                f"{_fmt_bytes(l['modeled_bytes']):>12} "
-                f"{l['dur_us'] / 1e3:>9.3f} {l['gb_per_s']:>8.2f}"
+                f"{_fmt_bytes(l['modeled_bytes']):>12}"
             )
             dts = l.get("stage_dtypes")
             if dts and any(dt is not None for dt in dts):
@@ -201,8 +199,7 @@ def render(summary: dict[str, Any]) -> str:
         )
     lines.append(
         f"spans: plan={summary['n_plan_spans']} "
-        f"measure={summary['n_measure_spans']} "
-        f"halo_exchange={summary['n_exchange_spans']}"
+        f"measure={summary['n_measure_spans']}"
     )
     counters = summary["counters"]
     if counters:

@@ -174,6 +174,7 @@ class Planner:
         self.tuned_db = tuned_db
         self.last_plan_seconds: float | None = None  # cold-vs-warm telemetry
         self.last_plan_tuned: bool = False           # did a tuned entry win?
+        self.last_plan_cache: str | None = None      # hit / miss / tuned
 
     # -- cheap diagnostics (no tile search) --------------------------------
 
@@ -300,16 +301,23 @@ class Planner:
 
         With a ``tuned_db`` attached, a measured winner recorded for this
         request on this backend wins over the analytic choice (§11 autotune
-        loop); a DB miss — or no DB — resolves analytically, unchanged."""
-        if request is None:
-            kw.setdefault("strategy", self.strategy)
-            request = PlanRequest.make(**kw)
-        key = request.cache_key()
-        # Hot serving path: one predicate check with recording off.
-        if obs.enabled():
-            with obs.span("plan", key=key) as sp:
-                plan = self._plan_resolve(request, key)
+        loop); a DB miss — or no DB — resolves analytically, unchanged.
+
+        The ``plan`` span covers the request build, its sha256 key and the
+        resolution, and carries ``cache``: ``hit``, ``miss`` or
+        ``tuned`` (:attr:`last_plan_cache`).  With neither a recorder nor
+        a profiler it is the shared null span."""
+        with obs.span("plan") as sp:
+            if request is None:
+                kw.setdefault("strategy", self.strategy)
+                request = PlanRequest.make(**kw)
+            key = request.cache_key()
+            plan = self._plan_resolve(request, key)
+            if sp is not obs.NULL_SPAN:
+                sp.set(cache=self.last_plan_cache)
+            if obs.enabled():
                 sp.set(
+                    key=key,
                     tuned=self.last_plan_tuned,
                     tile=list(plan.tile),
                     sweep_axis=plan.sweep_axis,
@@ -317,8 +325,7 @@ class Planner:
                     num_shards=plan.num_shards,
                     traffic_bytes=plan.traffic_bytes,
                 )
-            return plan
-        return self._plan_resolve(request, key)
+        return plan
 
     def _plan_resolve(self, request: PlanRequest, key: str) -> StencilPlan:
         t0 = time.perf_counter()
@@ -327,6 +334,7 @@ class Planner:
             tuned = self._tuned_winner(key)
             if tuned is not None:
                 self.last_plan_tuned = True
+                self.last_plan_cache = "tuned"
                 self.last_plan_seconds = time.perf_counter() - t0
                 return tuned
         plan = self._analytic(request, key)
@@ -341,7 +349,9 @@ class Planner:
         key = key if key is not None else request.cache_key()
         cached = self.cache.get(key)
         if cached is not None:
+            self.last_plan_cache = "hit"
             return cached
+        self.last_plan_cache = "miss"
         plan = self._compile(request)
         self.cache.put(key, plan)
         return plan

@@ -120,17 +120,26 @@ def test_env_activation_writes_trace_at_exit(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_disabled_path_allocates_nothing():
-    assert not obs.enabled()
+    assert not obs.enabled() and not obs.tracing()
     assert obs.span("a") is obs.span("b") is obs.NULL_SPAN
+    assert obs.call_span("stencil_call") is obs.NULL_SPAN
     assert obs.NULL_SPAN.set(x=1) is obs.NULL_SPAN
 
     def hot():
         # The exact shape of every instrumented hot path: a predicate
-        # check, a bare span, a counter bump.
-        if obs.enabled():
+        # check, a bare span, a counter bump — and the call's own sites:
+        # the numbered root, the lowering, the plan with its outcome
+        # guard, the launch.
+        if obs.enabled() or obs.tracing():
             raise AssertionError("recording must be off")
-        with obs.span("kernel_launch"):
-            pass
+        with obs.call_span("stencil_call"):
+            with obs.span("ir_lower"):
+                pass
+            with obs.span("plan") as sp:
+                if sp is not obs.NULL_SPAN:
+                    raise AssertionError("the plan span must be null")
+            with obs.span("kernel_launch"):
+                pass
         obs.add("launches")
 
     import gc
@@ -146,6 +155,129 @@ def test_disabled_path_allocates_nothing():
     assert after - before <= 2, (
         f"no-op obs path leaked {after - before} blocks over 512 calls"
     )
+
+
+def test_profiler_session_is_detected(tmp_path):
+    """``obs.tracing()`` rests on ``jax.profiler.TraceAnnotation.
+    is_enabled``: false with no session, true while one collects.  With
+    no recorder, a span is then a bare annotation and nothing is kept."""
+    import jax
+
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert not obs.tracing()
+    with jax.profiler.trace(str(tmp_path)):
+        assert jax.profiler.TraceAnnotation.is_enabled()
+        assert obs.tracing() and not obs.enabled()
+        sp = obs.span("plan")
+        assert sp is not obs.NULL_SPAN
+        with sp:
+            sp.set(cache="hit")
+        with obs.call_span("stencil_call") as root:
+            assert root is not obs.NULL_SPAN
+        assert obs.active() is None
+    assert not obs.tracing()
+    assert obs.span("plan") is obs.NULL_SPAN
+
+
+def _stencil_calls(kind):
+    """Two warm calls of a public entry: ``stencil_iterate`` on one
+    device, or a periodic 27-point ``ir.run_program`` over a 4-device
+    mesh."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro import ir
+    from repro.kernels.stencil import stencil_iterate
+
+    if kind == "stencil_iterate":
+        offs = star_stencil(3, 1)
+        w = [1.0 / len(offs)] * len(offs)
+        u = jnp.ones((16, 16, 128), jnp.float32)
+        return lambda: stencil_iterate(u, offs, w, 1)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.launch.mesh import make_column_mesh
+
+    offs = np.array([(i, j, k) for i in (-1, 0, 1) for j in (-1, 0, 1)
+                     for k in (-1, 0, 1)])
+    w = [1.0 / 27] * 27
+    prog = ir.stencil_program(offs, w, 1, d=3, boundary="periodic")
+    mesh = make_column_mesh(4)
+    u = jax.device_put(jnp.ones((32, 16, 128), jnp.float32),
+                       NamedSharding(mesh, P("columns")))
+    return lambda: ir.run_program(prog, u, num_shards=4, shard_axis=0)
+
+
+CALL_SPANS = ("stencil_call", "ir_lower", "plan", "kernel_launch")
+
+
+@pytest.mark.parametrize("kind", ["stencil_iterate", "run_program_4dev"])
+def test_profiler_trace_holds_call_spans(tmp_path, kind):
+    """Under ``jax.profiler`` with no recorder, each call leaves one
+    numbered ``stencil_call`` on the caller's thread holding its
+    ``ir_lower``, ``plan`` (with the cache outcome) and launch spans."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    call = _stencil_calls(kind)
+    call().block_until_ready()  # compile and plan outside the trace
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=options):
+        for _ in range(3):
+            call().block_until_ready()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    events = [
+        (line.name, e.name, e.start_ns, e.start_ns + e.duration_ns,
+         dict(e.stats))
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines for e in line.events
+        if e.name in CALL_SPANS
+    ]
+    assert {line for line, *_ in events} == {events[0][0]}  # one thread
+    roots = sorted((s, e, st) for _, name, s, e, st in events
+                   if name == "stencil_call")
+    assert len(roots) == 3
+    numbers = [st["call"] for _, _, st in roots]
+    assert numbers == list(range(numbers[0], numbers[0] + 3))
+    for s, e, _ in roots:
+        inside = sorted((cs, name, st) for _, name, cs, ce, st in events
+                        if name != "stencil_call" and s <= cs and ce <= e)
+        names = [name for _, name, _ in inside]
+        assert names[:2] == ["ir_lower", "plan"]
+        assert names[2:] and set(names[2:]) == {"kernel_launch"}
+        assert inside[1][2]["cache"] == "hit"
+    assert len(events) == sum(
+        1 for _, name, *_ in events if name == "stencil_call"
+    ) * 4
+
+
+def test_recorder_gets_call_spans():
+    """With a recorder the same spans land in it, with their arguments:
+    the call number, the plan's key and cache outcome, the launch's
+    pricing."""
+    call = _stencil_calls("stencil_iterate")
+    call().block_until_ready()
+    with obs.recording() as rec:
+        call().block_until_ready()
+        call().block_until_ready()
+    by_name = {}
+    for sp in rec.spans:
+        by_name.setdefault(sp.name, []).append(sp)
+    roots = by_name["stencil_call"]
+    assert [r.args["call"] for r in roots][1] == roots[0].args["call"] + 1
+    for name in ("ir_lower", "plan", "kernel_launch"):
+        assert len(by_name[name]) == 2
+        for child, root in zip(by_name[name], roots):
+            assert child.tid == root.tid
+            assert root.ts_us <= child.ts_us
+            assert child.ts_us + child.dur_us <= root.ts_us + root.dur_us
+    assert all(p.args["cache"] == "hit" and p.args["key"]
+               for p in by_name["plan"])
+    assert all("modeled_bytes" in k.args for k in by_name["kernel_launch"])
+    assert rec.counters["launches"] == 2
 
 
 def test_plan_cache_warm_hit_stays_fast_with_obs_disabled():
@@ -181,6 +313,7 @@ def test_plan_span_and_cache_counters():
     assert len(plans) == 2
     assert plans[0].args["key"] == plans[1].args["key"]
     assert plans[0].args["tuned"] is False
+    assert [p.args["cache"] for p in plans] == ["miss", "hit"]
     lookups = [s for s in rec.spans if s.name == "plan_cache_lookup"]
     assert [s.args["outcome"] for s in lookups] == ["miss", "hit"]
 
@@ -296,7 +429,7 @@ def test_traced_tuned_sharded_run_reconciles(tmp_path):
     summary = summarize(doc)
     assert reconcile(summary) == [], "trace does not reconcile"
     assert summary["counters"]["launches"] == len(summary["launches"]) > 0
-    assert summary["n_exchange_spans"] > 0  # 4-shard halo exchanges
+    assert summary["counters"]["halo_exchange_bytes"] > 0  # 4 shards
     # k=2 analytic candidates plus the §15 window-flip and advisory
     # bf16/int8 dtype variants the race appends beyond top-k.
     assert summary["races"] and summary["races"][0]["candidates"] >= 2
@@ -306,6 +439,27 @@ def test_traced_tuned_sharded_run_reconciles(tmp_path):
     assert launch["fused_depth"] >= 1
     # the CLI agrees
     assert report_main([trace, "--check"]) == 0
+
+
+def test_report_prints_no_device_time():
+    """``kernel_launch`` times the host's enqueue, so the report's launch
+    table shows no wall time or bandwidth derived from it."""
+    from repro.obs.report import render
+
+    with obs.recording() as rec:
+        obs.add("launches")
+        obs.add("modeled_bytes", 1 << 20)
+        with obs.span("kernel_launch", plan_key="k", tile=[8, 128],
+                      fused_depth=1, num_shards=1, modeled_bytes=1 << 20):
+            pass
+    summary = summarize(rec.to_trace_events())
+    assert reconcile(summary) == []
+    assert "modeled_bytes" in summary["launches"][0]
+    assert not {"dur_us", "gb_per_s"} & set(summary["launches"][0])
+    text = render(summary)
+    assert "1.00 MiB" in text
+    assert "wall" not in text and "GB/s" not in text
+    assert "halo_exchange" not in text
 
 
 # ---------------------------------------------------------------------------

@@ -14,6 +14,7 @@ all collect the same tests.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -80,6 +81,16 @@ def chip(topo, monkeypatch):
     return topo
 
 
+def _scopes(text):
+    """The ``stencil_*`` scopes named in the compiled ops' metadata, and
+    the HLO names of the Mosaic calls."""
+    scopes = set(re.findall(r'op_name="[^"]*?/(stencil_[a-z]+)/', text))
+    kernels = set(re.findall(
+        r"%([\w.-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text
+    ))
+    return scopes, kernels
+
+
 def _compile(fn, arg):
     with obs.recording() as rec:
         compiled = jax.jit(fn).lower(arg).compile()
@@ -87,6 +98,11 @@ def _compile(fn, arg):
     assert "tpu_custom_call" in text
     launches = [s for s in rec.spans if s.name == "kernel_launch"]
     assert launches and not any(s.args["interpret"] for s in launches)
+    # The kernel is named, and the launch buffer's ops carry their scope
+    # into the compiled program (a profiler trace reads both).
+    scopes, kernels = _scopes(text)
+    assert kernels and all(k.startswith("stencil_sweep") for k in kernels)
+    assert {"stencil_embed", "stencil_sweep"} <= scopes
     return text, launches
 
 
@@ -123,6 +139,34 @@ def test_four_chip_column_sharded_compiles(chip, time_steps, n):
     assert "collective-permute" in text  # the shard-boundary halo exchange
 
 
+def test_four_chip_periodic_program_scopes(chip):
+    """A periodic 27-point program (NPB MG's operator shape) over the four
+    chips: the compiled ops carry the wrap fill's and the halo exchange's
+    scopes beside the embed's, and the exchange is a collective."""
+    from repro import ir
+
+    offs = np.array([(i, j, k) for i in (-1, 0, 1) for j in (-1, 0, 1)
+                     for k in (-1, 0, 1)])
+    w = [1.0 / 27] * 27
+    prog = ir.stencil_program(offs, w, 1, d=3, boundary="periodic")
+    mesh = make_column_mesh(4, devices=chip.devices)
+    arg = jax.ShapeDtypeStruct(
+        (128, 128, 128), jnp.float32,
+        sharding=NamedSharding(mesh, P("columns")),
+    )
+    text, launches = _compile(
+        lambda u: ir.run_program(prog, u, mesh=mesh, shard_axis=0,
+                                 interpret=False),
+        arg,
+    )
+    assert all(s.args["num_shards"] == 4 for s in launches)
+    scopes, _ = _scopes(text)
+    assert {"stencil_embed", "stencil_wrap", "stencil_halo"} <= scopes
+    permutes = re.findall(r'collective-permute-start[^\n]*op_name="([^"]*)"',
+                          text)
+    assert permutes and all("/stencil_halo/" in p for p in permutes)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_conv1d_compiles(chip, dtype):
     """The causal conv's sweep shares the grain-rounded window: a
@@ -137,7 +181,9 @@ def test_conv1d_compiles(chip, dtype):
     compiled = jax.jit(
         lambda x, w, b: causal_conv1d(x, w, b, tile_s=256, interpret=False)
     ).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "%conv1d_sweep" in text  # the kernel's name= reaches the HLO
 
 
 def test_unaligned_explicit_tile_refused_before_compile(chip):
