@@ -82,6 +82,7 @@ __all__ = [
     "candidate_tiles",
     "chain_flops",
     "chain_halo",
+    "direct_input",
     "dtype_itemsize",
     "fused_halo",
     "fused_stage_bytes",
@@ -197,6 +198,50 @@ def window_extents(
             e = -(-e // g) * g
         ext.append(e)
     return tuple(ext)
+
+
+def direct_input(
+    shape: Sequence[int],
+    tile: Sequence[int],
+    halo: Sequence[tuple[int, int]],
+    dtype_bytes: int,
+    bcs: Sequence | None = None,
+    in_quant: tuple | None = None,
+    num_shards: int = 1,
+) -> bool:
+    """Whether a launch reads the caller's array as it is, with no
+    zero-filled launch buffer (DESIGN.md §16): the kernel writes the
+    window's zeros outside the grid itself.  It does when
+
+    * the fill is zero: no §15 int8 hand-off (``in_quant``), whose fill
+      is the zero point;
+    * no stage wraps periodically (§15): those ghost cells are copies of
+      the far side, not zeros;
+    * it runs on one device: a §10 shard's slab is built by the halo
+      exchange;
+    * every DMA from the array stays on the (sublane, lane) grain
+      (:func:`axis_grain`): each of the last two axes is a whole number
+      of grains, and an axis split into several tiles is split at grain
+      multiples behind a low halo of whole grains.  (An axis in one tile
+      lands its cells up to a grain early and the kernel shifts them in
+      VMEM.)
+
+    The window is the one :func:`window_extents` gives either way, so
+    the planner's VMEM charge holds for both.  The launcher, its
+    ``kernel_launch`` span and the plan report (``repro.plan.explain``)
+    all decide with this one function."""
+    if in_quant is not None or int(num_shards) > 1:
+        return False
+    if bcs and any(bc is not None and bc[0] == "periodic" for bc in bcs):
+        return False
+    d = len(shape)
+    for i, (n, t, (lo, _)) in enumerate(zip(shape, tile, halo)):
+        g = axis_grain(i, d, dtype_bytes)
+        if int(n) % g:
+            return False
+        if -(-int(n) // int(t)) > 1 and (int(t) % g or int(lo) % g):
+            return False
+    return True
 
 
 def halo_from_offsets(

@@ -55,20 +55,27 @@ stage written back — at the stage dtype, while every stage still
 accumulates in f32.  A bf16 input window halves the streamed bytes (and
 the dtype-aware planner doubles the sublane grain to match).
 
-Boundary semantics match ``kernels.ref.stencil_ref``: the launch embeds
-each input in a zero-filled buffer (``launch_pads``/``embed_inputs``)
-with the window's low halo in front and, behind, the high halo plus the
-round-up to whole tiles (grids not divisible by the tile take this path).
-§13 boundary ops other than zero fill become in-kernel correction taps;
-only §15 periodic wrap fills ghost cells in the buffer.
+Boundary semantics match ``kernels.ref.stencil_ref``: every window cell
+outside the grid holds zero, and grids not divisible by the tile compute
+zeros in the round-up and trim them.  A **direct launch** (DESIGN.md
+§16, ``core.tiling.direct_input``) hands the caller's array to the
+kernel as it is; the kernel writes the zeros into its window itself and
+DMAs only the grid's cells.  Every other launch embeds each input in a
+zero-filled launch buffer (``launch_pads``/``embed_inputs``) with the
+window's low halo in front and, behind, the high halo plus the round-up
+to whole tiles: a §15 periodic wrap (whose ghost cells the buffer
+holds), a §15 int8 hand-off, a §10 sharded launch, and a grid or tiling
+off the DMA grain.  §13 boundary ops other than zero fill become
+in-kernel correction taps over the same zero-extended window.
 
 **On the chip** (Mosaic): every DMA moves whole (sublane, lane) grains —
 8/16/32 sublanes for 4/2/1-byte dtypes, 128 lanes — at offsets that are
 grain multiples.  So the VMEM window is the halo'd tile rounded up to the
 grain on the last two axes (``core.tiling.window_extents``, the same
 extents the planner charges), the logical window sits at its origin, and
-the launch buffer carries the trailing slack the last window reads; the
-taps never read the slack.  A tile off the grain with more than one tile
+a launch buffer carries the trailing slack the last window reads (a
+direct launch clips its DMAs to the grid instead); the taps never read
+the slack.  A tile off the grain with more than one tile
 along that axis is refused before compiling.  Each launch passes a
 scoped-VMEM limit derived from its buffers, the double-buffered output
 block and the body's f32 values, capped by the device's VMEM
@@ -101,6 +108,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.tiling import (  # shared with the planner
     axis_grain,
     chain_halo,
+    direct_input,
     dtype_itemsize,
     fused_stage_bytes,
     halo_from_offsets,
@@ -163,9 +171,36 @@ def _frontier_depth(stages, j, t_s, sweep, window_kind):
     return stages[j].ext[sweep]
 
 
+def _clip_runs(step, base, size, n, count):
+    """The static cases of one axis of a direct launch's DMA: for each
+    index g < ``count`` the axis range ``[g·step + base, +size)`` clipped
+    to the grid's ``[0, n)``, as runs ``(g0, g1, at, ext)`` of
+    consecutive indices that fetch ``ext`` cells (0: none) to offset
+    ``at`` of the range; the source starts at ``g·step + base + at``."""
+    runs = []
+    for g in range(count):
+        start = g * step + base
+        lo, hi = max(start, 0), min(start + size, n)
+        case = (lo - start, hi - lo) if hi > lo else (0, 0)
+        if runs and runs[-1][2:] == case:
+            runs[-1] = (runs[-1][0], g) + case
+        else:
+            runs.append((g, g) + case)
+    return runs
+
+
+def _when(cond, fn):
+    """Run ``fn`` under ``pl.when(cond)``; a ``None`` condition always
+    holds."""
+    if cond is None:
+        fn()
+    else:
+        pl.when(cond)(fn)
+
+
 def _sweep_kernel(
     offsets, weights, lo_w, hi_w, stages, tile, sweep, nswp, pipelined,
-    window_kind, n_true, in_quant, *refs
+    window_kind, n_true, in_quant, *refs, direct=False
 ):
     """Generic d-dim, p-RHS sweep kernel, optionally stage-chain fused.
 
@@ -180,6 +215,13 @@ def _sweep_kernel(
     double-buffered next-slab prefetch; frontiers are the ``T - 1``
     narrowing stage buffers holding the intermediate iterates, persisted
     across sweep steps (DESIGN.md §9).
+
+    ``direct`` (DESIGN.md §16): each x_hbm is the caller's array itself,
+    not a launch buffer.  The window keeps its layout; the kernel writes
+    its cells outside the grid as zeros and DMAs only the grid's own
+    cells.  Each DMA lands its cells ``lo_w mod grain`` before their
+    place on the last two axes (the chip's DMA lands only on the grain)
+    and a VMEM copy moves them home; the taps read the same window.
 
     ``stages`` is the static per-stage chain (``None`` = single
     application, possibly multi-RHS).  ``window_kind`` sizes the
@@ -260,7 +302,12 @@ def _sweep_kernel(
             slab_sem.at[a, slot],
         )
 
-    if not reuse:
+    if direct:
+        _direct_loads(
+            x_hbm, windows, slabs, win_sem, slab_sem if pipelined else None,
+            gids, k, tile, sweep, nswp, lo_w, n_true, reuse,
+        )
+    elif not reuse:
         # No overlap to reuse (h_s == 0 or a single sweep step): every step
         # fetches its full window.
         for cp in window_load(k):
@@ -598,6 +645,231 @@ def _sweep_kernel(
             streaming_step()
 
 
+def _direct_loads(x_hbm, windows, slabs, win_sem, slab_sem, gids, k, tile,
+                  sweep, nswp, lo_w, n_true, reuse):
+    """The window fill of a direct launch (DESIGN.md §16): the same
+    windows, slabs and sweep-step order as the launch-buffer path, filled
+    from the caller's arrays.
+
+    Every DMA fetches only cells inside the grid.  On an axis in one tile
+    the window's low halo is off the grid, so a DMA lands the cells
+    ``r = lo_w mod grain`` early (on the grain), and a VMEM copy moves
+    them home (``r`` is 0 elsewhere: ``core.tiling.direct_input`` admits
+    a split axis only behind a whole-grain low halo).  Zeros cover the
+    rest of the window: once per sweep column around the cells the first
+    fill lands, on the strip a move leaves behind, and on the landing
+    rows of sweep steps past the grid's end — the VMEM shift keeps them.
+    Static cases (``_clip_runs``) select each DMA's extents by tile and
+    step index, so a start and its wait always agree."""
+    d = len(tile)
+    p = len(windows)
+    cross_axes = [i for i in range(d) if i != sweep]
+    win_ext = tuple(windows[0].shape)
+    t_s = tile[sweep]
+    w_s = win_ext[sweep]
+    keep = w_s - t_s
+    item = x_hbm[0].dtype.itemsize
+    sft = tuple(lo % axis_grain(i, d, item) for i, lo in enumerate(lo_w))
+
+    def span(b):
+        return tuple(pl.ds(a, n) for a, n in b)
+
+    def rows(start, size):
+        return span([
+            (start, size) if i == sweep else (0, e)
+            for i, e in enumerate(win_ext)
+        ])
+
+    def landed(box):
+        return [(a - r, n) for (a, n), r in zip(box, sft)]
+
+    def hull(box):  # the landed cells and their home
+        return [(a - r, n + r) for (a, n), r in zip(box, sft)]
+
+    # Per cross axis: the runs of tile indices whose window holds the
+    # same grid cells, as (condition, source start, (home, size)).
+    cross_opts = []
+    for j, i in enumerate(cross_axes):
+        nt = -(-n_true[i] // tile[i])
+        opts = []
+        for g0, g1, at, n in _clip_runs(
+            tile[i], -lo_w[i], win_ext[i], n_true[i], nt
+        ):
+            cond = (
+                None if (g0, g1) == (0, nt - 1)
+                else (gids[j] >= g0) & (gids[j] <= g1)
+            )
+            opts.append((cond, gids[j] * tile[i] - lo_w[i] + at, (at, n)))
+        cross_opts.append(opts)
+
+    def all_of(conds):
+        conds = [c for c in conds if c is not None]
+        return functools.reduce(jnp.logical_and, conds) if conds else None
+
+    def cases(kk, r0, size, d0):
+        """Sweep rows ``[kk·t_s + r0 − lo_s, +size)`` of the tile's
+        window, homed at row ``d0``: ``(condition, source, home box)``
+        per static case; rows off the grid are not fetched."""
+        sweep_opts = []
+        for g0, g1, at, n in _clip_runs(
+            t_s, r0 - lo_w[sweep], size, n_true[sweep], nswp
+        ):
+            if n == 0:
+                continue
+            if isinstance(kk, int):
+                if not g0 <= kk <= g1:
+                    continue
+                cond = None
+            elif (g0, g1) == (0, nswp - 1):
+                cond = None
+            else:
+                cond = (kk >= g0) & (kk <= g1)
+            sweep_opts.append(
+                (cond, kk * t_s + r0 - lo_w[sweep] + at, (d0 + at, n))
+            )
+        out = []
+        for combo in itertools.product(sweep_opts, *cross_opts):
+            src = [None] * d
+            box = [None] * d
+            for i, (_, start, b) in zip([sweep] + cross_axes, combo):
+                src[i] = pl.ds(start, b[1])
+                box[i] = b
+            out.append((all_of(c for c, _, _ in combo), tuple(src), box))
+        return out
+
+    def copy(a, src, dst, sem):
+        return pltpu.make_async_copy(x_hbm[a].at[src], dst, sem)
+
+    def zeros_over(ref, box):
+        ref[span(box)] = jnp.zeros(tuple(n for _, n in box), ref.dtype)
+
+    def settle(ref, box):
+        """Move the cells landed early home and zero the strip left."""
+        if not any(sft):
+            return
+        ref[span(box)] = ref[span(landed(box))]
+        for i, r in enumerate(sft):
+            if r:
+                strip = hull(box)
+                strip[i] = (box[i][0] - r, r)
+                zeros_over(ref, strip)
+
+    def window_load(kk):
+        """Fill whole windows: zeros around the landing hull, then the
+        DMAs; the caller waits and settles."""
+        loads = []
+        for cond, src, box in cases(kk, 0, w_s, 0):
+            around = hull(box)
+            for a in range(p):
+                cp = copy(a, src, windows[a].at[span(landed(box))],
+                          win_sem.at[a])
+
+                def start(a=a, cp=cp, around=around):
+                    for i in range(d):
+                        lo, n = around[i]
+                        for at, size in ((0, lo),
+                                         (lo + n, win_ext[i] - lo - n)):
+                            if size > 0:
+                                part = [
+                                    around[j] if j < i else (at, size)
+                                    if j == i else (0, win_ext[j])
+                                    for j in range(d)
+                                ]
+                                zeros_over(windows[a], part)
+                    cp.start()
+
+                _when(cond, start)
+                loads.append((cond, a, cp, box))
+        return loads
+
+    def finish(loads):
+        for cond, a, cp, box in loads:
+            def done(a=a, cp=cp, box=box):
+                cp.wait()
+                settle(windows[a], box)
+            _when(cond, done)
+
+    def slab_copies(a, kk, slot):
+        return [
+            (cond, copy(a, src, slabs[a].at[(slot,) + span(landed(box))],
+                        slab_sem.at[a, slot]))
+            for cond, src, box in cases(kk, keep, t_s, 0)
+        ]
+
+    def run(copies, op):
+        for cond, cp in copies:
+            _when(cond, getattr(cp, op))
+
+    def zero_rows_past_grid():
+        """Zeros over landing rows whose source lies past the grid's end
+        (a sweep column's tail): they hold the last step's rows."""
+        for g0, g1, _, n in _clip_runs(
+            t_s, keep - lo_w[sweep], t_s, n_true[sweep], nswp
+        ):
+            if n == t_s or g1 < 1:
+                continue
+
+            def body(n=n):
+                for a in range(p):
+                    zeros_over(windows[a], [
+                        (keep + n, t_s - n) if i == sweep else (0, e)
+                        for i, e in enumerate(win_ext)
+                    ])
+
+            _when((k >= g0) & (k <= g1), body)
+
+    if not reuse:
+        finish(window_load(k))
+        return
+
+    @pl.when(k == 0)
+    def _():
+        loads = window_load(0)
+        if slabs is not None:
+            for a in range(p):  # prefetch step 1's slab during compute
+                run(slab_copies(a, 1, 1), "start")
+        finish(loads)
+
+    @pl.when(k > 0)
+    def _():
+        for a in range(p):  # the scanning-face reuse, as on the buffer
+            windows[a][rows(0, keep)] = windows[a][rows(t_s, keep)]
+        if slabs is not None:
+            for a in range(p):
+                run(slab_copies(a, k, k % 2), "wait")
+
+            @pl.when(k + 1 < nswp)
+            def _():
+                for a in range(p):
+                    run(slab_copies(a, k + 1, (k + 1) % 2), "start")
+
+            # Only the slab's cells inside the grid land, moved home: the
+            # window's zero margins across stay as they are.
+            for combo in itertools.product(*cross_opts):
+                box = [(keep, t_s)] * d
+                for i, (_, _, b) in zip(cross_axes, combo):
+                    box[i] = b
+                src = [(0, t_s)] * d
+                for i in cross_axes:
+                    src[i] = landed(box)[i]
+
+                def land(box=box, src=src):
+                    for a in range(p):
+                        windows[a][span(box)] = slabs[a][(k % 2,) + span(src)]
+
+                _when(all_of(c for c, _, _ in combo), land)
+        else:
+            loads = []
+            for cond, src, box in cases(k, keep, t_s, keep):
+                for a in range(p):
+                    cp = copy(a, src, windows[a].at[span(landed(box))],
+                              win_sem.at[a])
+                    _when(cond, cp.start)
+                    loads.append((cond, a, cp, box))
+            finish(loads)
+        zero_rows_past_grid()
+
+
 def _launch_geometry(offsets_w, stages_w, tile, bcs_w=None, dtypes_w=None,
                      quants_w=None):
     """Static launch geometry shared by the single-device and sharded
@@ -661,7 +933,7 @@ def _launch_geometry(offsets_w, stages_w, tile, bcs_w=None, dtypes_w=None,
 
 def _padded_call(ins, dom, offsets, weights, stages, lo_w, hi_w, tile,
                  sweep, pipelined, interpret, n_true,
-                 window_kind="ring", in_quant=None):
+                 window_kind="ring", in_quant=None, direct=False):
     """Run the sweep kernel over already-padded arrays and return the
     *padded* result (``∏ ntiles_i · tile_i`` per dim, no trim).
 
@@ -671,7 +943,11 @@ def _padded_call(ins, dom, offsets, weights, stages, lo_w, hi_w, tile,
     traced ``(d,)`` int32 true-grid coordinate of local element 0 (zeros
     on a single device) and ``n_true`` the *global* unpadded grid shape —
     together they keep the intermediate-stage domain masks global under
-    ``shard_map``."""
+    ``shard_map``.
+
+    ``direct=True`` (DESIGN.md §16; only for a launch that
+    ``core.tiling.direct_input`` admits) takes the caller's arrays as
+    they are instead, and the kernel fills the windows' zeros itself."""
     d = len(tile)
     p = len(ins)
     T = 1 if stages is None else len(stages)
@@ -679,9 +955,12 @@ def _padded_call(ins, dom, offsets, weights, stages, lo_w, hi_w, tile,
     window_shape = window_extents(
         tile, list(zip(lo_w, hi_w)), u0.dtype.itemsize
     )
-    ntiles = tuple(
-        (u0.shape[i] - window_shape[i]) // tile[i] + 1 for i in range(d)
-    )
+    if direct:
+        ntiles = tuple(-(-int(n) // t) for n, t in zip(u0.shape, tile))
+    else:
+        ntiles = tuple(
+            (u0.shape[i] - window_shape[i]) // tile[i] + 1 for i in range(d)
+        )
     if not interpret:
         _check_dma_grain(tile, ntiles, u0.dtype.itemsize)
     nswp = ntiles[sweep]
@@ -727,7 +1006,7 @@ def _padded_call(ins, dom, offsets, weights, stages, lo_w, hi_w, tile,
         functools.partial(
             _sweep_kernel, offsets, weights, lo_w, hi_w, stages, tile,
             sweep, nswp, pipelined, window_kind,
-            tuple(int(n) for n in n_true), in_quant,
+            tuple(int(n) for n in n_true), in_quant, direct=direct,
         ),
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
@@ -782,6 +1061,22 @@ def _check_dma_grain(tile, ntiles, itemsize):
                 f"grain on axis {i} ({n} tiles along it): the chip's DMA "
                 "cannot address it; use an aligned tile or interpret mode"
             )
+
+
+def input_buffer(shape, tile, halo, itemsize, bcs_w=None, in_quant=None,
+                 num_shards=1):
+    """What one launch reads its input from: ``"direct"``, the caller's
+    array as it is (``core.tiling.direct_input``, DESIGN.md §16), or a
+    launch buffer built by ``"wrap"`` (§15 periodic ghost fill),
+    ``"embed"`` (zeros plus one update, §13 boundary programs) or
+    ``"pad"`` (``jnp.pad``)."""
+    if direct_input(shape, tile, halo, itemsize, bcs_w, in_quant,
+                    num_shards):
+        return "direct"
+    bcs = [bc for bc in (bcs_w or ()) if bc is not None]
+    if any(bc[0] == "periodic" for bc in bcs):
+        return "wrap"
+    return "embed" if bcs else "pad"
 
 
 def launch_pads(shape, tile, lo_w, hi_w, itemsize):
@@ -889,27 +1184,35 @@ def _stencil_call(us, offsets_w, tile, sweep, pipelined, interpret,
     (tuple per stage, ``None``/``(scale, zero_point)``) quantizes each
     stage's stored output onto the affine int8 grid, and ``in_quant``
     declares the launch *input*'s quantization when it is a quantized
-    inter-launch handoff (§15)."""
+    inter-launch handoff (§15).
+
+    A launch that ``core.tiling.direct_input`` admits hands the caller's
+    arrays to the kernel as they are (DESIGN.md §16); every other one
+    builds the launch buffer ``input_buffer`` names first."""
     u0 = us[0]
     d = u0.ndim
     tile = tuple(int(t) for t in tile)
     offsets, weights, stages, lo_w, hi_w = _launch_geometry(
         offsets_w, stages_w, tile, bcs_w, dtypes_w, quants_w
     )
-    pads = launch_pads(u0.shape, tile, lo_w, hi_w, u0.dtype.itemsize)
-    periodic = bcs_w is not None and any(
-        bc is not None and bc[0] == "periodic" for bc in bcs_w
+    buf = input_buffer(
+        u0.shape, tile, list(zip(lo_w, hi_w)), u0.dtype.itemsize, bcs_w,
+        in_quant,
     )
-    ins = embed_inputs(
-        us, pads,
-        pad_free=bcs_w is not None and any(bc is not None for bc in bcs_w),
-        wrap=tuple(zip(lo_w, hi_w)) if periodic else None,
-        fill=int(in_quant[1]) if in_quant is not None else 0,
-    )
+    if buf == "direct":
+        ins = us
+    else:
+        ins = embed_inputs(
+            us, launch_pads(u0.shape, tile, lo_w, hi_w, u0.dtype.itemsize),
+            pad_free=buf != "pad",
+            wrap=tuple(zip(lo_w, hi_w)) if buf == "wrap" else None,
+            fill=int(in_quant[1]) if in_quant is not None else 0,
+        )
     out = _padded_call(
         ins, jnp.zeros((d,), jnp.int32), offsets, weights, stages, lo_w,
         hi_w, tile, sweep, pipelined, interpret, u0.shape,
         window_kind=window_kind, in_quant=in_quant,
+        direct=buf == "direct",
     )
     with jax.named_scope("stencil_trim"):
         return out[tuple(slice(0, n) for n in u0.shape)]
@@ -1420,14 +1723,17 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
         offs, wts = op
         return (tuple(map(tuple, np.asarray(offs).tolist())), tuple(wts))
 
-    def launch_span(n_run, run=None, run_dts=None, run_qs=None):
+    def launch_span(n_run, run=None, run_dts=None, run_qs=None, x=None,
+                    bcs_w=None, in_q=None):
         """The ``kernel_launch`` span of one launch: it times the host's
         side only — the launcher lookup (the sharded path's mesh and
         jitted launch) and the jitted call until it returns the unready
         array, not the device's run.  With a recorder it also prices
         this launch's slice of the plan's whole-chain model (n_run of T
-        stages) and bumps the counters the report CLI reconciles against
-        the spans; a profiler alone gets the bare span."""
+        stages), names what the launch reads its input ``x`` from
+        (``input_buffer``) and bumps the counters the report CLI
+        reconciles against the spans; a profiler alone gets the bare
+        span."""
         if not obs.enabled():
             return obs.span("kernel_launch")
         p = resolved_plan
@@ -1462,7 +1768,17 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
         quantized = run_qs is not None and any(
             q is not None for q in run_qs
         )
+        x = us[0] if x is None else x
+        halo = (
+            chain_halo([halo_from_offsets([o], d) for o, _ in run])
+            if run is not None
+            else halo_from_offsets(offsets_list, d)
+        )
+        buf = input_buffer(
+            x.shape, tile, halo, x.dtype.itemsize, bcs_w, in_q, num_shards
+        )
         obs.add("launches")
+        obs.add("direct_input_launches", int(buf == "direct"))
         obs.add("modeled_bytes", mb)
         obs.add("modeled_flops", mf)
         obs.add("ring_vmem_bytes", rvb)
@@ -1472,6 +1788,7 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
             fused_depth=int(depth), steps=n_run, num_shards=num_shards,
             interpret=interpret, modeled_bytes=mb, modeled_flops=mf,
             program=prog_summary, window_kind=window_kind,
+            input_buffer=buf,
             stage_dtypes=(list(run_dts) if run_dts is not None else None),
             ring_vmem_bytes=rvb,
             stage_quants=(
@@ -1500,9 +1817,11 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
         )
         run_qs = tuple(chain_quants[pos : pos + len(run)])
         pos += len(run)
-        with launch_span(len(run), run, run_dts, run_qs):
+        bcs_w = run_bcs if any(bc is not None for bc in run_bcs) else None
+        with launch_span(len(run), run, run_dts, run_qs, arrays[0], bcs_w,
+                         in_q):
             launch = launcher()
-            if any(bc is not None for bc in run_bcs) or run_dts is not None:
+            if bcs_w is not None or run_dts is not None:
                 # §13 boundary-op / §14 mixed-dtype / §15 quantized
                 # launch: always the stage-chain form (even for one
                 # stage), with the lowered per-stage bcs as in-kernel
@@ -1515,9 +1834,7 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
                     arrays, (static_spec(run[0]),), tile, sweep_axis,
                     pipelined, interpret,
                     stages_w=tuple(static_spec(op) for op in run),
-                    bcs_w=run_bcs if any(
-                        bc is not None for bc in run_bcs
-                    ) else None,
+                    bcs_w=bcs_w,
                     dtypes_w=run_dts,
                     window_kind=window_kind,
                     quants_w=run_qs if any(

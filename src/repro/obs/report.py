@@ -4,7 +4,8 @@ Reads a ``trace_event`` JSON file written by :mod:`repro.obs` and prints
 the evidence trail the paper's model promises (DESIGN.md §12):
 
 * a per-launch reconciliation table — plan key, fused depth, shard
-  count, tile, window kind, modeled bytes — one row per
+  count, tile, window kind, input buffer (``direct`` or the launch
+  buffer's kind), modeled bytes — one row per
   ``kernel_launch`` span.  That span times the host's enqueue, not the
   device's run, so the table shows no wall time or bandwidth: device
   time comes from a ``jax.profiler`` trace, read by the benchmark's
@@ -17,8 +18,9 @@ the ``launches`` counter matches the number of launch spans, the summed
 per-span ``modeled_bytes`` match the ``modeled_bytes`` counter, the
 summed per-span ``ring_vmem_bytes`` (§14 staged-frontier VMEM at each
 stage's own dtype; 0 on pre-v6 traces) match the ``ring_vmem_bytes``
-counter, and the summed ``measure`` span nanoseconds match
-``measured_ns`` — exiting non-zero on any mismatch.  This is what the
+counter, the ``direct_input_launches`` counter matches the launch
+spans with ``input_buffer=direct``, and the summed ``measure`` span
+nanoseconds match ``measured_ns`` — exiting non-zero on any mismatch.  This is what the
 CI obs smoke runs.
 """
 
@@ -74,6 +76,9 @@ def summarize(doc: dict) -> dict[str, Any]:
             "window_kind": args.get("window_kind"),
             "stage_dtypes": args.get("stage_dtypes"),
             "ring_vmem_bytes": int(args.get("ring_vmem_bytes", 0)),
+            # What the launch read its input from (DESIGN.md §16);
+            # absent in traces that predate the direct launch.
+            "input_buffer": args.get("input_buffer"),
         })
     races = []
     for ev in _spans(doc, "tune_race"):
@@ -138,6 +143,13 @@ def reconcile(summary: dict[str, Any]) -> list[str]:
             f"ring_vmem_bytes counter={c.get('ring_vmem_bytes', 0)} but "
             f"launch spans sum to {span_ring}"
         )
+    n_direct = sum(1 for l in launches if l["input_buffer"] == "direct")
+    if n_direct != int(c.get("direct_input_launches", 0)):
+        problems.append(
+            f"direct_input_launches counter="
+            f"{c.get('direct_input_launches', 0)} but {n_direct} launch "
+            f"spans read their input directly"
+        )
     if summary["measure_ns_total"] != int(c.get("measured_ns", 0)):
         problems.append(
             f"measured_ns counter={c.get('measured_ns', 0)} but measure "
@@ -160,17 +172,18 @@ def render(summary: dict[str, Any]) -> str:
     if launches:
         hdr = (
             f"{'#':>3}  {'plan key':<14} {'T':>3} {'shards':>6} "
-            f"{'tile':<14} {'win':<5} {'ring vmem':>10} "
+            f"{'tile':<14} {'win':<5} {'input':<6} {'ring vmem':>10} "
             f"{'modeled':>12}"
         )
         lines += [hdr, "-" * len(hdr)]
         for i, l in enumerate(launches):
             tile = "x".join(map(str, l["tile"])) if l["tile"] else "-"
             wk = (l.get("window_kind") or "-")[:5]
+            buf = (l.get("input_buffer") or "-")[:6]
             lines.append(
                 f"{i:>3}  {l['plan_key'][:14]:<14} "
                 f"{l['fused_depth'] or 1:>3} {l['num_shards'] or 1:>6} "
-                f"{tile:<14} {wk:<5} "
+                f"{tile:<14} {wk:<5} {buf:<6} "
                 f"{_fmt_bytes(l['ring_vmem_bytes']):>10} "
                 f"{_fmt_bytes(l['modeled_bytes']):>12}"
             )

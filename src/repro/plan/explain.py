@@ -64,6 +64,30 @@ def _fmt_bytes(b: float) -> str:
     return f"{b:.0f} B"
 
 
+def launch_input(plan: StencilPlan) -> str:
+    """``"direct"`` when the plan's first launch reads the caller's array
+    as it is (``core.tiling.direct_input``, DESIGN.md §16), else
+    ``"buffer"``: it reads a zero-filled launch buffer."""
+    from repro.core.tiling import (
+        chain_halo, direct_input, halo_from_offsets,
+    )
+
+    req = plan.request
+    d = len(req.shape)
+    if req.stages:
+        halo = chain_halo([
+            halo_from_offsets([st.offsets], d)
+            for st in req.stages[: plan.fused_depth]
+        ])
+    else:
+        halo = halo_from_offsets(req.offsets, d)
+    direct = direct_input(
+        req.shape, plan.tile, halo, req.dtype_bytes, bcs=req.bcs,
+        num_shards=plan.num_shards,
+    )
+    return "direct" if direct else "buffer"
+
+
 def format_plan(plan: StencilPlan, validation: dict | None = None) -> str:
     req = plan.request
     lines = [
@@ -96,6 +120,11 @@ def format_plan(plan: StencilPlan, validation: dict | None = None) -> str:
         f"    why: {plan.pad.reason}",
         f"  tile: {plan.tile}  sweep axis {plan.sweep_axis}  "
         f"grid {plan.grid}  pipelined {plan.pipelined}",
+        "  input: " + (
+            "direct (the kernel reads the caller's array, §16)"
+            if launch_input(plan) == "direct"
+            else "launch buffer (zero-filled copy of the grid)"
+        ),
     ]
     if plan.time_steps > 1:
         n_launch = -(-plan.time_steps // plan.fused_depth)
@@ -225,6 +254,7 @@ def plan_json_doc(plan: StencilPlan) -> dict:
             "efficiency": plan.efficiency,
             "window_kind": plan.window_kind,
             "stage_dtypes": [st.dtype for st in plan.request.stages] or None,
+            "input": launch_input(plan),
         },
     }
 

@@ -91,6 +91,10 @@ def _scopes(text):
     return scopes, kernels
 
 
+def _pad_ops(text):
+    return re.findall(r"= \S+ pad\(", text)
+
+
 def _compile(fn, arg):
     with obs.recording() as rec:
         compiled = jax.jit(fn).lower(arg).compile()
@@ -98,11 +102,16 @@ def _compile(fn, arg):
     assert "tpu_custom_call" in text
     launches = [s for s in rec.spans if s.name == "kernel_launch"]
     assert launches and not any(s.args["interpret"] for s in launches)
-    # The kernel is named, and the launch buffer's ops carry their scope
-    # into the compiled program (a profiler trace reads both).
+    # The kernel is named, and a launch buffer's ops carry their scope
+    # into the compiled program (a profiler trace reads both); a program
+    # whose launches all read the caller's array builds no buffer.
     scopes, kernels = _scopes(text)
     assert kernels and all(k.startswith("stencil_sweep") for k in kernels)
-    assert {"stencil_embed", "stencil_sweep"} <= scopes
+    assert "stencil_sweep" in scopes
+    if all(s.args["input_buffer"] == "direct" for s in launches):
+        assert "stencil_embed" not in scopes and not _pad_ops(text)
+    else:
+        assert "stencil_embed" in scopes
     return text, launches
 
 
@@ -117,6 +126,40 @@ def test_one_chip_phase_compiles(chip, case):
         # The fused stage-chain kernel really runs: one launch, depth >= 2.
         assert len(launches) == 1
         assert launches[0].args["fused_depth"] >= 2
+
+
+# The star13_512 cells' launches, straight from the caller's array: the
+# single application at tile (1, 512, 512) and the fused depth-4 f32 ring
+# at (1, 256, 512) (~3 min to compile).
+DIRECT = {
+    "star_512_single": (
+        (1, 512, 512),
+        lambda u: stencil_pallas(
+            u, STAR, STAR_W, tile=(1, 512, 512), sweep_axis=0,
+            interpret=False,
+        ),
+    ),
+    "star_512_ring_T4": (
+        (1, 256, 512),
+        lambda u: stencil_iterate(
+            u, STAR, STAR_W, 4, tile=(1, 256, 512), sweep_axis=0,
+            window_kind="ring", interpret=False,
+        ),
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DIRECT))
+def test_direct_launch_compiles(chip, case):
+    tile, fn = DIRECT[case]
+    arg = jax.ShapeDtypeStruct(
+        (512, 512, 512), jnp.float32,
+        sharding=SingleDeviceSharding(chip.devices[0]),
+    )
+    text, launches = _compile(fn, arg)
+    assert [s.args["input_buffer"] for s in launches] == ["direct"]
+    assert launches[0].args["tile"] == list(tile)
+    assert not _pad_ops(text) and "dynamic-update-slice" not in text
 
 
 # The fused T=3 sharded launch compiles in ~2 min at 512^3 (its per-step
