@@ -86,6 +86,7 @@ __all__ = [
     "dtype_itemsize",
     "fused_halo",
     "fused_stage_bytes",
+    "grid_slack",
     "halo_from_offsets",
     "kernel_vmem_bytes",
     "stage_suffix_halos",
@@ -200,6 +201,23 @@ def window_extents(
     return tuple(ext)
 
 
+def grid_slack(shape: Sequence[int], dtype_bytes: int) -> tuple[int, int]:
+    """``(sublanes, lanes)``: on each of the grid's last two axes, the
+    cells between the grid's end and the end of its last (sublane, lane)
+    grain (:func:`axis_grain`), which a grain-rounded window reaching the
+    grid's end covers and the output never holds.  ``(0, 0)`` for a grid
+    of whole grains; a 1-d grid has no sublane axis.  A grid with slack
+    launches from a buffer (:func:`direct_input`); the launcher's
+    ``kernel_launch`` span and the plan report (``repro.plan.explain``)
+    both give this figure."""
+    d = len(shape)
+    slack = [0, 0]
+    for i in range(max(d - 2, 0), d):
+        g = axis_grain(i, d, dtype_bytes)
+        slack[i - d + 2] = -int(shape[i]) % g
+    return tuple(slack)
+
+
 def direct_input(
     shape: Sequence[int],
     tile: Sequence[int],
@@ -221,10 +239,11 @@ def direct_input(
       exchange;
     * every DMA from the array stays on the (sublane, lane) grain
       (:func:`axis_grain`): each of the last two axes is a whole number
-      of grains, and an axis split into several tiles is split at grain
-      multiples behind a low halo of whole grains.  (An axis in one tile
-      lands its cells up to a grain early and the kernel shifts them in
-      VMEM.)
+      of grains (no :func:`grid_slack`: the chip's compiler slices an
+      array only in whole grains, even along a whole axis), and an axis
+      split into several tiles is split at grain multiples behind a low
+      halo of whole grains.  (An axis in one tile lands its cells up to
+      a grain early and the kernel shifts them in VMEM.)
 
     The window is the one :func:`window_extents` gives either way, so
     the planner's VMEM charge holds for both.  The launcher, its
@@ -234,11 +253,11 @@ def direct_input(
         return False
     if bcs and any(bc is not None and bc[0] == "periodic" for bc in bcs):
         return False
+    if any(grid_slack(shape, dtype_bytes)):
+        return False
     d = len(shape)
     for i, (n, t, (lo, _)) in enumerate(zip(shape, tile, halo)):
         g = axis_grain(i, d, dtype_bytes)
-        if int(n) % g:
-            return False
         if -(-int(n) // int(t)) > 1 and (int(t) % g or int(lo) % g):
             return False
     return True

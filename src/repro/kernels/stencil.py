@@ -75,7 +75,10 @@ grain on the last two axes (``core.tiling.window_extents``, the same
 extents the planner charges), the logical window sits at its origin, and
 a launch buffer carries the trailing slack the last window reads (a
 direct launch clips its DMAs to the grid instead); the taps never read
-the slack.  A tile off the grain with more than one tile
+the slack.  A grid off the grain (``core.tiling.grid_slack``) launches
+from the buffer, which is whole grains: the chip slices an array only
+in whole grains.  A tile off the grain is its axis's only tile, and its
+DMA offset on that axis is the constant 0; one with more than one tile
 along that axis is refused before compiling.  Each launch passes a
 scoped-VMEM limit derived from its buffers, the double-buffered output
 block and the body's f32 values, capped by the device's VMEM
@@ -111,6 +114,7 @@ from repro.core.tiling import (  # shared with the planner
     direct_input,
     dtype_itemsize,
     fused_stage_bytes,
+    grid_slack,
     halo_from_offsets,
     kernel_vmem_bytes,
     stage_suffix_halos,
@@ -267,14 +271,26 @@ def _sweep_kernel(
     win_ext = tuple(windows[0].shape)
     w_s = win_ext[sweep]
     keep = w_s - t_s
+    item = x_hbm[0].dtype.itemsize
+
+    def tile_start(g, i):
+        """Where tile ``g`` of axis ``i`` starts in the launch buffer.  A
+        tile off the axis's grain is the axis's only tile (on the chip,
+        ``_check_dma_grain``), so its start is the constant 0: the chip's
+        compiler must prove a DMA offset a multiple of the grain, which
+        ``g · tile`` is not."""
+        alone = x_hbm[0].shape[i] - win_ext[i] < tile[i]
+        if alone and tile[i] % axis_grain(i, d, item):
+            return 0
+        return g * tile[i]
 
     def src_index(kk, start, size):
         """HBM index tuple for rows [kk*t_s+start, +size) of the sweep axis
         and the full window cross extents of the current tile."""
         idx = [None] * d
         for j, i in enumerate(cross_axes):
-            idx[i] = pl.ds(gids[j] * tile[i], win_ext[i])
-        idx[sweep] = pl.ds(kk * t_s + start, size)
+            idx[i] = pl.ds(tile_start(gids[j], i), win_ext[i])
+        idx[sweep] = pl.ds(tile_start(kk, sweep) + start, size)
         return tuple(idx)
 
     def win_part(start, size):
@@ -1220,7 +1236,7 @@ def _stencil_call(us, offsets_w, tile, sweep, pipelined, interpret,
 
 def _auto_tile(shape, offsets_list, dtype_bytes, n_arrays, vmem_budget=None,
                time_steps=1, stages=None, num_shards=1, tune=None,
-               bcs=None, dtypes=None, window_kind="auto"):
+               bcs=None, dtypes=None, window_kind="auto", shard_axis=None):
     """Tile decision for an un-planned call: a thin wrapper over the plan
     compiler (``repro.plan``), whose persistent cache makes repeated shapes
     — the serving case — O(1).  The old ad-hoc heuristic survives as
@@ -1235,7 +1251,11 @@ def _auto_tile(shape, offsets_list, dtype_bytes, n_arrays, vmem_budget=None,
     ``tune`` (``True`` or an ``AutoTuner``) routes the decision through
     the §11 measured-cost loop instead: a warm TunedPlanDB hit serves the
     measured winner, a miss races the top-k candidates on the live
-    backend first (``repro.plan.tune``)."""
+    backend first (``repro.plan.tune``).
+
+    ``shard_axis`` is the caller's pinned partition axis of a sharded
+    call: the tile is planned for that axis's column slab
+    (``Planner.plan_along``), not for the planner's own choice of axis."""
     from repro.plan import default_planner, resolve_tuner
 
     d = len(shape)
@@ -1259,6 +1279,8 @@ def _auto_tile(shape, offsets_list, dtype_bytes, n_arrays, vmem_budget=None,
     tuner = resolve_tuner(tune)
     if tuner is not None:
         return tuner.plan(**kw)
+    if shard_axis is not None and num_shards > 1:
+        return default_planner().plan_along(shard_axis, **kw)
     return default_planner().plan(**kw)
 
 
@@ -1665,6 +1687,7 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
             bcs=bcs if chain is not None else None,
             dtypes=req_dtypes if chain is not None else None,
             window_kind=window_kind or "auto",
+            shard_axis=shard_axis,
         )
         tile = choice.tile
         if sweep_axis is None:
@@ -1731,9 +1754,10 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
         array, not the device's run.  With a recorder it also prices
         this launch's slice of the plan's whole-chain model (n_run of T
         stages), names what the launch reads its input ``x`` from
-        (``input_buffer``) and bumps the counters the report CLI
-        reconciles against the spans; a profiler alone gets the bare
-        span."""
+        (``input_buffer``) and how far ``x`` stops short of whole
+        (sublane, lane) grains (``grid_slack``), and bumps the counters
+        the report CLI reconciles against the spans; a profiler alone
+        gets the bare span."""
         if not obs.enabled():
             return obs.span("kernel_launch")
         p = resolved_plan
@@ -1777,8 +1801,10 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
         buf = input_buffer(
             x.shape, tile, halo, x.dtype.itemsize, bcs_w, in_q, num_shards
         )
+        slack = grid_slack(x.shape, x.dtype.itemsize)
         obs.add("launches")
         obs.add("direct_input_launches", int(buf == "direct"))
+        obs.add("offgrain_launches", int(any(slack)))
         obs.add("modeled_bytes", mb)
         obs.add("modeled_flops", mf)
         obs.add("ring_vmem_bytes", rvb)
@@ -1788,7 +1814,7 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
             fused_depth=int(depth), steps=n_run, num_shards=num_shards,
             interpret=interpret, modeled_bytes=mb, modeled_flops=mf,
             program=prog_summary, window_kind=window_kind,
-            input_buffer=buf,
+            input_buffer=buf, grid_slack=list(slack),
             stage_dtypes=(list(run_dts) if run_dts is not None else None),
             ring_vmem_bytes=rvb,
             stage_quants=(

@@ -5,7 +5,8 @@ the evidence trail the paper's model promises (DESIGN.md §12):
 
 * a per-launch reconciliation table — plan key, fused depth, shard
   count, tile, window kind, input buffer (``direct`` or the launch
-  buffer's kind), modeled bytes — one row per
+  buffer's kind), grid slack (sublanes x lanes past the grid's end in
+  its last grain), modeled bytes — one row per
   ``kernel_launch`` span.  That span times the host's enqueue, not the
   device's run, so the table shows no wall time or bandwidth: device
   time comes from a ``jax.profiler`` trace, read by the benchmark's
@@ -19,7 +20,9 @@ per-span ``modeled_bytes`` match the ``modeled_bytes`` counter, the
 summed per-span ``ring_vmem_bytes`` (§14 staged-frontier VMEM at each
 stage's own dtype; 0 on pre-v6 traces) match the ``ring_vmem_bytes``
 counter, the ``direct_input_launches`` counter matches the launch
-spans with ``input_buffer=direct``, and the summed ``measure`` span
+spans with ``input_buffer=direct``, the ``offgrain_launches`` counter
+matches the launch spans whose ``grid_slack`` is not ``(0, 0)`` (a
+grid off the (sublane, lane) grain), and the summed ``measure`` span
 nanoseconds match ``measured_ns`` — exiting non-zero on any mismatch.  This is what the
 CI obs smoke runs.
 """
@@ -79,6 +82,9 @@ def summarize(doc: dict) -> dict[str, Any]:
             # What the launch read its input from (DESIGN.md §16);
             # absent in traces that predate the direct launch.
             "input_buffer": args.get("input_buffer"),
+            # Cells past the grid's end in its last (sublane, lane)
+            # grain; absent in traces that predate off-grain launches.
+            "grid_slack": args.get("grid_slack"),
         })
     races = []
     for ev in _spans(doc, "tune_race"):
@@ -150,6 +156,12 @@ def reconcile(summary: dict[str, Any]) -> list[str]:
             f"{c.get('direct_input_launches', 0)} but {n_direct} launch "
             f"spans read their input directly"
         )
+    n_off = sum(1 for l in launches if any(l["grid_slack"] or ()))
+    if n_off != int(c.get("offgrain_launches", 0)):
+        problems.append(
+            f"offgrain_launches counter={c.get('offgrain_launches', 0)} "
+            f"but {n_off} launch spans have grid slack"
+        )
     if summary["measure_ns_total"] != int(c.get("measured_ns", 0)):
         problems.append(
             f"measured_ns counter={c.get('measured_ns', 0)} but measure "
@@ -172,18 +184,20 @@ def render(summary: dict[str, Any]) -> str:
     if launches:
         hdr = (
             f"{'#':>3}  {'plan key':<14} {'T':>3} {'shards':>6} "
-            f"{'tile':<14} {'win':<5} {'input':<6} {'ring vmem':>10} "
-            f"{'modeled':>12}"
+            f"{'tile':<14} {'win':<5} {'input':<6} {'slack':<7} "
+            f"{'ring vmem':>10} {'modeled':>12}"
         )
         lines += [hdr, "-" * len(hdr)]
         for i, l in enumerate(launches):
             tile = "x".join(map(str, l["tile"])) if l["tile"] else "-"
             wk = (l.get("window_kind") or "-")[:5]
             buf = (l.get("input_buffer") or "-")[:6]
+            slack = l.get("grid_slack")
+            slack = "x".join(map(str, slack)) if slack else "-"
             lines.append(
                 f"{i:>3}  {l['plan_key'][:14]:<14} "
                 f"{l['fused_depth'] or 1:>3} {l['num_shards'] or 1:>6} "
-                f"{tile:<14} {wk:<5} {buf:<6} "
+                f"{tile:<14} {wk:<5} {buf:<6} {slack:<7} "
                 f"{_fmt_bytes(l['ring_vmem_bytes']):>10} "
                 f"{_fmt_bytes(l['modeled_bytes']):>12}"
             )

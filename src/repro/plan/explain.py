@@ -31,6 +31,7 @@ import sys
 import numpy as np
 
 from repro.core.cache_fitting import box_stencil, star_stencil
+from repro.core.tiling import grid_slack
 
 from .cache import PlanCache
 from .planner import Planner
@@ -90,6 +91,7 @@ def launch_input(plan: StencilPlan) -> str:
 
 def format_plan(plan: StencilPlan, validation: dict | None = None) -> str:
     req = plan.request
+    slack = grid_slack(req.shape, req.dtype_bytes)
     lines = [
         f"plan for grid {req.shape}  (dtype {req.dtype_bytes} B, "
         f"{len(req.offsets)} RHS, budget {_fmt_bytes(req.vmem_budget)}, "
@@ -124,6 +126,11 @@ def format_plan(plan: StencilPlan, validation: dict | None = None) -> str:
             "direct (the kernel reads the caller's array, §16)"
             if launch_input(plan) == "direct"
             else "launch buffer (zero-filled copy of the grid)"
+        ),
+        f"  grid slack: {slack[0]} sublanes x {slack[1]} lanes past the "
+        "grid's end in its last grain" + (
+            " (off the grain: the chip slices the array only in whole "
+            "grains, §16)" if any(slack) else ""
         ),
     ]
     if plan.time_steps > 1:
@@ -255,6 +262,8 @@ def plan_json_doc(plan: StencilPlan) -> dict:
             "window_kind": plan.window_kind,
             "stage_dtypes": [st.dtype for st in plan.request.stages] or None,
             "input": launch_input(plan),
+            "grid_slack": list(grid_slack(plan.request.shape,
+                                          plan.request.dtype_bytes)),
         },
     }
 

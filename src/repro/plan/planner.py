@@ -175,6 +175,7 @@ class Planner:
         self.last_plan_seconds: float | None = None  # cold-vs-warm telemetry
         self.last_plan_tuned: bool = False           # did a tuned entry win?
         self.last_plan_cache: str | None = None      # hit / miss / tuned
+        self._along: dict = {}  # (request, pinned shard axis) -> plan
 
     # -- cheap diagnostics (no tile search) --------------------------------
 
@@ -452,8 +453,24 @@ class Planner:
             out.append(self._freeze(sv, int(depth), c, priced))
         return out
 
-    def _compile(self, request: PlanRequest) -> StencilPlan:
-        sv = self._survey(request)
+    def plan_along(self, shard_axis: int, **kw) -> StencilPlan:
+        """:meth:`plan` for a sharded request whose caller pinned the
+        partitioned axis (§10): the cached plan when it splits
+        ``shard_axis`` already, else the same search over that axis's
+        column slab.  The request's cache key stands for the planner's
+        own axis, so those plans are memoized in this planner only."""
+        plan = self.plan(**kw)
+        if plan.shard_axis in (None, int(shard_axis)):
+            return plan
+        key = (plan.request, int(shard_axis))
+        if key not in self._along:
+            self._along[key] = self._compile(plan.request, int(shard_axis))
+        return self._along[key]
+
+    def _compile(
+        self, request: PlanRequest, shard_axis: int | None = None
+    ) -> StencilPlan:
+        sv = self._survey(request, shard_axis_override=shard_axis)
         single_total = sv.scored[1][0]
         # Shallower wins ties: same modeled traffic, smaller VMEM webs and
         # fewer staged buffers.

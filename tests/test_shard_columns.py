@@ -171,6 +171,25 @@ def test_unshardable_grid_rejected_upfront():
         )
 
 
+def test_pinned_shard_axis_plans_its_own_slab():
+    """A caller's shard axis reaches the planner: the tile is planned
+    for that axis's slab, not for the axis the planner would split (the
+    200-lane axis here, in four 50-lane tiles that the chip's DMA cannot
+    address)."""
+    planner = Planner(cache=PlanCache(persistent=False))
+    kw = dict(shape=(16, 20, 200), offsets=star_stencil(3, 2),
+              num_shards=4, aligned=True)
+    own = planner.plan(**kw)
+    assert own.shard_axis == 2 and own.tile[2] == 50
+    pinned = planner.plan_along(0, **kw)
+    assert pinned.shard_axis == 0 and pinned.request == own.request
+    assert pinned.tile[0] == 4 and pinned.tile[2] == 200
+    assert pinned.sweep_axis != 0
+    assert planner.plan_along(0, **kw) is pinned
+    assert planner.plan_along(2, **kw) is own
+    assert planner.plan(**kw) is own  # the cache keeps the planner's own
+
+
 def test_mesh_axis_name_shares_cache_key():
     """mesh_axis is display-only: requests differing only in the axis
     name must share one plan-cache key."""

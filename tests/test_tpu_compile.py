@@ -22,7 +22,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-from repro import obs
+from repro import ir, obs
 from repro.core.cache_fitting import star_stencil
 from repro.kernels.ref import star_weights_2nd_order
 from repro.kernels.stencil import stencil_iterate, stencil_pallas
@@ -208,6 +208,68 @@ def test_four_chip_periodic_program_scopes(chip):
     permutes = re.findall(r'collective-permute-start[^\n]*op_name="([^"]*)"',
                           text)
     assert permutes and all("/stencil_halo/" in p for p in permutes)
+
+
+# Grids off the (sublane, lane) grain: PolyBench jacobi-2d's one sweep
+# at EXTRALARGE (2800^2, the jacobi2d_2800.step1 cell's run_program) and
+# LARGE (1300^2), the paper's 511^3 star, and 512 x 510 x 510 and
+# 16 x 20 x 200 stars split four ways along axis 0.  Each reads a
+# launch buffer (DESIGN.md §16): the chip slices an array only in whole
+# grains.
+POLYBENCH_W = [0.2] * 5
+OFF_GRAIN = {
+    "jacobi_2d_2800": (
+        (2800, 2800), 1,
+        lambda u, mesh: ir.run_program(
+            ir.stencil_program(JACOBI_2D, POLYBENCH_W, 1, d=2), u,
+            interpret=False,
+        ),
+    ),
+    "jacobi_2d_1300": (
+        (1300, 1300), 1,
+        lambda u, mesh: stencil_pallas(
+            u, JACOBI_2D, POLYBENCH_W, interpret=False
+        ),
+    ),
+    "star_511": (
+        (511, 511, 511), 1,
+        lambda u, mesh: stencil_pallas(u, STAR, STAR_W, interpret=False),
+    ),
+    "star_512x510x510_4chip": (
+        (512, 510, 510), 4,
+        lambda u, mesh: stencil_pallas(
+            u, STAR, STAR_W, mesh=mesh, shard_axis=0, interpret=False
+        ),
+    ),
+    # The planner alone would split the 200 lanes into 50-lane slabs.
+    "star_16x20x200_4chip": (
+        (16, 20, 200), 4,
+        lambda u, mesh: stencil_pallas(
+            u, STAR, STAR_W, mesh=mesh, shard_axis=0, interpret=False
+        ),
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(OFF_GRAIN))
+def test_off_grain_grid_compiles(chip, case):
+    from repro.core.tiling import grid_slack
+
+    shape, chips, fn = OFF_GRAIN[case]
+    if chips > 1:
+        mesh = make_column_mesh(chips, devices=chip.devices)
+        sharding = NamedSharding(mesh, P("columns"))
+    else:
+        mesh = None
+        sharding = SingleDeviceSharding(chip.devices[0])
+    arg = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+    text, launches = _compile(lambda u: fn(u, mesh), arg)
+    slack = list(grid_slack(shape, 4))
+    assert any(slack)
+    for s in launches:
+        assert s.args["grid_slack"] == slack
+        assert s.args["input_buffer"] == "pad"
+        assert s.args["num_shards"] == chips
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
