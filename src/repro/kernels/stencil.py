@@ -100,6 +100,9 @@ from __future__ import annotations
 
 import functools
 import itertools
+import threading
+import weakref
+from collections import OrderedDict
 from typing import TYPE_CHECKING, NamedTuple, Sequence
 
 import jax
@@ -1481,19 +1484,26 @@ def multi_stencil_pallas(
     ``trace="path.json"`` records this call into a Chrome ``trace_event``
     file (see :mod:`repro.obs`).
 
-    Each call is one ``stencil_call`` span (numbered ``call=<n>``) over
-    ``ir_lower`` (program build, lowering, summary), the planner's
-    ``plan`` and one ``kernel_launch`` per launch, recorded by a
-    :mod:`repro.obs` recorder or, with none, sent to a collecting
-    ``jax.profiler`` session."""
+    The launches a call resolves to depend only on its static signature
+    (operator, shapes, dtypes, keywords), so the entry memoizes them
+    (DESIGN.md §17): a repeated signature skips lowering and planning
+    and goes straight to the bound launches.  ``tune=`` calls bypass the
+    memo.
+
+    Each call is one ``stencil_call`` span (numbered ``call=<n>``,
+    ``memo=hit|miss``).  A miss holds ``ir_lower`` (program build,
+    lowering, summary), the planner's ``plan`` and one ``kernel_launch``
+    per launch; a hit holds the ``kernel_launch`` spans alone.  The spans
+    are recorded by a :mod:`repro.obs` recorder or, with none, sent to a
+    collecting ``jax.profiler`` session."""
     args = (us, offsets_list, weights_list, tile, interpret, vmem_budget,
             sweep_axis, pipelined, plan, time_steps, stages, num_shards,
             shard_axis, mesh, tune, program, dtypes, window_kind)
     if trace is not None:
-        with obs.recording(trace), obs.call_span("stencil_call"):
-            return _stencil_entry(*args)
-    with obs.call_span("stencil_call"):
-        return _stencil_entry(*args)
+        with obs.recording(trace), obs.call_span("stencil_call") as root:
+            return _stencil_entry(root, *args)
+    with obs.call_span("stencil_call") as root:
+        return _stencil_entry(root, *args)
 
 
 def _build_program(us, offsets_list, weights_list, time_steps, stages,
@@ -1556,11 +1566,159 @@ def _build_program(us, offsets_list, weights_list, time_steps, stages,
     return ir.rhs_program(offsets_list, weights_list, d=d)
 
 
-def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
+# -- the entry memo (DESIGN.md §17) -------------------------------------------
+
+_MEMO_SIZE = 128  # resolved signatures kept, least recently used dropped
+_memo: OrderedDict = OrderedDict()
+_memo_lock = threading.Lock()
+
+
+class _Launch(NamedTuple):
+    """One resolved launch: ``call(arrays)`` runs it with every static
+    argument bound; ``args`` and ``counters`` are what a recorder gets
+    for it (the ``kernel_launch`` span's arguments, the counter bumps)."""
+
+    call: object
+    args: dict
+    counters: tuple
+
+
+class _Entry(NamedTuple):
+    """A call signature's resolved launch sequence.  ``order`` permutes a
+    multi-RHS call's arrays into the lowered operand order; ``pins`` holds
+    the objects the key names by identity (plan, mesh, planner) so their
+    ids stay theirs while the entry lives; ``program`` is a weak reference
+    to a keyed :class:`repro.ir.Program`.  No caller array is held: the
+    arrays are arguments of the launches, never captured."""
+
+    launches: tuple
+    order: tuple | None
+    pins: tuple
+    program: object
+
+    def run(self, us):
+        if self.order is not None:
+            us = tuple(us[i] for i in self.order)
+        recording = obs.enabled()
+        for launch in self.launches:
+            if recording:
+                for name, value in launch.counters:
+                    obs.add(name, value)
+                span = obs.span("kernel_launch", **launch.args)
+            else:
+                span = obs.span("kernel_launch")
+            with span:
+                out = launch.call(us)
+            us = (out,)
+        return out
+
+
+def _offsets_key(offsets):
+    a = np.asarray(offsets)
+    return a.dtype.str, a.shape, a.tobytes()
+
+
+def _weights_key(weights):
+    return tuple(map(float, weights))
+
+
+def _memo_key(us, offsets_list, weights_list, tile, interpret, vmem_budget,
+              sweep_axis, pipelined, plan, time_steps, stages, num_shards,
+              shard_axis, mesh, program, dtypes, window_kind):
+    """Everything that decides a call's launches, cheap to hash: the
+    operator as given (a program by identity, checked against its weak
+    reference on a hit), the arrays' shapes and dtypes, every planning
+    keyword, ``plan`` and ``mesh`` by identity, and the default planner
+    with its generation."""
+    from repro.plan import default_planner
+
+    planner = default_planner()
+    return (
+        program if isinstance(program, str) else id(program),
+        None if offsets_list is None
+        else tuple(map(_offsets_key, offsets_list)),
+        None if weights_list is None
+        else tuple(map(_weights_key, weights_list)),
+        None if stages is None
+        else tuple((_offsets_key(o), _weights_key(w)) for o, w in stages),
+        time_steps,
+        None if dtypes is None else tuple(dtypes),
+        tuple((u.shape, u.dtype) for u in us),
+        None if tile is None else tuple(tile),
+        interpret, vmem_budget, sweep_axis, pipelined, num_shards,
+        shard_axis, window_kind, id(mesh), id(plan), id(planner),
+        planner.generation,
+    )
+
+
+def _memo_get(key, program):
+    with _memo_lock:
+        entry = _memo.get(key)
+        if entry is None:
+            return None
+        if entry.program is not None and entry.program() is not program:
+            del _memo[key]  # a freed program's id, reused
+            return None
+        _memo.move_to_end(key)
+        return entry
+
+
+def _memo_put(key, entry):
+    with _memo_lock:
+        _memo[key] = entry
+        _memo.move_to_end(key)
+        while len(_memo) > _MEMO_SIZE:
+            _memo.popitem(last=False)
+
+
+@functools.lru_cache(maxsize=128)
+def _bound_call(statics: tuple):
+    """``_stencil_call`` with its static arguments (``statics``, sorted
+    ``(name, value)`` pairs) bound once: the jitted dispatch then sees
+    only the arrays.  It traces the same function under the same name,
+    so it compiles what ``_stencil_call`` compiles."""
+    fn = _stencil_call.__wrapped__
+    bound = functools.partial(fn, **dict(statics))
+    return jax.jit(functools.update_wrapper(bound, fn))
+
+
+def _stencil_entry(root, us, offsets_list, weights_list, tile, interpret,
                    vmem_budget, sweep_axis, pipelined, plan, time_steps,
                    stages, num_shards, shard_axis, mesh, tune, program,
                    dtypes, window_kind):
-    """The body of :func:`multi_stencil_pallas`: lower, plan, launch."""
+    """The body of :func:`multi_stencil_pallas`: on a memo hit, the bound
+    launches; on a miss (and on every ``tune=`` call, since the tuner
+    measures), lower, plan and bind them first.  ``root`` is the call's
+    ``stencil_call`` span, which gets ``memo=hit|miss``."""
+    us = tuple(us)
+    interpret = resolve_interpret(interpret, kernel="stencil")
+    key = entry = None
+    if not tune:
+        key = _memo_key(
+            us, offsets_list, weights_list, tile, interpret, vmem_budget,
+            sweep_axis, pipelined, plan, time_steps, stages, num_shards,
+            shard_axis, mesh, program, dtypes, window_kind,
+        )
+        entry = _memo_get(key, program)
+    outcome = "miss" if entry is None else "hit"
+    obs.add("entry_memo_" + outcome)
+    if root is not obs.NULL_SPAN:
+        root.set(memo=outcome)
+    if entry is None:
+        entry = _resolve(
+            us, offsets_list, weights_list, tile, interpret, vmem_budget,
+            sweep_axis, pipelined, plan, time_steps, stages, num_shards,
+            shard_axis, mesh, tune, program, dtypes, window_kind,
+        )
+        if key is not None:
+            _memo_put(key, entry)
+    return entry.run(us)
+
+
+def _resolve(us, offsets_list, weights_list, tile, interpret, vmem_budget,
+             sweep_axis, pipelined, plan, time_steps, stages, num_shards,
+             shard_axis, mesh, tune, program, dtypes, window_kind):
+    """Lower, plan and bind one call signature: its :class:`_Entry`."""
     if window_kind is not None and window_kind not in ("ring", "trapezoid"):
         raise ValueError(
             f"window_kind must be 'ring' or 'trapezoid', got {window_kind!r}"
@@ -1570,16 +1728,17 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
             str(jnp.dtype(dt).name) if dt is not None else None
             for dt in dtypes
         )
-    us = tuple(us)
     assert len({u.shape for u in us}) == 1, "RHS arrays must share a shape"
     d = us[0].ndim
     shape = tuple(int(n) for n in us[0].shape)
+    in_name = str(jnp.dtype(us[0].dtype).name)
     # -- build the stencil program (§13), verify + lower it ----------------
     with obs.span("ir_lower"):
         prog = _build_program(us, offsets_list, weights_list, time_steps,
                               stages, program, dtypes)
         lowered = ir.lower(prog, shape)
         prog_summary = ir.summarize_program(prog)
+    order = None
     if lowered.kind == "chain":
         if len(us) != 1:
             raise ValueError(
@@ -1598,7 +1757,6 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
         # ``eff`` holds concrete names for the kernel/launch handoffs,
         # ``req_dtypes`` the None-normalized form the plan stack keys on
         # (a stage at the input dtype is the same request as no dtype).
-        in_name = str(jnp.dtype(us[0].dtype).name)
         chain_dtypes = tuple(lowered.dtypes) if lowered.dtypes else (None,) * T
         assert len(chain_dtypes) == T, (chain_dtypes, T)
         # §15 per-stage quantizations: execution parameters (not part of
@@ -1625,7 +1783,10 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
         # ``us`` arrives in load order; the combine may sum the operands
         # in any order, and stage p applies to lowered.inputs[p].
         load_order = {name: i for i, name in enumerate(prog.inputs())}
-        us = tuple(us[load_order[name]] for name in lowered.inputs)
+        order = tuple(load_order[name] for name in lowered.inputs)
+        us = tuple(us[i] for i in order)
+        if order == tuple(range(len(us))):
+            order = None
         chain = None
         bcs = ()
         T = 1
@@ -1636,7 +1797,6 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
             for o, _ in lowered.stages
         ]
         weights_list = [list(wts) for _, wts in lowered.stages]
-    interpret = resolve_interpret(interpret, kernel="stencil")
     explicit_sweep = sweep_axis is not None
     explicit_shard = shard_axis is not None
     if num_shards is None:
@@ -1708,9 +1868,10 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
     sweep_axis = int(sweep_axis)
     pipelined = bool(pipelined)
     num_shards = 1 if num_shards is None else int(num_shards)
+    sharded = num_shards > 1 or mesh is not None
 
     if (
-        (num_shards > 1 or mesh is not None)
+        sharded
         and shard_axis is not None
         and int(shard_axis) == sweep_axis
         and explicit_shard != explicit_sweep
@@ -1733,46 +1894,41 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
 
             shard_axis = pick_shard_axis(us[0].shape, tile, sweep_axis)
 
-    def launcher():
-        if num_shards > 1 or mesh is not None:
-            from repro.parallel.shard_columns import column_launcher
+    p = resolved_plan
+    if p is not None:
+        chain_bytes = (
+            p.per_shard_traffic_bytes * p.num_shards + p.halo_exchange_bytes
+        )
+        plan_key = p.request.cache_key()
+    else:
+        plan_key = "<explicit-tile>"  # explicit tile: the caller owns the model
 
-            return column_launcher(
-                num_shards=num_shards, shard_axis=shard_axis, mesh=mesh,
+    def bind(statics, n_run, x_dtype, run=None, run_dts=None, run_qs=None,
+             bcs_w=None, in_q=None):
+        """One launch, bound: the sharded path's built program or the
+        single-device bound call; and what a recorder gets for it — this
+        launch's slice of the plan's whole-chain model (n_run of T
+        stages), what it reads its input from (``input_buffer``), how far
+        the input stops short of whole (sublane, lane) grains
+        (``grid_slack``), and the counters the report CLI reconciles
+        against the spans.  The ``kernel_launch`` span times the host's
+        side only: the bound call until it returns the unready array."""
+        xbytes = None
+        if sharded:
+            from repro.parallel.shard_columns import column_launch
+
+            call, xbytes = column_launch(
+                shape, x_dtype, len(us), num_shards=num_shards,
+                shard_axis=shard_axis, mesh=mesh, **statics,
             )
-        return _stencil_call
-
-    def static_spec(op):
-        offs, wts = op
-        return (tuple(map(tuple, np.asarray(offs).tolist())), tuple(wts))
-
-    def launch_span(n_run, run=None, run_dts=None, run_qs=None, x=None,
-                    bcs_w=None, in_q=None):
-        """The ``kernel_launch`` span of one launch: it times the host's
-        side only — the launcher lookup (the sharded path's mesh and
-        jitted launch) and the jitted call until it returns the unready
-        array, not the device's run.  With a recorder it also prices
-        this launch's slice of the plan's whole-chain model (n_run of T
-        stages), names what the launch reads its input ``x`` from
-        (``input_buffer``) and how far ``x`` stops short of whole
-        (sublane, lane) grains (``grid_slack``), and bumps the counters
-        the report CLI reconciles against the spans; a profiler alone
-        gets the bare span."""
-        if not obs.enabled():
-            return obs.span("kernel_launch")
-        p = resolved_plan
+        else:
+            call = _bound_call(tuple(sorted(statics.items())))
         if p is not None:
-            chain_bytes = (
-                p.per_shard_traffic_bytes * p.num_shards
-                + p.halo_exchange_bytes
-            )
             n_stages = max(len(chain) if chain is not None else 1, 1)
             mb = round(chain_bytes * n_run / n_stages)
             mf = round(p.modeled_flops * n_run / n_stages)
-            plan_key = p.request.cache_key()
         else:
-            mb = mf = 0  # explicit tile: the caller owns the model
-            plan_key = "<explicit-tile>"
+            mb = mf = 0
         # §14 frontier accounting: the modeled VMEM bytes of this
         # launch's staged buffers under the resolved window kind, at each
         # stage's own dtype — reconciled by ``repro.obs.report --check``.
@@ -1792,24 +1948,28 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
         quantized = run_qs is not None and any(
             q is not None for q in run_qs
         )
-        x = us[0] if x is None else x
+        x_db = jnp.dtype(x_dtype).itemsize
         halo = (
             chain_halo([halo_from_offsets([o], d) for o, _ in run])
             if run is not None
             else halo_from_offsets(offsets_list, d)
         )
-        buf = input_buffer(
-            x.shape, tile, halo, x.dtype.itemsize, bcs_w, in_q, num_shards
+        buf = input_buffer(shape, tile, halo, x_db, bcs_w, in_q, num_shards)
+        slack = grid_slack(shape, x_db)
+        counters = (
+            ("launches", 1),
+            ("direct_input_launches", int(buf == "direct")),
+            ("offgrain_launches", int(any(slack))),
+            ("modeled_bytes", mb),
+            ("modeled_flops", mf),
+            ("ring_vmem_bytes", rvb),
         )
-        slack = grid_slack(x.shape, x.dtype.itemsize)
-        obs.add("launches")
-        obs.add("direct_input_launches", int(buf == "direct"))
-        obs.add("offgrain_launches", int(any(slack)))
-        obs.add("modeled_bytes", mb)
-        obs.add("modeled_flops", mf)
-        obs.add("ring_vmem_bytes", rvb)
-        return obs.span(
-            "kernel_launch",
+        if xbytes is not None:
+            # The exchange runs inside the jitted SPMD program (its device
+            # time is the ``stencil_halo`` scope of a profiler trace), so
+            # the counter holds the modeled cross-device bytes.
+            counters += (("halo_exchange_bytes", xbytes),)
+        args = dict(
             plan_key=plan_key, tile=list(tile), sweep_axis=sweep_axis,
             fused_depth=int(depth), steps=n_run, num_shards=num_shards,
             interpret=interpret, modeled_bytes=mb, modeled_flops=mf,
@@ -1822,31 +1982,36 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
                 if quantized else None
             ),
         )
+        return _Launch(call, args, counters)
 
+    def static_spec(op):
+        offs, wts = op
+        return (tuple(map(tuple, np.asarray(offs).tolist())), tuple(wts))
+
+    base = dict(tile=tile, sweep=sweep_axis, pipelined=pipelined,
+                interpret=interpret)
+    launches = []
     if chain is None:  # multi-RHS single application
         offsets_w = tuple(
             static_spec((o, tuple(float(w) for w in ws)))
             for o, ws in zip(offsets_list, weights_list)
         )
-        with launch_span(1):
-            return launcher()(
-                us, offsets_w, tile, sweep_axis, pipelined, interpret,
+        launches.append(bind(dict(base, offsets_w=offsets_w), 1,
+                             str(jnp.dtype(us[0].dtype).name)))
+    else:
+        pos = 0
+        in_q = None
+        x_dtype = in_name
+        while pos < len(chain):
+            run = chain[pos : pos + int(depth)]
+            run_bcs = tuple(bcs[pos : pos + len(run)])
+            run_dts = (
+                tuple(eff[pos : pos + len(run)]) if eff is not None else None
             )
-    arrays = us
-    pos = 0
-    in_q = None
-    while True:
-        run = chain[pos : pos + int(depth)]
-        run_bcs = tuple(bcs[pos : pos + len(run)])
-        run_dts = (
-            tuple(eff[pos : pos + len(run)]) if eff is not None else None
-        )
-        run_qs = tuple(chain_quants[pos : pos + len(run)])
-        pos += len(run)
-        bcs_w = run_bcs if any(bc is not None for bc in run_bcs) else None
-        with launch_span(len(run), run, run_dts, run_qs, arrays[0], bcs_w,
-                         in_q):
-            launch = launcher()
+            run_qs = tuple(chain_quants[pos : pos + len(run)])
+            pos += len(run)
+            bcs_w = run_bcs if any(bc is not None for bc in run_bcs) else None
+            statics = dict(base, offsets_w=(static_spec(run[0]),))
             if bcs_w is not None or run_dts is not None:
                 # §13 boundary-op / §14 mixed-dtype / §15 quantized
                 # launch: always the stage-chain form (even for one
@@ -1856,9 +2021,7 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
                 # the chain forces eff non-None (its dtype is int8), so
                 # every launch of such a chain takes this branch and the
                 # quantized inter-launch handoff (``in_q``) is threaded.
-                result = launch(
-                    arrays, (static_spec(run[0]),), tile, sweep_axis,
-                    pipelined, interpret,
+                statics.update(
                     stages_w=tuple(static_spec(op) for op in run),
                     bcs_w=bcs_w,
                     dtypes_w=run_dts,
@@ -1868,19 +2031,25 @@ def _stencil_entry(us, offsets_list, weights_list, tile, interpret,
                     ) else None,
                     in_quant=in_q,
                 )
-            elif len(run) == 1:
-                result = launch(
-                    arrays, (static_spec(run[0]),), tile, sweep_axis,
-                    pipelined, interpret,
-                )
-            else:
-                result = launch(
-                    arrays, (static_spec(run[0]),), tile, sweep_axis,
-                    pipelined, interpret,
+            elif len(run) > 1:
+                statics.update(
                     stages_w=tuple(static_spec(op) for op in run),
                     window_kind=window_kind,
                 )
-        if pos == len(chain):
-            return result
-        arrays = (result,)
-        in_q = run_qs[-1]
+            launches.append(bind(statics, len(run), x_dtype, run, run_dts,
+                                 run_qs, bcs_w, in_q))
+            # The next launch reads this one's output, at its last stage's
+            # dtype.
+            if run_dts is not None:
+                x_dtype = run_dts[-1]
+            in_q = run_qs[-1]
+    from repro.plan import default_planner
+
+    return _Entry(
+        launches=tuple(launches),
+        order=order,
+        pins=(plan, mesh, default_planner()),
+        program=(
+            weakref.ref(program) if isinstance(program, ir.Program) else None
+        ),
+    )

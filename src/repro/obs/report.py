@@ -22,8 +22,11 @@ stage's own dtype; 0 on pre-v6 traces) match the ``ring_vmem_bytes``
 counter, the ``direct_input_launches`` counter matches the launch
 spans with ``input_buffer=direct``, the ``offgrain_launches`` counter
 matches the launch spans whose ``grid_slack`` is not ``(0, 0)`` (a
-grid off the (sublane, lane) grain), and the summed ``measure`` span
-nanoseconds match ``measured_ns`` — exiting non-zero on any mismatch.  This is what the
+grid off the (sublane, lane) grain), the ``entry_memo_hit`` and
+``entry_memo_miss`` counters match the ``stencil_call`` roots carrying
+``memo=hit`` and ``memo=miss`` (the kernel entry's memo, DESIGN.md
+§17), and the summed ``measure`` span nanoseconds match
+``measured_ns`` — exiting non-zero on any mismatch.  This is what the
 CI obs smoke runs.
 """
 
@@ -107,12 +110,19 @@ def summarize(doc: dict) -> dict[str, Any]:
             "dur_us": float(ev.get("dur", 0.0)),
         })
     measures = _spans(doc, "measure")
+    memo = {"hit": 0, "miss": 0}
+    for ev in _spans(doc, "stencil_call"):
+        outcome = (ev.get("args") or {}).get("memo")
+        if outcome in memo:
+            memo[outcome] += 1
     return {
         "counters": counters,
         "launches": launches,
         "races": races,
         "candidates": candidates,
         "n_plan_spans": len(_spans(doc, "plan")),
+        # The kernel entry's memo outcome per ``stencil_call`` root (§17).
+        "memo": memo,
         "n_measure_spans": len(measures),
         "measure_ns_total": int(
             sum((m.get("args") or {}).get("measured_ns", 0) for m in measures)
@@ -162,6 +172,13 @@ def reconcile(summary: dict[str, Any]) -> list[str]:
             f"offgrain_launches counter={c.get('offgrain_launches', 0)} "
             f"but {n_off} launch spans have grid slack"
         )
+    for outcome, n in summary["memo"].items():
+        name = f"entry_memo_{outcome}"
+        if n != int(c.get(name, 0)):
+            problems.append(
+                f"{name} counter={c.get(name, 0)} but {n} stencil_call "
+                f"spans carry memo={outcome}"
+            )
     if summary["measure_ns_total"] != int(c.get("measured_ns", 0)):
         problems.append(
             f"measured_ns counter={c.get('measured_ns', 0)} but measure "
@@ -226,7 +243,9 @@ def render(summary: dict[str, Any]) -> str:
         )
     lines.append(
         f"spans: plan={summary['n_plan_spans']} "
-        f"measure={summary['n_measure_spans']}"
+        f"measure={summary['n_measure_spans']} "
+        f"calls: memo hit={summary['memo']['hit']} "
+        f"miss={summary['memo']['miss']}"
     )
     counters = summary["counters"]
     if counters:

@@ -1,8 +1,4 @@
-from .shard_columns import (  # noqa: F401
-    column_launcher,
-    pick_shard_axis,
-    sharded_stencil_call,
-)
+from .shard_columns import column_launch, pick_shard_axis  # noqa: F401
 from .sharding import (  # noqa: F401
     LOGICAL_RULES,
     ParamSpec,

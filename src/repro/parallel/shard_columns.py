@@ -49,9 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .. import obs
-
-__all__ = ["column_launcher", "pick_shard_axis", "sharded_stencil_call"]
+__all__ = ["column_launch", "pick_shard_axis"]
 
 
 def pick_shard_axis(shape, tile, sweep_axis) -> int:
@@ -69,53 +67,26 @@ def pick_shard_axis(shape, tile, sweep_axis) -> int:
     return max(cross, key=lambda i: (ncols[i], -i))
 
 
-def column_launcher(num_shards=None, shard_axis=None, mesh=None):
-    """A drop-in for ``kernels.stencil._stencil_call`` that runs every
-    launch column-sharded — what ``multi_stencil_pallas`` substitutes
-    when the call (or its plan) asks for more than one shard."""
-
-    def launch(us, offsets_w, tile, sweep, pipelined, interpret,
-               stages_w=None, bcs_w=None, dtypes_w=None,
-               window_kind="ring", quants_w=None, in_quant=None):
-        return sharded_stencil_call(
-            us, offsets_w, tile, sweep, pipelined, interpret,
-            stages_w=stages_w, bcs_w=bcs_w, dtypes_w=dtypes_w,
-            window_kind=window_kind, quants_w=quants_w, in_quant=in_quant,
-            num_shards=num_shards, shard_axis=shard_axis, mesh=mesh,
-        )
-
-    return launch
-
-
-def sharded_stencil_call(
-    us, offsets_w, tile, sweep, pipelined, interpret, stages_w=None,
-    bcs_w=None, dtypes_w=None, window_kind="ring", quants_w=None,
-    in_quant=None, num_shards=None, shard_axis=None, mesh=None,
-):
-    """One column-sharded launch; signature and result match
-    ``_stencil_call`` exactly (bit-wise).  ``mesh`` must be a 1-axis
+def column_launch(shape, dtype, p, num_shards=None, shard_axis=None,
+                  mesh=None, **statics):
+    """Resolve one column-sharded launch of ``p`` arrays of ``shape`` and
+    ``dtype``; ``statics`` are ``kernels.stencil._stencil_call``'s static
+    arguments.  Returns ``(call, exchange_bytes)``: ``call(us)`` runs the
+    launch with every static argument bound, bit-wise equal to
+    ``_stencil_call``, and ``exchange_bytes`` is its modeled
+    cross-device halo traffic — ``None`` for a 1-shard request, which
+    falls back to the single-device call.  ``mesh`` must be a 1-axis
     mesh; ``mesh=None`` builds one over the first ``num_shards`` devices
-    (:func:`repro.launch.mesh.make_column_mesh`).  A 1-shard request
-    falls back to the plain single-device call."""
-    from repro.kernels.stencil import _stencil_call
+    (:func:`repro.launch.mesh.make_column_mesh`).  The kernel entry keeps
+    the call in its memo (DESIGN.md §17), so a repeated signature builds
+    no mesh and hashes no static tuple."""
+    from repro.kernels.stencil import _bound_call
 
-    us = tuple(us)
-    u0 = us[0]
-    d = u0.ndim
-    tile = tuple(int(t) for t in tile)
-    sweep = int(sweep)
     if mesh is None:
-        num_shards = 1 if num_shards is None else int(num_shards)
-        if num_shards == 1:
-            return _stencil_call(
-                us, offsets_w, tile, sweep, pipelined, interpret,
-                stages_w=stages_w, bcs_w=bcs_w, dtypes_w=dtypes_w,
-                window_kind=window_kind, quants_w=quants_w,
-                in_quant=in_quant,
-            )
-        from repro.launch.mesh import make_column_mesh
+        if num_shards is not None and int(num_shards) > 1:
+            from repro.launch.mesh import make_column_mesh
 
-        mesh = make_column_mesh(num_shards)
+            mesh = make_column_mesh(int(num_shards))
     else:
         size = int(prod(mesh.shape[a] for a in mesh.axis_names))
         if num_shards is not None and int(num_shards) != size:
@@ -128,14 +99,15 @@ def sharded_stencil_call(
                 f"{mesh.axis_names}"
             )
         if size == 1:
-            return _stencil_call(
-                us, offsets_w, tile, sweep, pipelined, interpret,
-                stages_w=stages_w, bcs_w=bcs_w, dtypes_w=dtypes_w,
-                window_kind=window_kind, quants_w=quants_w,
-                in_quant=in_quant,
-            )
+            mesh = None
+    if mesh is None:
+        return _bound_call(tuple(sorted(statics.items()))), None
+    shape = tuple(int(n) for n in shape)
+    d = len(shape)
+    tile = tuple(int(t) for t in statics["tile"])
+    sweep = int(statics["sweep"])
     if shard_axis is None:
-        shard_axis = pick_shard_axis(u0.shape, tile, sweep)
+        shard_axis = pick_shard_axis(shape, tile, sweep)
     a = int(shard_axis)
     if not 0 <= a < d:
         raise ValueError(f"shard_axis {a} out of range for {d}-d grid")
@@ -144,33 +116,31 @@ def sharded_stencil_call(
             f"shard_axis {a} is the sweep axis: columns are partitioned "
             "across the sweep, not along it"
         )
+    offsets_w = statics["offsets_w"]
+    stages_w = statics.get("stages_w")
+    bcs_w = statics.get("bcs_w")
     run = _build_sharded(
-        mesh, a, tile, sweep, bool(pipelined), bool(interpret), offsets_w,
-        stages_w, bcs_w, dtypes_w, str(window_kind), quants_w, in_quant,
-        tuple(int(n) for n in u0.shape), str(u0.dtype), len(us),
+        mesh, a, tile, sweep, bool(statics["pipelined"]),
+        bool(statics["interpret"]), offsets_w, stages_w, bcs_w,
+        statics.get("dtypes_w"), str(statics.get("window_kind", "ring")),
+        statics.get("quants_w"), statics.get("in_quant"), shape, str(dtype),
+        p,
     )
-    if obs.enabled():
-        # The exchange itself runs inside the jitted SPMD program (its
-        # device time is the ``stencil_halo`` scope of a profiler trace),
-        # so the Python layer counts the *modeled* cross-device bytes per
-        # launch (same arithmetic as _build_sharded).
-        from repro.kernels.stencil import _launch_geometry, _round_up
+    # The modeled cross-device bytes of one launch (same arithmetic as
+    # _build_sharded).
+    from repro.kernels.stencil import _launch_geometry, _round_up
 
-        S = int(mesh.shape[mesh.axis_names[0]])
-        *_, lo_w, hi_w = _launch_geometry(
-            offsets_w, stages_w, tile, bcs_w=bcs_w
-        )
-        lo_a, hi_a = int(lo_w[a]), int(hi_w[a])
-        padded = [_round_up(int(n), t) for n, t in zip(u0.shape, tile)]
-        cross_ext = prod(
-            padded[i] + lo_w[i] + hi_w[i] for i in range(d) if i != a
-        )
-        xbytes = (
-            len(us) * (S - 1) * (lo_a + hi_a) * cross_ext
-            * u0.dtype.itemsize
-        )
-        obs.add("halo_exchange_bytes", xbytes)
-    return run(*us)
+    S = int(mesh.shape[mesh.axis_names[0]])
+    *_, lo_w, hi_w = _launch_geometry(offsets_w, stages_w, tile, bcs_w=bcs_w)
+    lo_a, hi_a = int(lo_w[a]), int(hi_w[a])
+    padded = [_round_up(n, t) for n, t in zip(shape, tile)]
+    cross_ext = prod(
+        padded[i] + lo_w[i] + hi_w[i] for i in range(d) if i != a
+    )
+    xbytes = (
+        p * (S - 1) * (lo_a + hi_a) * cross_ext * jnp.dtype(dtype).itemsize
+    )
+    return run, xbytes
 
 
 @functools.lru_cache(maxsize=128)
@@ -316,7 +286,7 @@ def _build_sharded(mesh, a, tile, sweep, pipelined, interpret, offsets_w,
 
     sharding = NamedSharding(mesh, spec)
 
-    def run(*arrays):
+    def run(arrays):
         ins = embed_inputs(arrays, pads, pad_free=pad_free, wrap=wrap,
                            fill=fill)
         # Build the launch buffers shard by shard, each on its own device,

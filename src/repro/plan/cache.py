@@ -76,6 +76,9 @@ class PlanCache:
         self.dir = (cache_dir or default_cache_dir()) if persistent else None
         self._degraded = False
         self._mem: OrderedDict[str, StencilPlan] = OrderedDict()
+        # Bumped by clear(): a planner's generation (and so the kernel
+        # entry's memo of resolved launches) moves with it.
+        self.generation = 0
         self.stats = _Stats(self, {
             "hits": 0,
             "misses": 0,
@@ -201,6 +204,7 @@ class PlanCache:
             self._disable_disk(e)  # degrade to memory-only, log once
 
     def clear(self, disk: bool = False) -> None:
+        self.generation += 1
         self._mem.clear()
         if disk and self.dir is not None and os.path.isdir(self.dir):
             for name in os.listdir(self.dir):

@@ -166,6 +166,7 @@ class Planner:
     ):
         assert strategy in ("paper", "legacy"), strategy
         self.strategy = strategy
+        self._gen = 0
         self.cache = cache if cache is not None else PlanCache()
         # Optional repro.plan.tunedb.TunedPlanDB: when attached, plan()
         # prefers a measured winner recorded for this exact request on
@@ -176,6 +177,36 @@ class Planner:
         self.last_plan_tuned: bool = False           # did a tuned entry win?
         self.last_plan_cache: str | None = None      # hit / miss / tuned
         self._along: dict = {}  # (request, pinned shard axis) -> plan
+
+    # -- what may change a plan this planner serves --------------------------
+
+    @property
+    def cache(self) -> PlanCache:
+        return self._cache
+
+    @cache.setter
+    def cache(self, cache: PlanCache) -> None:
+        self._gen += 1
+        self._cache = cache
+
+    @property
+    def tuned_db(self):
+        return self._tuned_db
+
+    @tuned_db.setter
+    def tuned_db(self, db) -> None:
+        self._gen += 1
+        self._tuned_db = db
+
+    @property
+    def generation(self) -> tuple:
+        """Changes whenever a plan this planner serves may change: its
+        cache is swapped or cleared, or a tuned DB is attached or
+        written.  The kernel entry keys its memo of resolved launches
+        (DESIGN.md §17) on it."""
+        db = self._tuned_db
+        return (self._gen, self._cache.generation,
+                db.generation if db is not None else 0)
 
     # -- cheap diagnostics (no tile search) --------------------------------
 

@@ -237,6 +237,9 @@ class TunedPlanDB:
         self.dir = (db_dir or default_tuned_db_dir()) if persistent else None
         self._degraded = False
         self._mem: OrderedDict[tuple[str, str], TuneRecord] = OrderedDict()
+        # Bumped by put() and clear(): a planner serving measured winners
+        # from this DB changes generation with it.
+        self.generation = 0
         self.stats = _Stats(self, {
             "hits": 0,
             "misses": 0,
@@ -360,6 +363,7 @@ class TunedPlanDB:
         return None
 
     def put(self, rec: TuneRecord) -> None:
+        self.generation += 1
         self._remember(rec.key, rec.fingerprint, rec)
         if self.dir is None:
             return
@@ -380,6 +384,7 @@ class TunedPlanDB:
             self._disable_disk(e)  # degrade to memory-only, log once
 
     def clear(self, disk: bool = False) -> None:
+        self.generation += 1
         self._mem.clear()
         if disk and self.dir is not None and os.path.isdir(self.dir):
             for name in os.listdir(self.dir):

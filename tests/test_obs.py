@@ -180,20 +180,26 @@ def test_profiler_session_is_detected(tmp_path):
 
 
 def _stencil_calls(kind):
-    """Two warm calls of a public entry: ``stencil_iterate`` on one
-    device, or a periodic 27-point ``ir.run_program`` over a 4-device
-    mesh."""
+    """Two spellings of one warm call of a public entry: ``stencil_iterate``
+    on one device, or a periodic 27-point ``ir.run_program`` over a
+    4-device mesh.  The second spelling (offsets as int32, or an equal new
+    program) is a second key of the entry's memo that resolves to the
+    same plan and launches."""
     import jax
     import jax.numpy as jnp
 
     from repro import ir
+    from repro.kernels import stencil as kstencil
     from repro.kernels.stencil import stencil_iterate
 
+    kstencil._memo.clear()  # the second key misses whatever ran before
     if kind == "stencil_iterate":
         offs = star_stencil(3, 1)
         w = [1.0 / len(offs)] * len(offs)
         u = jnp.ones((16, 16, 128), jnp.float32)
-        return lambda: stencil_iterate(u, offs, w, 1)
+        offs32 = offs.astype(np.int32)
+        return (lambda: stencil_iterate(u, offs, w, 1),
+                lambda: stencil_iterate(u, offs32, w, 1))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.launch.mesh import make_column_mesh
@@ -202,10 +208,12 @@ def _stencil_calls(kind):
                      for k in (-1, 0, 1)])
     w = [1.0 / 27] * 27
     prog = ir.stencil_program(offs, w, 1, d=3, boundary="periodic")
+    prog2 = ir.stencil_program(offs, w, 1, d=3, boundary="periodic")
     mesh = make_column_mesh(4)
     u = jax.device_put(jnp.ones((32, 16, 128), jnp.float32),
                        NamedSharding(mesh, P("columns")))
-    return lambda: ir.run_program(prog, u, num_shards=4, shard_axis=0)
+    return (lambda: ir.run_program(prog, u, num_shards=4, shard_axis=0),
+            lambda: ir.run_program(prog2, u, num_shards=4, shard_axis=0))
 
 
 CALL_SPANS = ("stencil_call", "ir_lower", "plan", "kernel_launch")
@@ -214,19 +222,22 @@ CALL_SPANS = ("stencil_call", "ir_lower", "plan", "kernel_launch")
 @pytest.mark.parametrize("kind", ["stencil_iterate", "run_program_4dev"])
 def test_profiler_trace_holds_call_spans(tmp_path, kind):
     """Under ``jax.profiler`` with no recorder, each call leaves one
-    numbered ``stencil_call`` on the caller's thread holding its
-    ``ir_lower``, ``plan`` (with the cache outcome) and launch spans."""
+    numbered ``stencil_call`` on the caller's thread carrying its memo
+    outcome: a miss (a second key of a planned call) holds ``ir_lower``,
+    ``plan`` (with the plan cache's hit) and its launch spans; the hits
+    after it hold the launch spans alone."""
     import glob
 
     import jax
     from jax.profiler import ProfileData
 
-    call = _stencil_calls(kind)
-    call().block_until_ready()  # compile and plan outside the trace
+    call, second_key = _stencil_calls(kind)
+    call().block_until_ready()  # compile, plan and memoize outside the trace
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     with jax.profiler.trace(str(tmp_path), profiler_options=options):
-        for _ in range(3):
+        second_key().block_until_ready()
+        for _ in range(2):
             call().block_until_ready()
     (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
     events = [
@@ -242,41 +253,51 @@ def test_profiler_trace_holds_call_spans(tmp_path, kind):
     assert len(roots) == 3
     numbers = [st["call"] for _, _, st in roots]
     assert numbers == list(range(numbers[0], numbers[0] + 3))
-    for s, e, _ in roots:
+    assert [st["memo"] for _, _, st in roots] == ["miss", "hit", "hit"]
+    n_launch = None
+    for i, (s, e, _) in enumerate(roots):
         inside = sorted((cs, name, st) for _, name, cs, ce, st in events
                         if name != "stencil_call" and s <= cs and ce <= e)
         names = [name for _, name, _ in inside]
-        assert names[:2] == ["ir_lower", "plan"]
-        assert names[2:] and set(names[2:]) == {"kernel_launch"}
-        assert inside[1][2]["cache"] == "hit"
-    assert len(events) == sum(
-        1 for _, name, *_ in events if name == "stencil_call"
-    ) * 4
+        if i == 0:
+            assert names[:2] == ["ir_lower", "plan"]
+            assert inside[1][2]["cache"] == "hit"
+            names = names[2:]
+            n_launch = len(names)
+        assert names and set(names) == {"kernel_launch"}
+        assert len(names) == n_launch
+    assert len(events) == 3 * (1 + n_launch) + 2
 
 
 def test_recorder_gets_call_spans():
     """With a recorder the same spans land in it, with their arguments:
-    the call number, the plan's key and cache outcome, the launch's
-    pricing."""
-    call = _stencil_calls("stencil_iterate")
+    the call number and memo outcome, the plan's key and cache outcome on
+    the miss, the launch's pricing on both."""
+    call, second_key = _stencil_calls("stencil_iterate")
     call().block_until_ready()
     with obs.recording() as rec:
-        call().block_until_ready()
+        second_key().block_until_ready()
         call().block_until_ready()
     by_name = {}
     for sp in rec.spans:
         by_name.setdefault(sp.name, []).append(sp)
     roots = by_name["stencil_call"]
     assert [r.args["call"] for r in roots][1] == roots[0].args["call"] + 1
-    for name in ("ir_lower", "plan", "kernel_launch"):
-        assert len(by_name[name]) == 2
-        for child, root in zip(by_name[name], roots):
+    assert [r.args["memo"] for r in roots] == ["miss", "hit"]
+    assert rec.counters["entry_memo_miss"] == 1
+    assert rec.counters["entry_memo_hit"] == 1
+    for name, owners in (("ir_lower", roots[:1]), ("plan", roots[:1]),
+                         ("kernel_launch", roots)):
+        assert len(by_name[name]) == len(owners)
+        for child, root in zip(by_name[name], owners):
             assert child.tid == root.tid
             assert root.ts_us <= child.ts_us
             assert child.ts_us + child.dur_us <= root.ts_us + root.dur_us
     assert all(p.args["cache"] == "hit" and p.args["key"]
                for p in by_name["plan"])
     assert all("modeled_bytes" in k.args for k in by_name["kernel_launch"])
+    miss_launch, hit_launch = by_name["kernel_launch"]
+    assert miss_launch.args == hit_launch.args
     assert rec.counters["launches"] == 2
 
 
