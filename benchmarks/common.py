@@ -2,7 +2,6 @@
 :func:`force_cpu_devices` must run before the first jax import."""
 from __future__ import annotations
 
-import json
 import os
 import sys
 import time
@@ -37,39 +36,3 @@ def timed(fn, *args, repeats: int = 1, **kw):
 
 def emit(name: str, us: float, derived: str):
     print(f"{name},{us:.1f},{derived}")
-
-
-def gates_ok(gates: dict) -> bool:
-    """True iff every boolean-valued entry of a gate dict passed.  Numeric
-    entries (achieved ratios, error magnitudes) are informational riders."""
-    return all(v for v in gates.values() if isinstance(v, bool))
-
-
-def emit_bench(
-    name: str,
-    gates: dict,
-    record: dict,
-    json_path: str | None = None,
-    us: float = 0.0,
-) -> bool:
-    """The shared tail of every BENCH_PRn emitter: write the JSON artifact
-    (when a path is given), print the one-line CSV with the gate summary,
-    and return whether every boolean gate passed.
-
-    ``gates`` maps gate names to booleans (hard pass/fail) or numbers
-    (the achieved value behind a gate); both are printed, only booleans
-    decide the return value."""
-    if json_path:
-        with open(json_path, "w") as f:
-            json.dump(record, f, indent=2)
-            f.write("\n")
-    parts = []
-    for k, v in gates.items():
-        if isinstance(v, bool):
-            parts.append(f"{k}={v}")
-        elif isinstance(v, float):
-            parts.append(f"{k}={v:.3g}")
-        else:
-            parts.append(f"{k}={v}")
-    emit(name, us, " ".join(parts))
-    return gates_ok(gates)

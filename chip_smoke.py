@@ -7,14 +7,14 @@
 One chip (the default):
 
   a  the paper's 13-point star(3,2), one application, 512^3 f32
-     (``stencil_pallas``);
+     (``stencil_iterate``, T=1);
   b  the same star as a T=3 Jacobi chain, 256^3 f32 (``stencil_iterate``);
      the plan must fuse at least two stages into one launch;
-  c  the T=4 chain with bf16 frontiers (the ring chain of BENCH_PR9):
+  c  the T=4 chain with bf16 frontiers (the §14 ring chain):
      bf16 input, stage dtypes bf16, bf16, bf16, f32, 256^3
      (``stencil_iterate(dtypes=...)``);
   d  a 2-D 5-point Jacobi, 16384^2 f32, which sweeps along the sublane
-     axis (``ir.run_program``).
+     axis (``repro.entry.run``).
 
 ``--chips 4`` runs only the column-sharded launch (``num_shards=4``) of
 the star at 512^3 f32, T=1 and T=3, against the single-device result of
@@ -173,7 +173,7 @@ def one_chip_phases():
     roundings, extra check) for phases a-d."""
     from repro import ir
     from repro.core.cache_fitting import star_stencil
-    from repro.kernels.stencil import stencil_iterate, stencil_pallas
+    from repro.entry import run, stencil_iterate
 
     star, star_w, diff_w = star_operators()
     jac, jac_w = star_stencil(2, 1), [0.0, 0.25, 0.25, 0.25, 0.25]
@@ -188,7 +188,7 @@ def one_chip_phases():
 
     return [
         ("a", (512,) * 3, "float32",
-         lambda u: stencil_pallas(u, star, star_w),
+         lambda u: stencil_iterate(u, star, star_w, 1),
          1, star_w, star, 0, None),
         ("b", (256,) * 3, "float32",
          lambda u: stencil_iterate(u, star, diff_w, 3),
@@ -197,7 +197,7 @@ def one_chip_phases():
          lambda u: stencil_iterate(u, star, diff_w, 4, dtypes=bf16_chain),
          4, diff_w, star, 3, fused),
         ("d", (16384,) * 2, "float32",
-         lambda u: ir.run_program(jacobi, u),
+         lambda u: run(jacobi, u),
          1, jac_w, jac, 0, None),
     ]
 
@@ -239,7 +239,7 @@ def run_one_chip(seed):
 def run_four_chips(seed, devices):
     import jax
     import jax.numpy as jnp
-    from repro.kernels.stencil import stencil_iterate
+    from repro.entry import stencil_iterate
 
     if len(devices) < 4:
         print(f"--chips 4 needs 4 devices, found {len(devices)}",

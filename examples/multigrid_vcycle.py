@@ -30,6 +30,7 @@ import numpy as np
 
 from repro import ir
 from repro.core.cache_fitting import star_stencil
+from repro.entry import run
 from repro.kernels.ref import stencil_ref
 
 SHAPE = (48, 64)          # fine grid (coarse = half along each dim)
@@ -102,7 +103,7 @@ def full_weighting_program(d: int) -> ir.Program:
 
 def smooth(u, f, prog, sweeps):
     for _ in range(sweeps):
-        u = ir.run_program(prog, {"u": u, "f": f}, tile=TILE, sweep_axis=0)
+        u = run(prog, {"u": u, "f": f}, tile=TILE, sweep_axis=0)
     return u
 
 
@@ -127,8 +128,8 @@ def assemble_coarse(shape):
 
 def v_cycle(u, f, smoother, resid, full_weight, a_coarse):
     u = smooth(u, f, smoother, NU)                       # pre-smooth
-    r = ir.run_program(resid, {"u": u, "f": f}, tile=TILE, sweep_axis=0)
-    rs = ir.run_program(full_weight, r, tile=TILE, sweep_axis=0)
+    r = run(resid, {"u": u, "f": f}, tile=TILE, sweep_axis=0)
+    rs = run(full_weight, r, tile=TILE, sweep_axis=0)
     r_c = rs[::2, ::2]                                   # full-weight + inject
     # The unscaled stencil is h^-2-free, so restricting onto a grid of
     # doubled spacing scales the right-hand side by (h_c/h_f)^2 = 4.
@@ -162,7 +163,7 @@ def main() -> None:
     u = jnp.zeros(SHAPE, jnp.float32)
 
     def rnorm(u):
-        r = ir.run_program(resid, {"u": u, "f": f}, tile=TILE, sweep_axis=0)
+        r = run(resid, {"u": u, "f": f}, tile=TILE, sweep_axis=0)
         return float(jnp.linalg.norm(r))
 
     a_coarse = assemble_coarse(tuple(s // 2 for s in SHAPE))
@@ -183,7 +184,7 @@ def main() -> None:
     )
     neu = ir.chain_program([(offs, wts)], d, boundary="neumann")
     print("neumann smoother:", ir.summarize_program(neu))
-    out = ir.run_program(neu, u, tile=TILE, sweep_axis=0)
+    out = run(neu, u, tile=TILE, sweep_axis=0)
     ref = stencil_ref(u, offs, list(wts), boundary="neumann")
     err = float(jnp.abs(out - ref).max())
     print(f"  max |engine - oracle| = {err:.2e}")

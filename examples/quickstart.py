@@ -14,7 +14,7 @@ from repro.core import (
 from repro.core.cache_fitting import plan_schedule
 from repro.core.lattice import CacheGeometry, InterferenceLattice
 from repro.core.tiling import select_tile
-from repro.kernels.ops import apply_star_2nd_order
+from repro.entry import stencil_iterate
 from repro.kernels.ref import star_weights_2nd_order, stencil_ref
 from repro.plan import PlanCache, Planner
 
@@ -60,8 +60,9 @@ def main():
           f"efficiency_vs_isoperimetric={choice.efficiency:.2f}")
 
     u = jax.random.normal(jax.random.PRNGKey(0), (24, 40, 256), jnp.float32)
-    out = apply_star_2nd_order(u)
-    ref = stencil_ref(u, *star_weights_2nd_order(3, 2))
+    offs, w = star_weights_2nd_order(3, 2)
+    out = stencil_iterate(u, offs, w, 1)
+    ref = stencil_ref(u, offs, w)
     print(f"  pallas kernel max|err| vs oracle: "
           f"{float(jnp.abs(out - ref).max()):.2e}")
 
@@ -87,11 +88,11 @@ def main():
 
     # Run the kernel with a plan as the single source of truth (un-planned
     # calls consult the default planner internally).
-    from repro.kernels.stencil import stencil_pallas
+    from repro.entry import stencil_iterate
 
     offs, w = star_weights_2nd_order(3, 2)
     tpu_plan = planner.plan(shape=u.shape, offsets=offs)
-    out_planned = stencil_pallas(u, offs, w, plan=tpu_plan)
+    out_planned = stencil_iterate(u, offs, w, 1, plan=tpu_plan)
     print(f"  planned kernel max|err| vs oracle: "
           f"{float(jnp.abs(out_planned - ref).max()):.2e}")
 

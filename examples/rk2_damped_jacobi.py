@@ -20,8 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.cache_fitting import star_stencil
+from repro.entry import stencil_iterate
 from repro.kernels.ref import stencil_ref
-from repro.kernels.stencil import stencil_iterate
 from repro.plan import PlanCache, Planner
 
 SHAPE = (48, 64, 96)

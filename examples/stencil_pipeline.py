@@ -12,7 +12,8 @@ import numpy as np
 
 from repro.core.padding import advise_dim
 from repro.core.tiling import select_tile
-from repro.kernels.ops import apply_star_2nd_order, plan_tiles
+from repro.entry import stencil_iterate
+from repro.kernels.ops import plan_tiles
 from repro.kernels.ref import star_weights_2nd_order, stencil_ref
 
 
@@ -33,8 +34,10 @@ def main(argv=None):
     u = jax.random.normal(jax.random.PRNGKey(0), shape, jnp.float32)
     # one verification sweep against the oracle (keep the planner's sweep
     # axis — the tile shape was optimized for it)
-    out = apply_star_2nd_order(u, tile=plan.tile, sweep_axis=plan.sweep_axis)
-    ref = stencil_ref(u, *star_weights_2nd_order(3, 2))
+    offs, w = star_weights_2nd_order(3, 2)
+    out = stencil_iterate(u, offs, w, 1, tile=plan.tile,
+                          sweep_axis=plan.sweep_axis)
+    ref = stencil_ref(u, offs, w)
     err = float(jnp.abs(out - ref).max())
     assert err < 1e-3, err
     print(f"verified vs oracle (max|err|={err:.2e}); running {args.iters} sweeps")
@@ -42,7 +45,8 @@ def main(argv=None):
     t0 = time.time()
     x = u
     for _ in range(args.iters):
-        x = apply_star_2nd_order(x, tile=plan.tile, sweep_axis=plan.sweep_axis)
+        x = stencil_iterate(x, offs, w, 1, tile=plan.tile,
+                            sweep_axis=plan.sweep_axis)
         x = x / jnp.maximum(jnp.abs(x).max(), 1e-6)  # keep finite
     x.block_until_ready()
     dt = time.time() - t0

@@ -1,8 +1,9 @@
 #!/usr/bin/env bash
 # Tier-1 CI: the full test suite, the planner and autotuner smokes, the
-# docs-rot check, and the PR-tracked perf record.
+# docs-rot check, and the observability, IR and fuzzer smokes.  Speed is
+# measured on the chip by the benchmark BENCHMARK.json declares.
 #
-#   scripts/ci.sh            # tests + smokes + docs check + BENCH_PR10.json
+#   scripts/ci.sh            # tests + smokes + docs check
 #
 # The planner smoke plans 7 shapes (one Fig. 5 unfavorable grid, one
 # time_steps=3 fused plan, one two-stage heterogeneous chain, one 4-way
@@ -16,21 +17,15 @@
 # bf16/int8 dtype variants, with the winner never an advisory row and
 # the v2 record round-tripping.  check_docs.py fails on documentation
 # referencing renamed or removed modules or dangling DESIGN.md § anchors.
-# The JSON pass re-derives the §15 quantized depth-uncapping record
-# checked in at BENCH_PR10.json (f32 caps at depth 3 under the 700k
-# budget where the int8-frontier chain fuses depth 4 with a >=1.15x
-# modeled traffic cut, int8 chain inside the documented ±1-code band,
-# PR9..PR1 gates embedded); a drift there is a regression, not flake.
-# The IR smoke (§13) lowers a two-stage heterogeneous chain spelled as a
-# program and asserts bit-wise parity with the legacy stages= launch.
+# The IR smoke (§13) runs a two-stage heterogeneous chain spelled as a
+# program and asserts bit-wise parity with the stages= spelling.
 # The obs smoke (§12) runs one tuned 4-way-sharded fused T=3 chain under
 # REPRO_TRACE, asserts the trace parses as valid trace_event JSON, and
 # gates on repro.obs.report --check reconciling counters against spans
 # (including the §14 ring_vmem_bytes counter).  The §15 fuzzer step
 # replays the committed differential corpus (random programs vs the
 # numpy oracle, tolerance-banded per DESIGN.md §15); when hypothesis is
-# installed it widens into fresh generative search.  bench_history.py
-# then verifies the PR10⊃…⊃PR1 embedded gate chain.
+# installed it widens into fresh generative search.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -46,9 +41,8 @@ eval "$(python -m repro.runtime.isa --export)"
 
 python -m pytest -x -q
 python -m repro.plan.explain --smoke
-python -m repro.plan.tune --smoke
+python -m repro.autotune --smoke
 python scripts/check_docs.py
-python -m benchmarks.run --json
 
 # --- §12 observability smoke -------------------------------------------
 OBS_TMP="$(mktemp -d)"
@@ -59,7 +53,7 @@ isa.pin_xla_flags()
 import numpy as np
 import jax.numpy as jnp
 from repro.core.cache_fitting import star_stencil
-from repro.kernels.stencil import stencil_iterate
+from repro.entry import stencil_iterate
 from repro.plan import AutoTuner, PlanCache, Planner, TunedPlanDB
 
 offs = star_stencil(3, 1)
@@ -92,7 +86,7 @@ import numpy as np
 import jax, jax.numpy as jnp
 from repro import ir
 from repro.core.cache_fitting import star_stencil
-from repro.kernels.stencil import stencil_iterate
+from repro.entry import run, stencil_iterate
 
 offs1 = star_stencil(2, 1)
 w1 = list(np.linspace(-0.3, 0.4, len(offs1)))
@@ -100,13 +94,13 @@ offs2 = star_stencil(2, 2)
 w2 = list(np.linspace(-0.1, 0.12, len(offs2)))
 u = jax.random.normal(jax.random.PRNGKey(3), (48, 56), jnp.float32)
 
-legacy = stencil_iterate(u, stages=[(offs1, w1), (offs2, w2)],
+staged = stencil_iterate(u, stages=[(offs1, w1), (offs2, w2)],
                          tile=(8, 16), sweep_axis=0)
 prog = ir.chain_program([(offs1, w1), (offs2, w2)], d=2)
 ir.verify(prog, u.shape)
-lowered = ir.run_program(prog, u, tile=(8, 16), sweep_axis=0)
-assert np.array_equal(np.asarray(legacy), np.asarray(lowered)), \
-    "program spelling diverged from the legacy stages= launch"
+lowered = run(prog, u, tile=(8, 16), sweep_axis=0)
+assert np.array_equal(np.asarray(staged), np.asarray(lowered)), \
+    "program spelling diverged from the stages= spelling"
 halos = ir.infer_halos(prog)   # keyed by value name; the load is "u0"
 print(f"ir smoke: {ir.summarize_program(prog)} bit-wise == stages= "
       f"(input halo {halos['u0']})")
@@ -119,5 +113,3 @@ python -m repro.obs.report "$OBS_TMP/ir_trace.json" --check
 # fuzzer failure, not a generic pytest one.  With hypothesis installed
 # the same file widens into generative search.
 python -m pytest -q tests/test_program_fuzz.py
-
-python scripts/bench_history.py

@@ -37,7 +37,7 @@ from .ops import (
 )
 from .verify import verify
 
-__all__ = ["IRLowerError", "Lowered", "lower", "run_program"]
+__all__ = ["IRLowerError", "Lowered", "lower"]
 
 
 class IRLowerError(ValueError):
@@ -306,31 +306,3 @@ def _as_multi_rhs(op: Combine, env: dict[str, _Chain]) -> Lowered:
         bcs=(None,) * len(stages),
         quants=(None,) * len(stages),
     )
-
-
-def run_program(program: Program, arrays, **kwargs):
-    """Execute ``program`` on the sweep engine.
-
-    ``arrays`` maps the program's load names to jax arrays (a single
-    array or positional sequence also works, matched to
-    ``program.inputs()`` order).  Extra keyword arguments (``tile=``,
-    ``plan=``, ``num_shards=``, ``tune=``, ``interpret=``...) pass
-    through to :func:`repro.kernels.stencil.multi_stencil_pallas`.
-    """
-    from repro.kernels.stencil import multi_stencil_pallas  # lazy: jax
-
-    names = program.inputs()
-    if isinstance(arrays, dict):
-        missing = [n for n in names if n not in arrays]
-        if missing:
-            raise KeyError(f"program inputs missing from arrays: {missing}")
-        us = [arrays[n] for n in names]
-    elif isinstance(arrays, (list, tuple)):
-        if len(arrays) != len(names):
-            raise ValueError(
-                f"{len(arrays)} arrays for {len(names)} program inputs"
-            )
-        us = list(arrays)
-    else:
-        us = [arrays] * len(names)
-    return multi_stencil_pallas(us, None, None, program=program, **kwargs)

@@ -588,7 +588,7 @@ def stencil_program(
     dtypes: Sequence[str | None] | None = None,
 ) -> Program:
     """``time_steps`` repeated applications of one operator — the program
-    form of ``stencil_pallas(time_steps=T)``."""
+    form of ``stencil_iterate(u, offsets, weights, T)``."""
     arr = np.asarray(offsets, dtype=np.int64)
     if d is None:
         d = arr.shape[-1]

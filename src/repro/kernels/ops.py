@@ -1,19 +1,14 @@
-"""Public jit'd API for the stencil kernels.
+"""Modeled tile decisions for logging and reports.
 
-``apply_stencil`` is what the rest of the framework calls (examples,
-benchmarks, the Mamba2/Whisper conv frontends fall back to it for their
-1-D stencils).  It reports the tile decision so callers can log the
-cache-fitting statistics (traffic vs. isoperimetric bound), and
-``traffic_report`` compares the sweep-reuse model against the per-tile-halo
-model so the benchmark harness can track the HBM-traffic trajectory.
+``plan_tiles`` exposes the cache-fitting tile decision so callers can log
+its statistics (traffic vs. isoperimetric bound), and ``traffic_report``
+compares the sweep-reuse model against the per-tile-halo model.  Calls
+themselves go through ``repro.entry`` (``stencil_iterate``, ``run``).
 """
 
 from __future__ import annotations
 
 from typing import Sequence
-
-import jax.numpy as jnp
-import numpy as np
 
 from repro.core.tiling import (
     TileChoice,
@@ -21,19 +16,7 @@ from repro.core.tiling import (
     select_tile,
 )
 
-from .ref import star_weights_2nd_order, stencil_ref
-from .stencil import multi_stencil_pallas, stencil_iterate, stencil_pallas
-
-__all__ = [
-    "apply_stencil",
-    "apply_star_2nd_order",
-    "apply_multi_rhs",
-    "plan_tiles",
-    "traffic_report",
-    "stencil_iterate",
-    "stencil_ref",
-    "star_weights_2nd_order",
-]
+__all__ = ["plan_tiles", "traffic_report"]
 
 
 def plan_tiles(
@@ -90,49 +73,3 @@ def traffic_report(
         "lower_bound_bytes": swept.lower_bound_bytes,
         "traffic_ratio": naive.traffic_bytes / max(swept.traffic_bytes, 1),
     }
-
-
-def apply_stencil(
-    u: jnp.ndarray,
-    offsets: np.ndarray,
-    weights: Sequence[float],
-    tile: Sequence[int] | None = None,
-    interpret: bool | None = None,
-    sweep_axis: int | None = None,
-    pipelined: bool = True,
-    time_steps: int = 1,
-) -> jnp.ndarray:
-    """q = K u with zero boundary fill; sweep-pipelined Pallas tiles.
-    ``time_steps=T > 1`` fuses T applications into the §8 trapezoid."""
-    return stencil_pallas(
-        u, offsets, weights, tile=tile, interpret=interpret,
-        sweep_axis=sweep_axis, pipelined=pipelined, time_steps=time_steps,
-    )
-
-
-def apply_star_2nd_order(
-    u: jnp.ndarray, tile: Sequence[int] | None = None,
-    interpret: bool | None = None,
-    sweep_axis: int | None = None,
-) -> jnp.ndarray:
-    """The paper's measured operator: second-order star (13-point in 3-D)."""
-    offsets, weights = star_weights_2nd_order(u.ndim, r=2)
-    return apply_stencil(
-        u, offsets, weights, tile=tile, interpret=interpret,
-        sweep_axis=sweep_axis,
-    )
-
-
-def apply_multi_rhs(
-    us: Sequence[jnp.ndarray],
-    offsets_list: Sequence[np.ndarray],
-    weights_list: Sequence[Sequence[float]],
-    tile: Sequence[int] | None = None,
-    interpret: bool | None = None,
-    sweep_axis: int | None = None,
-) -> jnp.ndarray:
-    """q = Σ_p K_p u_p (§5) with the per-operand VMEM budget split."""
-    return multi_stencil_pallas(
-        us, offsets_list, weights_list, tile=tile, interpret=interpret,
-        sweep_axis=sweep_axis,
-    )

@@ -87,23 +87,23 @@ block and the body's f32 values, capped by the device's VMEM
 **Multi-core sharding** (DESIGN.md §10): sweep columns are independent
 even with frontier state (each column warms its own rings at ``k == 0``),
 so the cross-axis tile columns can be partitioned over a device mesh.
-``stencil_pallas(..., num_shards=N)`` (or an explicit ``mesh=``) routes
-every launch through :mod:`repro.parallel.shard_columns`: each shard runs
-this same sweep kernel on its column slab, with halo exchange only at
+:mod:`repro.parallel.shard_columns` runs this same sweep kernel
+(``padded_call``) on each shard's column slab, with halo exchange only at
 shard boundaries.  The kernel itself is shard-agnostic — it receives a
 ``(d,)`` domain-offset vector in SMEM giving the true-grid coordinate of
 the local array's origin (all-zero on a single device), which keeps the
 §8/§9 intermediate-stage masks in *global* coordinates under SPMD.
+
+This module is the kernel and its launcher only: it knows nothing of
+programs, plans or meshes.  Calls reach it through :mod:`repro.entry`,
+which lowers, plans, memoizes and binds them (``bound_call``).
 """
 
 from __future__ import annotations
 
 import functools
 import itertools
-import threading
-import weakref
-from collections import OrderedDict
-from typing import TYPE_CHECKING, NamedTuple, Sequence
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -115,30 +115,26 @@ from repro.core.tiling import (  # shared with the planner
     axis_grain,
     chain_halo,
     direct_input,
-    dtype_itemsize,
-    fused_stage_bytes,
-    grid_slack,
     halo_from_offsets,
     kernel_vmem_bytes,
     stage_suffix_halos,
     window_extents,
 )
 
-from .. import ir, obs
-from ._backend import checked_vmem_limit, resolve_interpret
-
-if TYPE_CHECKING:
-    from repro.plan import StencilPlan
+from ._backend import checked_vmem_limit
 
 __all__ = [
-    "stencil_pallas",
-    "multi_stencil_pallas",
-    "stencil_iterate",
-    "halo_from_offsets",
+    "bound_call",
+    "embed_inputs",
+    "input_buffer",
+    "launch_geometry",
+    "launch_pads",
+    "padded_call",
+    "round_up",
 ]
 
 
-def _round_up(n: int, t: int) -> int:
+def round_up(n: int, t: int) -> int:
     return -(-n // t) * t
 
 
@@ -889,7 +885,7 @@ def _direct_loads(x_hbm, windows, slabs, win_sem, slab_sem, gids, k, tile,
         zero_rows_past_grid()
 
 
-def _launch_geometry(offsets_w, stages_w, tile, bcs_w=None, dtypes_w=None,
+def launch_geometry(offsets_w, stages_w, tile, bcs_w=None, dtypes_w=None,
                      quants_w=None):
     """Static launch geometry shared by the single-device and sharded
     paths: per-RHS offset/weight arrays, the per-stage chain (``None`` =
@@ -950,7 +946,7 @@ def _launch_geometry(offsets_w, stages_w, tile, bcs_w=None, dtypes_w=None,
     return offsets, weights, stages, lo_w, hi_w
 
 
-def _padded_call(ins, dom, offsets, weights, stages, lo_w, hi_w, tile,
+def padded_call(ins, dom, offsets, weights, stages, lo_w, hi_w, tile,
                  sweep, pipelined, interpret, n_true,
                  window_kind="ring", in_quant=None, direct=False):
     """Run the sweep kernel over already-padded arrays and return the
@@ -1105,7 +1101,7 @@ def launch_pads(shape, tile, lo_w, hi_w, itemsize):
     the round-up to whole tiles and the grain slack."""
     ext = window_extents(tile, list(zip(lo_w, hi_w)), itemsize)
     return [
-        (lo, _round_up(int(n), t) - t + e - lo - int(n))
+        (lo, round_up(int(n), t) - t + e - lo - int(n))
         for n, t, lo, e in zip(shape, tile, lo_w, ext)
     ]
 
@@ -1211,7 +1207,7 @@ def _stencil_call(us, offsets_w, tile, sweep, pipelined, interpret,
     u0 = us[0]
     d = u0.ndim
     tile = tuple(int(t) for t in tile)
-    offsets, weights, stages, lo_w, hi_w = _launch_geometry(
+    offsets, weights, stages, lo_w, hi_w = launch_geometry(
         offsets_w, stages_w, tile, bcs_w, dtypes_w, quants_w
     )
     buf = input_buffer(
@@ -1227,7 +1223,7 @@ def _stencil_call(us, offsets_w, tile, sweep, pipelined, interpret,
             wrap=tuple(zip(lo_w, hi_w)) if buf == "wrap" else None,
             fill=int(in_quant[1]) if in_quant is not None else 0,
         )
-    out = _padded_call(
+    out = padded_call(
         ins, jnp.zeros((d,), jnp.int32), offsets, weights, stages, lo_w,
         hi_w, tile, sweep, pipelined, interpret, u0.shape,
         window_kind=window_kind, in_quant=in_quant,
@@ -1237,442 +1233,8 @@ def _stencil_call(us, offsets_w, tile, sweep, pipelined, interpret,
         return out[tuple(slice(0, n) for n in u0.shape)]
 
 
-def _auto_tile(shape, offsets_list, dtype_bytes, n_arrays, vmem_budget=None,
-               time_steps=1, stages=None, num_shards=1, tune=None,
-               bcs=None, dtypes=None, window_kind="auto", shard_axis=None):
-    """Tile decision for an un-planned call: a thin wrapper over the plan
-    compiler (``repro.plan``), whose persistent cache makes repeated shapes
-    — the serving case — O(1).  The old ad-hoc heuristic survives as
-    ``Planner(strategy="legacy")``; the planner asserts it never predicts
-    more traffic than that baseline.
-
-    ``stages`` (per-stage offset arrays, weights deliberately stripped so
-    cache keys stay weight-independent) requests a stage-chain plan; a
-    homogeneous chain canonicalizes to the same request — and cache key —
-    as the ``offsets + time_steps`` spelling.
-
-    ``tune`` (``True`` or an ``AutoTuner``) routes the decision through
-    the §11 measured-cost loop instead: a warm TunedPlanDB hit serves the
-    measured winner, a miss races the top-k candidates on the live
-    backend first (``repro.plan.tune``).
-
-    ``shard_axis`` is the caller's pinned partition axis of a sharded
-    call: the tile is planned for that axis's column slab
-    (``Planner.plan_along``), not for the planner's own choice of axis."""
-    from repro.plan import default_planner, resolve_tuner
-
-    d = len(shape)
-    kw = dict(
-        shape=tuple(int(n) for n in shape),
-        dtype_bytes=dtype_bytes,
-        vmem_budget=vmem_budget,
-        n_operands=n_arrays + 1,  # p inputs + the output tile (§5 split)
-        num_shards=int(num_shards),
-    )
-    kw["window_kind"] = window_kind
-    if stages is not None:
-        kw["stages"] = [np.asarray(o).reshape(-1, d) for o in stages]
-        if bcs is not None and any(bc is not None for bc in bcs):
-            kw["bcs"] = tuple(bcs)
-        if dtypes is not None and any(dt is not None for dt in dtypes):
-            kw["dtypes"] = tuple(dtypes)
-    else:
-        kw["offsets"] = [np.asarray(o).reshape(-1, d) for o in offsets_list]
-        kw["time_steps"] = time_steps
-    tuner = resolve_tuner(tune)
-    if tuner is not None:
-        return tuner.plan(**kw)
-    if shard_axis is not None and num_shards > 1:
-        return default_planner().plan_along(shard_axis, **kw)
-    return default_planner().plan(**kw)
-
-
-def stencil_pallas(
-    u: jnp.ndarray,
-    offsets: np.ndarray,
-    weights: Sequence[float],
-    tile: Sequence[int] | None = None,
-    interpret: bool | None = None,
-    vmem_budget: int | None = None,
-    sweep_axis: int | None = None,
-    pipelined: bool = True,
-    plan: "StencilPlan | None" = None,
-    time_steps: int = 1,
-    num_shards: int | None = None,
-    shard_axis: int | None = None,
-    mesh=None,
-    tune=None,
-    trace: str | None = None,
-    dtypes: Sequence | None = None,
-    window_kind: str | None = None,
-) -> jnp.ndarray:
-    """Single-array weighted stencil, zero boundary fill (matches ref).
-
-    ``plan``: a precompiled ``repro.plan.StencilPlan`` — the single source
-    of truth for tile/sweep/pipelining when given; otherwise the default
-    planner is consulted (and its cache makes repeats O(1)).
-
-    ``tune=True`` (or an ``repro.plan.AutoTuner``) opts the planning step
-    into the §11 measured-cost loop: the first call for a given request
-    races the top-k candidate plans on this backend and persists the
-    measured winner; every later call serves it sub-ms from the
-    TunedPlanDB.  Mutually exclusive with ``plan``/``tile`` (which pin
-    the decision already).
-
-    ``time_steps=T > 1`` applies the stencil T times (a Jacobi/RK sub-step
-    chain), lowered onto the same stage-chain engine as
-    ``stencil_iterate(stages=...)``: the planner picks the fusion depth,
-    or an explicit ``tile`` fuses all T steps into one launch.
-
-    ``num_shards=N > 1`` (or an explicit 1-axis ``mesh``) partitions the
-    cross-axis tile columns over N devices via ``jax.shard_map``
-    (DESIGN.md §10, :mod:`repro.parallel.shard_columns`): bit-wise equal
-    to the single-device launch, with halo exchange only at shard
-    boundaries.  ``shard_axis`` picks the partitioned cross axis
-    (default: the plan's, else the cross axis with the most columns).
-
-    ``trace="path.json"`` records this one call — plan span, cache
-    lookups, kernel launches — into a Chrome ``trace_event`` file via
-    :mod:`repro.obs` (equivalent to wrapping the call in
-    ``obs.recording(path)``)."""
-    return multi_stencil_pallas(
-        [u], [offsets], [weights], tile=tile, interpret=interpret,
-        vmem_budget=vmem_budget, sweep_axis=sweep_axis, pipelined=pipelined,
-        plan=plan, time_steps=time_steps, num_shards=num_shards,
-        shard_axis=shard_axis, mesh=mesh, tune=tune, trace=trace,
-        dtypes=dtypes, window_kind=window_kind,
-    )
-
-
-def stencil_iterate(
-    u: jnp.ndarray,
-    offsets: np.ndarray | None = None,
-    weights: Sequence[float] | None = None,
-    time_steps: int | None = None,
-    tile: Sequence[int] | None = None,
-    interpret: bool | None = None,
-    vmem_budget: int | None = None,
-    sweep_axis: int | None = None,
-    pipelined: bool = True,
-    plan: "StencilPlan | None" = None,
-    stages: Sequence[tuple] | None = None,
-    num_shards: int | None = None,
-    shard_axis: int | None = None,
-    mesh=None,
-    tune=None,
-    trace: str | None = None,
-    dtypes: Sequence | None = None,
-    window_kind: str | None = None,
-) -> jnp.ndarray:
-    """Run a stage-chain stencil program — the iterative-solver workload.
-
-    Two spellings lower onto one engine:
-
-    * ``stencil_iterate(u, offsets, weights, T)`` applies the same
-      operator T times (Jacobi sweeps) — equal to iterating
-      ``kernels.ref.stencil_ref`` T times.
-    * ``stencil_iterate(u, stages=[(offsets_1, weights_1), ...])`` runs a
-      chain with a *distinct* operator per stage (Runge-Kutta sub-steps,
-      damped-Jacobi smoother pairs) — equal to applying the references in
-      order.
-
-    The planner chooses how deeply to fuse (``plan.fused_depth``): each
-    fused launch advances up to that many consecutive stages in one HBM
-    pass via the §8/§9 trapezoid window with streaming frontiers, and the
-    chain runs ``ceil(T / fused_depth)`` launches.  A fused plan is only
-    ever chosen when its modeled traffic beats the planner's own
-    single-pass choice.
-
-    ``num_shards``/``shard_axis``/``mesh`` shard every launch of the
-    chain over cross-axis tile columns (DESIGN.md §10) — frontier rings
-    are per-column state, so the fused streaming launch shards exactly
-    like the single application.
-
-    ``dtypes=[dt_1, ..., dt_T]`` declares each stage's output dtype
-    (``None`` entries = the input's): frontiers, inter-launch handoffs
-    and the final write-back happen at the stage dtype while every stage
-    still accumulates in f32 — the mixed-precision chain of DESIGN.md
-    §14.  ``window_kind`` forces the frontier layout (``"ring"`` /
-    ``"trapezoid"``); default: the plan's choice, else the ring."""
-    if stages is not None:
-        if offsets is not None or weights is not None:
-            raise ValueError("pass (offsets, weights) or stages, not both")
-        if time_steps is not None and time_steps != len(stages):
-            raise ValueError(
-                f"time_steps={time_steps} contradicts {len(stages)} stages"
-            )
-        return multi_stencil_pallas(
-            [u], None, None, tile=tile, interpret=interpret,
-            vmem_budget=vmem_budget, sweep_axis=sweep_axis,
-            pipelined=pipelined, plan=plan, stages=stages,
-            num_shards=num_shards, shard_axis=shard_axis, mesh=mesh,
-            tune=tune, trace=trace, dtypes=dtypes, window_kind=window_kind,
-        )
-    if offsets is None or weights is None or time_steps is None:
-        raise ValueError(
-            "stencil_iterate needs (offsets, weights, time_steps) or stages"
-        )
-    return multi_stencil_pallas(
-        [u], [offsets], [weights], tile=tile, interpret=interpret,
-        vmem_budget=vmem_budget, sweep_axis=sweep_axis, pipelined=pipelined,
-        plan=plan, time_steps=time_steps, num_shards=num_shards,
-        shard_axis=shard_axis, mesh=mesh, tune=tune, trace=trace,
-        dtypes=dtypes, window_kind=window_kind,
-    )
-
-
-def multi_stencil_pallas(
-    us: Sequence[jnp.ndarray],
-    offsets_list: Sequence[np.ndarray] | None,
-    weights_list: Sequence[Sequence[float]] | None,
-    tile: Sequence[int] | None = None,
-    interpret: bool | None = None,
-    vmem_budget: int | None = None,
-    sweep_axis: int | None = None,
-    pipelined: bool = True,
-    plan: "StencilPlan | None" = None,
-    time_steps: int = 1,
-    stages: Sequence[tuple] | None = None,
-    num_shards: int | None = None,
-    shard_axis: int | None = None,
-    mesh=None,
-    tune=None,
-    trace: str | None = None,
-    program=None,
-    dtypes: Sequence | None = None,
-    window_kind: str | None = None,
-) -> jnp.ndarray:
-    """p-RHS stencil  q = Σ_p K_p u_p  (paper §5): one VMEM budget split
-    across p operand windows plus the output tile, one shared sweep.
-
-    Every spelling of a computation is lowered through the stencil-
-    program IR (DESIGN.md §13): the legacy ``offsets_list``/``stages=``/
-    ``time_steps=`` arguments are thin builders that construct the
-    equivalent :class:`repro.ir.Program` and lower it — bit-wise
-    identical launches, asserted by test.  ``program`` passes an explicit
-    :class:`repro.ir.Program` (or its serialized JSON) instead, mutually
-    exclusive with the legacy spellings; boundary ops in the program
-    lower to in-kernel correction taps (no host-side pad), and ``us``
-    matches ``program.inputs()`` order.
-
-    Tile/sweep resolution order: explicit ``tile``/``sweep_axis`` args win,
-    then the ``plan``'s decision, then the default planner (``tune=``
-    swaps that last step for the §11 measured-cost loop — warm TunedPlanDB
-    hits serve the measured winner; mutually exclusive with
-    ``plan``/``tile``).  A ``plan`` is
-    validated against the call (shape, offsets, dtype, time_steps, stage
-    chain) and a mismatch raises :class:`repro.plan.PlanMismatchError` —
-    executing a plan compiled for different inputs silently mis-tiles or
-    under-allocates the VMEM window.
-
-    ``time_steps=T > 1`` (single RHS only) runs the T-application chain;
-    ``stages=[(offsets, weights), ...]`` runs a chain with a distinct
-    operator per stage.  Both lower onto the §8/§9 stage-chain engine:
-    launches of up to ``fused_depth`` consecutive stages, one HBM pass
-    each, streaming per-stage frontiers inside.
-
-    ``num_shards``/``shard_axis``/``mesh`` resolve the same way as the
-    tile (explicit args win, then the plan, then 1 / auto) and route every
-    launch through the §10 column-sharded path; sharding is an execution
-    knob — it never changes the result (bit-wise) or the tile choice.
-
-    ``dtypes=[dt_1, ..., dt_T]`` (single-RHS chains only) declares each
-    stage's output dtype (``None`` = the input's); ``window_kind``
-    forces the §14 frontier layout (``"ring"``/``"trapezoid"``; default
-    the plan's choice, else ring) — an execution knob, bit-wise neutral.
-
-    ``trace="path.json"`` records this call into a Chrome ``trace_event``
-    file (see :mod:`repro.obs`).
-
-    The launches a call resolves to depend only on its static signature
-    (operator, shapes, dtypes, keywords), so the entry memoizes them
-    (DESIGN.md §17): a repeated signature skips lowering and planning
-    and goes straight to the bound launches.  ``tune=`` calls bypass the
-    memo.
-
-    Each call is one ``stencil_call`` span (numbered ``call=<n>``,
-    ``memo=hit|miss``).  A miss holds ``ir_lower`` (program build,
-    lowering, summary), the planner's ``plan`` and one ``kernel_launch``
-    per launch; a hit holds the ``kernel_launch`` spans alone.  The spans
-    are recorded by a :mod:`repro.obs` recorder or, with none, sent to a
-    collecting ``jax.profiler`` session."""
-    args = (us, offsets_list, weights_list, tile, interpret, vmem_budget,
-            sweep_axis, pipelined, plan, time_steps, stages, num_shards,
-            shard_axis, mesh, tune, program, dtypes, window_kind)
-    if trace is not None:
-        with obs.recording(trace), obs.call_span("stencil_call") as root:
-            return _stencil_entry(root, *args)
-    with obs.call_span("stencil_call") as root:
-        return _stencil_entry(root, *args)
-
-
-def _build_program(us, offsets_list, weights_list, time_steps, stages,
-                   program, dtypes):
-    """The :class:`repro.ir.Program` of one call, from an explicit
-    ``program`` or the legacy spellings (§13)."""
-    d = us[0].ndim
-    if program is not None:
-        if (offsets_list is not None or weights_list is not None
-                or stages is not None):
-            raise ValueError(
-                "pass program= or the (offsets/weights/stages) spellings, "
-                "not both"
-            )
-        if dtypes is not None:
-            raise ValueError(
-                "dtypes= belongs to the legacy spellings; a program "
-                "carries per-stage dtypes on its apply ops"
-            )
-        return (
-            ir.Program.from_json(program) if isinstance(program, str)
-            else program
-        )
-    if stages is not None:
-        if offsets_list is not None or weights_list is not None:
-            raise ValueError(
-                "pass (offsets_list, weights_list) or stages, not both"
-            )
-        if len(us) != 1:
-            raise ValueError(
-                f"stage chains require a single RHS; got {len(us)} arrays"
-            )
-        if not tuple(stages):
-            raise ValueError("stages must contain at least one stage")
-        for o, ws in stages:
-            offs = np.asarray(o, dtype=np.int64).reshape(-1, d)
-            if len(offs) != len(tuple(ws)):
-                raise ValueError(
-                    f"stage has {len(offs)} offsets but {len(tuple(ws))} "
-                    "weights"
-                )
-        return ir.chain_program(list(stages), d, dtypes=dtypes)
-    T = int(time_steps)
-    if T < 1:
-        raise ValueError(f"time_steps must be >= 1, got {T}")
-    if T > 1 and len(us) != 1:
-        raise ValueError(
-            "temporal fusion (time_steps > 1) requires a single RHS; "
-            f"got {len(us)} arrays"
-        )
-    if len(us) == 1:
-        # The canonical form: every single-RHS call IS a (possibly
-        # repeated) stage chain.
-        return ir.stencil_program(
-            offsets_list[0], weights_list[0], time_steps=T, d=d,
-            dtypes=dtypes,
-        )
-    if dtypes is not None:
-        raise ValueError("dtypes= requires a single-RHS stage chain")
-    return ir.rhs_program(offsets_list, weights_list, d=d)
-
-
-# -- the entry memo (DESIGN.md §17) -------------------------------------------
-
-_MEMO_SIZE = 128  # resolved signatures kept, least recently used dropped
-_memo: OrderedDict = OrderedDict()
-_memo_lock = threading.Lock()
-
-
-class _Launch(NamedTuple):
-    """One resolved launch: ``call(arrays)`` runs it with every static
-    argument bound; ``args`` and ``counters`` are what a recorder gets
-    for it (the ``kernel_launch`` span's arguments, the counter bumps)."""
-
-    call: object
-    args: dict
-    counters: tuple
-
-
-class _Entry(NamedTuple):
-    """A call signature's resolved launch sequence.  ``order`` permutes a
-    multi-RHS call's arrays into the lowered operand order; ``pins`` holds
-    the objects the key names by identity (plan, mesh, planner) so their
-    ids stay theirs while the entry lives; ``program`` is a weak reference
-    to a keyed :class:`repro.ir.Program`.  No caller array is held: the
-    arrays are arguments of the launches, never captured."""
-
-    launches: tuple
-    order: tuple | None
-    pins: tuple
-    program: object
-
-    def run(self, us):
-        if self.order is not None:
-            us = tuple(us[i] for i in self.order)
-        recording = obs.enabled()
-        for launch in self.launches:
-            if recording:
-                for name, value in launch.counters:
-                    obs.add(name, value)
-                span = obs.span("kernel_launch", **launch.args)
-            else:
-                span = obs.span("kernel_launch")
-            with span:
-                out = launch.call(us)
-            us = (out,)
-        return out
-
-
-def _offsets_key(offsets):
-    a = np.asarray(offsets)
-    return a.dtype.str, a.shape, a.tobytes()
-
-
-def _weights_key(weights):
-    return tuple(map(float, weights))
-
-
-def _memo_key(us, offsets_list, weights_list, tile, interpret, vmem_budget,
-              sweep_axis, pipelined, plan, time_steps, stages, num_shards,
-              shard_axis, mesh, program, dtypes, window_kind):
-    """Everything that decides a call's launches, cheap to hash: the
-    operator as given (a program by identity, checked against its weak
-    reference on a hit), the arrays' shapes and dtypes, every planning
-    keyword, ``plan`` and ``mesh`` by identity, and the default planner
-    with its generation."""
-    from repro.plan import default_planner
-
-    planner = default_planner()
-    return (
-        program if isinstance(program, str) else id(program),
-        None if offsets_list is None
-        else tuple(map(_offsets_key, offsets_list)),
-        None if weights_list is None
-        else tuple(map(_weights_key, weights_list)),
-        None if stages is None
-        else tuple((_offsets_key(o), _weights_key(w)) for o, w in stages),
-        time_steps,
-        None if dtypes is None else tuple(dtypes),
-        tuple((u.shape, u.dtype) for u in us),
-        None if tile is None else tuple(tile),
-        interpret, vmem_budget, sweep_axis, pipelined, num_shards,
-        shard_axis, window_kind, id(mesh), id(plan), id(planner),
-        planner.generation,
-    )
-
-
-def _memo_get(key, program):
-    with _memo_lock:
-        entry = _memo.get(key)
-        if entry is None:
-            return None
-        if entry.program is not None and entry.program() is not program:
-            del _memo[key]  # a freed program's id, reused
-            return None
-        _memo.move_to_end(key)
-        return entry
-
-
-def _memo_put(key, entry):
-    with _memo_lock:
-        _memo[key] = entry
-        _memo.move_to_end(key)
-        while len(_memo) > _MEMO_SIZE:
-            _memo.popitem(last=False)
-
-
 @functools.lru_cache(maxsize=128)
-def _bound_call(statics: tuple):
+def bound_call(statics: tuple):
     """``_stencil_call`` with its static arguments (``statics``, sorted
     ``(name, value)`` pairs) bound once: the jitted dispatch then sees
     only the arrays.  It traces the same function under the same name,
@@ -1682,374 +1244,12 @@ def _bound_call(statics: tuple):
     return jax.jit(functools.update_wrapper(bound, fn))
 
 
-def _stencil_entry(root, us, offsets_list, weights_list, tile, interpret,
-                   vmem_budget, sweep_axis, pipelined, plan, time_steps,
-                   stages, num_shards, shard_axis, mesh, tune, program,
-                   dtypes, window_kind):
-    """The body of :func:`multi_stencil_pallas`: on a memo hit, the bound
-    launches; on a miss (and on every ``tune=`` call, since the tuner
-    measures), lower, plan and bind them first.  ``root`` is the call's
-    ``stencil_call`` span, which gets ``memo=hit|miss``."""
-    us = tuple(us)
-    interpret = resolve_interpret(interpret, kernel="stencil")
-    key = entry = None
-    if not tune:
-        key = _memo_key(
-            us, offsets_list, weights_list, tile, interpret, vmem_budget,
-            sweep_axis, pipelined, plan, time_steps, stages, num_shards,
-            shard_axis, mesh, program, dtypes, window_kind,
-        )
-        entry = _memo_get(key, program)
-    outcome = "miss" if entry is None else "hit"
-    obs.add("entry_memo_" + outcome)
-    if root is not obs.NULL_SPAN:
-        root.set(memo=outcome)
-    if entry is None:
-        entry = _resolve(
-            us, offsets_list, weights_list, tile, interpret, vmem_budget,
-            sweep_axis, pipelined, plan, time_steps, stages, num_shards,
-            shard_axis, mesh, tune, program, dtypes, window_kind,
-        )
-        if key is not None:
-            _memo_put(key, entry)
-    return entry.run(us)
+def __getattr__(name):
+    # Forwarder for bench/harness.py's make_entry, which imports
+    # stencil_iterate from here: resolved once, then a module global.
+    if name == "stencil_iterate":
+        from repro.entry import stencil_iterate
 
-
-def _resolve(us, offsets_list, weights_list, tile, interpret, vmem_budget,
-             sweep_axis, pipelined, plan, time_steps, stages, num_shards,
-             shard_axis, mesh, tune, program, dtypes, window_kind):
-    """Lower, plan and bind one call signature: its :class:`_Entry`."""
-    if window_kind is not None and window_kind not in ("ring", "trapezoid"):
-        raise ValueError(
-            f"window_kind must be 'ring' or 'trapezoid', got {window_kind!r}"
-        )
-    if dtypes is not None:
-        dtypes = tuple(
-            str(jnp.dtype(dt).name) if dt is not None else None
-            for dt in dtypes
-        )
-    assert len({u.shape for u in us}) == 1, "RHS arrays must share a shape"
-    d = us[0].ndim
-    shape = tuple(int(n) for n in us[0].shape)
-    in_name = str(jnp.dtype(us[0].dtype).name)
-    # -- build the stencil program (§13), verify + lower it ----------------
-    with obs.span("ir_lower"):
-        prog = _build_program(us, offsets_list, weights_list, time_steps,
-                              stages, program, dtypes)
-        lowered = ir.lower(prog, shape)
-        prog_summary = ir.summarize_program(prog)
-    order = None
-    if lowered.kind == "chain":
-        if len(us) != 1:
-            raise ValueError(
-                f"program lowers to a stage chain over one input; got "
-                f"{len(us)} arrays"
-            )
-        chain = tuple(
-            (np.asarray(o, dtype=np.int64).reshape(-1, d), wts)
-            for o, wts in lowered.stages
-        )
-        bcs = lowered.bcs
-        T = len(chain)
-        offsets_list = [chain[0][0]]
-        weights_list = [list(chain[0][1])]
-        # Per-stage output dtypes, resolved once against the chain input:
-        # ``eff`` holds concrete names for the kernel/launch handoffs,
-        # ``req_dtypes`` the None-normalized form the plan stack keys on
-        # (a stage at the input dtype is the same request as no dtype).
-        chain_dtypes = tuple(lowered.dtypes) if lowered.dtypes else (None,) * T
-        assert len(chain_dtypes) == T, (chain_dtypes, T)
-        # §15 per-stage quantizations: execution parameters (not part of
-        # plan keys — StageSpec dtypes already differentiate), threaded
-        # straight to the launches.
-        chain_quants = (
-            tuple(lowered.quants) if lowered.quants else (None,) * T
-        )
-        assert len(chain_quants) == T, (chain_quants, T)
-        eff = tuple(
-            str(jnp.dtype(dt).name) if dt is not None else in_name
-            for dt in chain_dtypes
-        )
-        req_dtypes = tuple(dt if dt != in_name else None for dt in eff)
-        if all(dt is None for dt in req_dtypes):
-            eff = None
-            req_dtypes = None
-    else:  # multi-RHS single application
-        if len(us) != len(lowered.inputs):
-            raise ValueError(
-                f"program loads {len(lowered.inputs)} inputs; got "
-                f"{len(us)} arrays"
-            )
-        # ``us`` arrives in load order; the combine may sum the operands
-        # in any order, and stage p applies to lowered.inputs[p].
-        load_order = {name: i for i, name in enumerate(prog.inputs())}
-        order = tuple(load_order[name] for name in lowered.inputs)
-        us = tuple(us[i] for i in order)
-        if order == tuple(range(len(us))):
-            order = None
-        chain = None
-        bcs = ()
-        T = 1
-        eff = req_dtypes = None
-        chain_quants = (None,)
-        offsets_list = [
-            np.asarray(o, dtype=np.int64).reshape(-1, d)
-            for o, _ in lowered.stages
-        ]
-        weights_list = [list(wts) for _, wts in lowered.stages]
-    explicit_sweep = sweep_axis is not None
-    explicit_shard = shard_axis is not None
-    if num_shards is None:
-        if mesh is not None:
-            num_shards = int(np.prod([mesh.shape[a] for a in mesh.axis_names]))
-        elif plan is not None:
-            num_shards = plan.num_shards
-    depth = None
-    if tune and (plan is not None or tile is not None):
-        raise ValueError(
-            "tune= requests the §11 measured-cost planning loop, but "
-            "plan=/tile= pin the decision already — pass one or the other"
-        )
-    resolved_plan = None
-    if plan is not None:
-        from repro.plan import validate_plan_call
-
-        validate_plan_call(
-            plan,
-            us[0].shape,
-            [np.asarray(o).reshape(-1, d) for o in offsets_list],
-            us[0].dtype.itemsize,
-            time_steps=T,
-            stages=[offs for offs, _ in chain] if chain is not None else None,
-            bcs=bcs if chain is not None else None,
-            dtypes=req_dtypes if chain is not None else None,
-        )
-        if tile is None:
-            tile = plan.tile
-        if sweep_axis is None:
-            sweep_axis = plan.sweep_axis
-        if shard_axis is None:
-            shard_axis = plan.shard_axis
-        if window_kind is None:
-            window_kind = plan.window_kind
-        pipelined = pipelined and plan.pipelined
-        depth = plan.fused_depth
-        resolved_plan = plan
-    elif tile is None:
-        choice = _auto_tile(
-            us[0].shape, offsets_list, us[0].dtype.itemsize, len(us),
-            vmem_budget=vmem_budget, time_steps=T,
-            stages=(
-                [offs for offs, _ in chain] if chain is not None else None
-            ),
-            num_shards=num_shards or 1,
-            tune=tune,
-            bcs=bcs if chain is not None else None,
-            dtypes=req_dtypes if chain is not None else None,
-            window_kind=window_kind or "auto",
-            shard_axis=shard_axis,
-        )
-        tile = choice.tile
-        if sweep_axis is None:
-            sweep_axis = choice.sweep_axis
-        if shard_axis is None:
-            shard_axis = choice.shard_axis
-        if window_kind is None:
-            window_kind = choice.window_kind
-        depth = choice.fused_depth
-        resolved_plan = choice
-    if sweep_axis is None:
-        sweep_axis = 0
-    if window_kind is None:
-        window_kind = "ring"  # §14 default: strictly smaller resident set
-    if depth is None:
-        depth = T  # explicit tile: the caller owns the VMEM arithmetic
-    tile = tuple(int(t) for t in tile)
-    sweep_axis = int(sweep_axis)
-    pipelined = bool(pipelined)
-    num_shards = 1 if num_shards is None else int(num_shards)
-    sharded = num_shards > 1 or mesh is not None
-
-    if (
-        sharded
-        and shard_axis is not None
-        and int(shard_axis) == sweep_axis
-        and explicit_shard != explicit_sweep
-    ):
-        # Exactly one of the two axes was pinned by the caller and the
-        # planner's independent choice of the other collided with it: the
-        # explicit pin wins — re-derive the free axis instead of refusing
-        # a feasible call.
-        if explicit_shard:
-            ncols = {
-                i: -(-us[0].shape[i] // tile[i])
-                for i in range(d)
-                if i != int(shard_axis)
-            }
-            if not ncols:  # 1-d grid: let the launcher raise its error
-                ncols = {sweep_axis: 1}
-            sweep_axis = max(ncols, key=lambda i: (ncols[i], -i))
-        else:
-            from repro.parallel.shard_columns import pick_shard_axis
-
-            shard_axis = pick_shard_axis(us[0].shape, tile, sweep_axis)
-
-    p = resolved_plan
-    if p is not None:
-        chain_bytes = (
-            p.per_shard_traffic_bytes * p.num_shards + p.halo_exchange_bytes
-        )
-        plan_key = p.request.cache_key()
-    else:
-        plan_key = "<explicit-tile>"  # explicit tile: the caller owns the model
-
-    def bind(statics, n_run, x_dtype, run=None, run_dts=None, run_qs=None,
-             bcs_w=None, in_q=None):
-        """One launch, bound: the sharded path's built program or the
-        single-device bound call; and what a recorder gets for it — this
-        launch's slice of the plan's whole-chain model (n_run of T
-        stages), what it reads its input from (``input_buffer``), how far
-        the input stops short of whole (sublane, lane) grains
-        (``grid_slack``), and the counters the report CLI reconciles
-        against the spans.  The ``kernel_launch`` span times the host's
-        side only: the bound call until it returns the unready array."""
-        xbytes = None
-        if sharded:
-            from repro.parallel.shard_columns import column_launch
-
-            call, xbytes = column_launch(
-                shape, x_dtype, len(us), num_shards=num_shards,
-                shard_axis=shard_axis, mesh=mesh, **statics,
-            )
-        else:
-            call = _bound_call(tuple(sorted(statics.items())))
-        if p is not None:
-            n_stages = max(len(chain) if chain is not None else 1, 1)
-            mb = round(chain_bytes * n_run / n_stages)
-            mf = round(p.modeled_flops * n_run / n_stages)
-        else:
-            mb = mf = 0
-        # §14 frontier accounting: the modeled VMEM bytes of this
-        # launch's staged buffers under the resolved window kind, at each
-        # stage's own dtype — reconciled by ``repro.obs.report --check``.
-        rvb = 0
-        if run is not None and len(run) > 1:
-            run_halos = [halo_from_offsets([o], d) for o, _ in run]
-            in_db = us[0].dtype.itemsize
-            sdb = [
-                dtype_itemsize(dt) if dt is not None else in_db
-                for dt in (run_dts or (None,) * len(run))
-            ]
-            rvb = fused_stage_bytes(
-                tile, run_halos[0], in_db, len(run),
-                stage_halos=run_halos, window_kind=window_kind,
-                sweep_axis=sweep_axis, stage_dtype_bytes=sdb,
-            ) * max(num_shards, 1)
-        quantized = run_qs is not None and any(
-            q is not None for q in run_qs
-        )
-        x_db = jnp.dtype(x_dtype).itemsize
-        halo = (
-            chain_halo([halo_from_offsets([o], d) for o, _ in run])
-            if run is not None
-            else halo_from_offsets(offsets_list, d)
-        )
-        buf = input_buffer(shape, tile, halo, x_db, bcs_w, in_q, num_shards)
-        slack = grid_slack(shape, x_db)
-        counters = (
-            ("launches", 1),
-            ("direct_input_launches", int(buf == "direct")),
-            ("offgrain_launches", int(any(slack))),
-            ("modeled_bytes", mb),
-            ("modeled_flops", mf),
-            ("ring_vmem_bytes", rvb),
-        )
-        if xbytes is not None:
-            # The exchange runs inside the jitted SPMD program (its device
-            # time is the ``stencil_halo`` scope of a profiler trace), so
-            # the counter holds the modeled cross-device bytes.
-            counters += (("halo_exchange_bytes", xbytes),)
-        args = dict(
-            plan_key=plan_key, tile=list(tile), sweep_axis=sweep_axis,
-            fused_depth=int(depth), steps=n_run, num_shards=num_shards,
-            interpret=interpret, modeled_bytes=mb, modeled_flops=mf,
-            program=prog_summary, window_kind=window_kind,
-            input_buffer=buf, grid_slack=list(slack),
-            stage_dtypes=(list(run_dts) if run_dts is not None else None),
-            ring_vmem_bytes=rvb,
-            stage_quants=(
-                [list(q) if q is not None else None for q in run_qs]
-                if quantized else None
-            ),
-        )
-        return _Launch(call, args, counters)
-
-    def static_spec(op):
-        offs, wts = op
-        return (tuple(map(tuple, np.asarray(offs).tolist())), tuple(wts))
-
-    base = dict(tile=tile, sweep=sweep_axis, pipelined=pipelined,
-                interpret=interpret)
-    launches = []
-    if chain is None:  # multi-RHS single application
-        offsets_w = tuple(
-            static_spec((o, tuple(float(w) for w in ws)))
-            for o, ws in zip(offsets_list, weights_list)
-        )
-        launches.append(bind(dict(base, offsets_w=offsets_w), 1,
-                             str(jnp.dtype(us[0].dtype).name)))
-    else:
-        pos = 0
-        in_q = None
-        x_dtype = in_name
-        while pos < len(chain):
-            run = chain[pos : pos + int(depth)]
-            run_bcs = tuple(bcs[pos : pos + len(run)])
-            run_dts = (
-                tuple(eff[pos : pos + len(run)]) if eff is not None else None
-            )
-            run_qs = tuple(chain_quants[pos : pos + len(run)])
-            pos += len(run)
-            bcs_w = run_bcs if any(bc is not None for bc in run_bcs) else None
-            statics = dict(base, offsets_w=(static_spec(run[0]),))
-            if bcs_w is not None or run_dts is not None:
-                # §13 boundary-op / §14 mixed-dtype / §15 quantized
-                # launch: always the stage-chain form (even for one
-                # stage), with the lowered per-stage bcs as in-kernel
-                # correction taps and the per-stage output dtypes on the
-                # frontiers/write-back.  A quantized stage anywhere in
-                # the chain forces eff non-None (its dtype is int8), so
-                # every launch of such a chain takes this branch and the
-                # quantized inter-launch handoff (``in_q``) is threaded.
-                statics.update(
-                    stages_w=tuple(static_spec(op) for op in run),
-                    bcs_w=bcs_w,
-                    dtypes_w=run_dts,
-                    window_kind=window_kind,
-                    quants_w=run_qs if any(
-                        q is not None for q in run_qs
-                    ) else None,
-                    in_quant=in_q,
-                )
-            elif len(run) > 1:
-                statics.update(
-                    stages_w=tuple(static_spec(op) for op in run),
-                    window_kind=window_kind,
-                )
-            launches.append(bind(statics, len(run), x_dtype, run, run_dts,
-                                 run_qs, bcs_w, in_q))
-            # The next launch reads this one's output, at its last stage's
-            # dtype.
-            if run_dts is not None:
-                x_dtype = run_dts[-1]
-            in_q = run_qs[-1]
-    from repro.plan import default_planner
-
-    return _Entry(
-        launches=tuple(launches),
-        order=order,
-        pins=(plan, mesh, default_planner()),
-        program=(
-            weakref.ref(program) if isinstance(program, ir.Program) else None
-        ),
-    )
+        globals()[name] = stencil_iterate
+        return stencil_iterate
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -35,9 +35,7 @@ Enabling, in precedence order (innermost wins; recorders nest):
   installed at first ``repro.obs`` import, flushed to ``path.json`` at
   interpreter exit (:func:`_activate_from_env`);
 * ``with obs.recording("path.json") as rec:`` — scoped recorder, trace
-  written on exit;
-* ``stencil_pallas(..., trace="path.json")`` — one traced kernel call
-  (the kernel frontends wrap themselves in :func:`recording`).
+  written on exit (around one call, it records that call).
 
 This module is dependency-free (stdlib only) and never imports jax; the
 optional ``jax.profiler`` bridge in :mod:`repro.obs.trace_event` only

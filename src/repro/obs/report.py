@@ -24,7 +24,7 @@ spans with ``input_buffer=direct``, the ``offgrain_launches`` counter
 matches the launch spans whose ``grid_slack`` is not ``(0, 0)`` (a
 grid off the (sublane, lane) grain), the ``entry_memo_hit`` and
 ``entry_memo_miss`` counters match the ``stencil_call`` roots carrying
-``memo=hit`` and ``memo=miss`` (the kernel entry's memo, DESIGN.md
+``memo=hit`` and ``memo=miss`` (the call entry's memo, DESIGN.md
 §17), and the summed ``measure`` span nanoseconds match
 ``measured_ns`` — exiting non-zero on any mismatch.  This is what the
 CI obs smoke runs.
@@ -121,7 +121,7 @@ def summarize(doc: dict) -> dict[str, Any]:
         "races": races,
         "candidates": candidates,
         "n_plan_spans": len(_spans(doc, "plan")),
-        # The kernel entry's memo outcome per ``stencil_call`` root (§17).
+        # The call entry's memo outcome per ``stencil_call`` root (§17).
         "memo": memo,
         "n_measure_spans": len(measures),
         "measure_ns_total": int(

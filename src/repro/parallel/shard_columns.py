@@ -6,7 +6,7 @@ rings keep them that way (each sweep column warms its own rings at
 ``k == 0``), so the sweep engine parallelizes over cores by *partitioning
 columns*, not by changing the kernel: this module splits one cross axis
 of the grid over a 1-axis device mesh, runs the unmodified
-:func:`repro.kernels.stencil._padded_call` sweep kernel on each shard's
+:func:`repro.kernels.stencil.padded_call` sweep kernel on each shard's
 column slab, and exchanges only the shard-boundary halos.
 
 Mechanics, per launch of a (possibly stage-fused) stencil program:
@@ -31,10 +31,10 @@ Mechanics, per launch of a (possibly stage-fused) stencil program:
 The planner prices this decomposition (plan schema v4:
 ``PlanRequest.num_shards``, ``StencilPlan.shard_axis`` /
 ``per_shard_traffic_bytes`` / ``halo_exchange_bytes``); the kernel
-frontends (``stencil_pallas(num_shards=...)``) route launches here.
+entry (:mod:`repro.entry`, ``num_shards=``/``mesh=``) routes launches here.
 
 §14 rides along unchanged: ``window_kind``/``dtypes_w`` pass straight
-through to ``_padded_call``, and the exchanged halo bands are slices of
+through to ``padded_call``, and the exchanged halo bands are slices of
 the launch's *input* arrays — a mixed-precision chain's later launches
 therefore exchange at the previous stage's output dtype for free (the
 band inherits the array's element width).
@@ -48,6 +48,16 @@ from math import prod
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
+
+from repro.core.tiling import window_extents
+from repro.kernels.stencil import (
+    bound_call,
+    embed_inputs,
+    launch_geometry,
+    launch_pads,
+    padded_call,
+    round_up,
+)
 
 __all__ = ["column_launch", "pick_shard_axis"]
 
@@ -77,11 +87,9 @@ def column_launch(shape, dtype, p, num_shards=None, shard_axis=None,
     cross-device halo traffic — ``None`` for a 1-shard request, which
     falls back to the single-device call.  ``mesh`` must be a 1-axis
     mesh; ``mesh=None`` builds one over the first ``num_shards`` devices
-    (:func:`repro.launch.mesh.make_column_mesh`).  The kernel entry keeps
+    (:func:`repro.launch.mesh.make_column_mesh`).  The call entry keeps
     the call in its memo (DESIGN.md §17), so a repeated signature builds
     no mesh and hashes no static tuple."""
-    from repro.kernels.stencil import _bound_call
-
     if mesh is None:
         if num_shards is not None and int(num_shards) > 1:
             from repro.launch.mesh import make_column_mesh
@@ -101,7 +109,7 @@ def column_launch(shape, dtype, p, num_shards=None, shard_axis=None,
         if size == 1:
             mesh = None
     if mesh is None:
-        return _bound_call(tuple(sorted(statics.items()))), None
+        return bound_call(tuple(sorted(statics.items()))), None
     shape = tuple(int(n) for n in shape)
     d = len(shape)
     tile = tuple(int(t) for t in statics["tile"])
@@ -128,12 +136,10 @@ def column_launch(shape, dtype, p, num_shards=None, shard_axis=None,
     )
     # The modeled cross-device bytes of one launch (same arithmetic as
     # _build_sharded).
-    from repro.kernels.stencil import _launch_geometry, _round_up
-
     S = int(mesh.shape[mesh.axis_names[0]])
-    *_, lo_w, hi_w = _launch_geometry(offsets_w, stages_w, tile, bcs_w=bcs_w)
+    *_, lo_w, hi_w = launch_geometry(offsets_w, stages_w, tile, bcs_w=bcs_w)
     lo_a, hi_a = int(lo_w[a]), int(hi_w[a])
-    padded = [_round_up(n, t) for n, t in zip(shape, tile)]
+    padded = [round_up(n, t) for n, t in zip(shape, tile)]
     cross_ext = prod(
         padded[i] + lo_w[i] + hi_w[i] for i in range(d) if i != a
     )
@@ -151,19 +157,11 @@ def _build_sharded(mesh, a, tile, sweep, pipelined, interpret, offsets_w,
     configuration — meshes and the offset/stage/boundary specs are
     hashable, so repeated shapes re-enter the compiled function
     directly."""
-    from repro.core.tiling import window_extents
-    from repro.kernels.stencil import (
-        _launch_geometry,
-        _padded_call,
-        embed_inputs,
-        launch_pads,
-    )
-
     itemsize = jnp.dtype(dtype).itemsize
     d = len(shape)
     axis_name = mesh.axis_names[0]
     S = int(mesh.shape[axis_name])
-    offsets, weights, stages, lo_w, hi_w = _launch_geometry(
+    offsets, weights, stages, lo_w, hi_w = launch_geometry(
         offsets_w, stages_w, tile, bcs_w=bcs_w, dtypes_w=dtypes_w,
         quants_w=quants_w,
     )
@@ -263,7 +261,7 @@ def _build_sharded(mesh, a, tile, sweep, pipelined, interpret, offsets_w,
         dom = jnp.zeros((d,), jnp.int32).at[a].set(
             idx.astype(jnp.int32) * C
         )
-        return _padded_call(
+        return padded_call(
             locs, dom, offsets, weights, stages, lo_w, hi_w, tile, sweep,
             pipelined, interpret, shape, window_kind=window_kind,
             in_quant=in_quant,

@@ -10,7 +10,7 @@ harness — treat the plan as the single source of truth for padding, tile
 shape, sweep axis and pipelining.
 
 ``python -m repro.plan.explain SHAPE`` prints a human-readable plan
-report (see :mod:`repro.plan.explain`); ``python -m repro.plan.tune
+report (see :mod:`repro.plan.explain`); ``python -m repro.autotune
 SHAPE`` races the top-k candidate plans on the live backend and persists
 the measured winner in the :class:`TunedPlanDB` (DESIGN.md §11 — a
 Planner built with ``tuned_db=`` then prefers measured winners).

@@ -9,8 +9,8 @@ planner's own single-pass choice, and the isoperimetric lower bound.
 ``--num-shards N`` plans the §10 column-sharded launch (per-shard
 figures + halo-exchange bytes).  ``--tuned`` additionally looks the
 request up in the §11 TunedPlanDB for this backend fingerprint and, on a
-hit, prints the stored measured-candidate table (``repro.plan.tune`` is
-the tool that writes it).  ``--smoke`` runs the CI gate: seven
+hit, prints the stored measured-candidate table (``python -m
+repro.autotune`` is the tool that writes it).  ``--smoke`` runs the CI gate: seven
 shapes (one unfavorable, one ``time_steps=3`` fused, one two-stage
 heterogeneous chain, one 4-way sharded, one §14 mixed-precision ring
 chain), asserting the pad triggers, the
@@ -415,7 +415,8 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--aligned", action="store_true",
                     help="restrict tiles to lane/sublane-aligned extents")
     ap.add_argument("--legacy", action="store_true",
-                    help="use the legacy _auto_tile strategy")
+                    help="use the pre-planner tile heuristic "
+                    "(Planner strategy='legacy')")
     ap.add_argument("--validate", action="store_true",
                     help="cache-simulate original vs padded grid")
     ap.add_argument("--tuned", action="store_true",
@@ -460,7 +461,7 @@ def main(argv: list[str] | None = None) -> int:
         if rec is None:
             print(
                 f"\ntuned: no record for this request at fingerprint {fp}\n"
-                "  (run `python -m repro.plan.tune "
+                "  (run `python -m repro.autotune "
                 f"{args.shape} --stencil {args.stencil}` to measure one)"
             )
         else:

@@ -38,8 +38,8 @@ Steps 1–3 only run when the request carries a hardware ``geometry``
 (a, z, w); on an explicitly-managed memory (TPU VMEM) conflict misses do
 not exist and the pad stage is a documented no-op.
 
-``strategy="legacy"`` reproduces the old ``kernels.stencil._auto_tile``
-heuristic exactly (default candidate set only); ``strategy="paper"`` adds
+``strategy="legacy"`` reproduces the pre-planner tile heuristic
+exactly (default candidate set only); ``strategy="paper"`` adds
 the lattice candidates and asserts it never predicts more traffic than
 legacy — the candidate set is a strict superset under the same model, so
 the assert is a model-consistency check, not a hope.
@@ -202,7 +202,7 @@ class Planner:
     def generation(self) -> tuple:
         """Changes whenever a plan this planner serves may change: its
         cache is swapped or cleared, or a tuned DB is attached or
-        written.  The kernel entry keys its memo of resolved launches
+        written.  The call entry keys its memo of resolved launches
         (DESIGN.md §17) on it."""
         db = self._tuned_db
         return (self._gen, self._cache.generation,
@@ -938,7 +938,7 @@ _DEFAULT: Planner | None = None
 
 def default_planner() -> Planner:
     """Process-wide planner with the persistent default cache — what the
-    kernel layer consults when no explicit plan is passed."""
+    call entry consults when no explicit plan is passed."""
     global _DEFAULT
     if _DEFAULT is None:
         _DEFAULT = Planner()

@@ -224,9 +224,8 @@ class StageSpec:
 class PlanRequest:
     """Canonical inputs of one planning problem (the cache key's preimage).
 
-    ``offsets`` is a tuple of per-RHS offset groups, matching
-    ``multi_stencil_pallas``'s ``offsets_list`` (a single-array stencil is a
-    1-tuple).  ``geometry`` is an ``(a, z, w)`` hardware-cache model for the
+    ``offsets`` is a tuple of per-RHS offset groups, one per load of a
+    §5 multi-RHS program (a single-array stencil is a 1-tuple).  ``geometry`` is an ``(a, z, w)`` hardware-cache model for the
     paper's CPU pipeline (unfavorable-grid detection + padding); ``None``
     means an explicitly-managed memory (TPU VMEM), where conflict misses do
     not exist and the pad stage is a no-op.
@@ -712,7 +711,7 @@ class PlanMismatchError(ValueError):
 
     Executing such a plan silently mis-tiles (wrong tile/sweep for the
     actual shape) or under-allocates the VMEM window (halo computed from
-    different offsets), so the kernel frontends refuse it loudly instead.
+    different offsets), so the call entry refuses it loudly instead.
     """
 
 

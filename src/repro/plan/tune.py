@@ -1,4 +1,4 @@
-"""Measured-cost autotune loop:  python -m repro.plan.tune 64x64x128
+"""Measured-cost autotune loop (command line: ``python -m repro.autotune``).
 
 The paper validates its miss model by *measuring* (Fig. 5: predicted vs
 observed misses on R10000); the planner so far trusts the §4 analytic
@@ -29,29 +29,30 @@ pricing to learn from, never served as the winner of the request they
 did not answer.
 
 A Planner constructed with ``tuned_db=`` (or an :class:`AutoTuner` used
-directly, or ``stencil_pallas(..., tune=True)``) then *prefers* the
+directly, or ``stencil_iterate(..., tune=True)``) then *prefers* the
 measured winner on a warm DB hit — sub-ms, no re-measurement — and falls
 back to the analytic choice unchanged on a miss.
 
 The tuner generates its own input arrays (the timing depends on shapes
-and dtypes, never on values) and launches each candidate with
-``plan=candidate`` explicitly, so tuning never recurses into tuning.
+and dtypes, never on values) and launches each candidate as a program
+through the *runner* its caller passes to :meth:`AutoTuner.tune` or
+:meth:`AutoTuner.plan` (the call entry passes its own
+:func:`repro.entry.run`), with ``plan=candidate`` explicitly, so tuning
+never recurses into tuning and the plan layer never imports the entry.
+The command line lives above the entry.
 
-jax and the kernel layer are imported lazily: importing ``repro.plan``
-must never fix the process's device topology before a caller (conftest,
-benchmarks, this CLI) has set ``XLA_FLAGS``.
+jax is imported lazily: importing ``repro.plan`` must never fix the
+process's device topology before a caller (conftest, benchmarks, the
+CLI) has set ``XLA_FLAGS``.
 """
 
 from __future__ import annotations
 
-import argparse
-import sys
 from datetime import datetime, timezone
 
 import numpy as np
 
 from .. import obs
-from .cache import PlanCache
 from .planner import Planner, default_planner
 from .schema import PlanRequest, StencilPlan
 from .tunedb import CandidateTiming, TunedPlanDB, TuneRecord
@@ -61,9 +62,7 @@ __all__ = [
     "backend_fingerprint",
     "default_tuner",
     "format_record",
-    "main",
     "resolve_tuner",
-    "smoke",
 ]
 
 
@@ -120,11 +119,12 @@ class AutoTuner:
     """Races candidate plans on the live backend, keeps measured winners.
 
     ``tune()`` measures one request and records a :class:`TuneRecord`;
-    ``plan()`` is the drop-in planning entry point the kernel layer's
+    ``plan()`` is the drop-in planning entry point the call entry's
     ``tune=`` knob routes through — warm DB hit returns the measured
     winner without re-measurement, miss tunes first.  ``force=True``
     re-measures even on a warm hit (fresh numbers after a driver or
-    clock change).
+    clock change).  Both take ``runner``, which runs one candidate as
+    ``runner(program, arrays, plan=, interpret=)``: :func:`repro.entry.run`.
     """
 
     def __init__(
@@ -149,10 +149,11 @@ class AutoTuner:
 
     # -- launching one candidate ------------------------------------------
 
-    def _launch_fn(self, request: PlanRequest, plan: StencilPlan,
+    def _launch_fn(self, request: PlanRequest, plan: StencilPlan, run,
                    quants=None):
         """A zero-arg closure running the request's whole computation under
-        ``plan`` — the thing :func:`repro.runtime.timing.measure` times.
+        ``plan`` through the runner ``run`` — the thing
+        :func:`repro.runtime.timing.measure` times.
         Inputs are synthesized here (timing depends on shape/dtype, not
         values); weights default to uniform 1/s so deep chains stay
         bounded.  ``plan=plan`` pins tile/sweep/depth/shard explicitly, so
@@ -167,7 +168,6 @@ class AutoTuner:
         import jax.numpy as jnp
 
         from repro import ir
-        from repro.kernels.stencil import multi_stencil_pallas
 
         dtype = {2: jnp.bfloat16, 4: jnp.float32, 8: jnp.float64}.get(
             request.dtype_bytes, jnp.float32
@@ -200,19 +200,19 @@ class AutoTuner:
                 quants=quants,
             )
             us = (mk(),)
-            return lambda: multi_stencil_pallas(
-                us, None, None, plan=plan, program=prog,
-                interpret=interpret,
-            )
+            return lambda: run(prog, us, plan=plan, interpret=interpret)
         offsets_list = [
             np.asarray(g, dtype=np.int64) for g in request.offsets
         ]
         weights_list = [(1.0 / len(g),) * len(g) for g in offsets_list]
+        d = len(request.shape)
+        if len(offsets_list) == 1:
+            prog = ir.stencil_program(offsets_list[0], weights_list[0],
+                                      request.time_steps, d=d)
+        else:
+            prog = ir.rhs_program(offsets_list, weights_list, d=d)
         us = tuple(mk() for _ in offsets_list)
-        return lambda: multi_stencil_pallas(
-            us, offsets_list, weights_list, plan=plan,
-            time_steps=request.time_steps, interpret=interpret,
-        )
+        return lambda: run(prog, us, plan=plan, interpret=interpret)
 
     # -- the §15 variant survey --------------------------------------------
 
@@ -300,7 +300,7 @@ class AutoTuner:
     # -- the tune pass -----------------------------------------------------
 
     def tune(
-        self, request: PlanRequest | None = None, /, **kw
+        self, request: PlanRequest | None = None, /, *, runner, **kw
     ) -> TuneRecord:
         """Measure the top-k candidates of one request and persist the
         result.  Candidate 0 is the planner's analytic argmin; the winner
@@ -323,7 +323,7 @@ class AutoTuner:
             entries += self._variants(request, cands[0])
             timed = []
             for rank, (plan, lreq, qns, advisory) in enumerate(entries):
-                fn = self._launch_fn(lreq, plan, quants=qns)
+                fn = self._launch_fn(lreq, plan, runner, quants=qns)
                 if obs.enabled():
                     with obs.span(
                         "tune_candidate", plan_key=key, rank=rank,
@@ -408,18 +408,21 @@ class AutoTuner:
         self.last_record = rec
         return rec
 
-    def plan(self, request: PlanRequest | None = None, /, **kw) -> StencilPlan:
+    def plan(
+        self, request: PlanRequest | None = None, /, *, runner, **kw
+    ) -> StencilPlan:
         """Planning entry point with measured preference: warm DB hit →
-        the measured winner (no re-measurement); miss → tune, then the
-        winner.  Signature-compatible with ``Planner.plan``, which is
-        what lets ``stencil_pallas(tune=...)`` swap it in."""
+        the measured winner (no re-measurement); miss → tune through
+        ``runner``, then the winner.  Otherwise signature-compatible with
+        ``Planner.plan``, which is what lets a call's ``tune=`` swap it
+        in."""
         if request is None:
             kw.setdefault("strategy", self.planner.strategy)
             request = PlanRequest.make(**kw)
         if obs.enabled():
             with obs.span("plan", key=request.cache_key(),
                           source="autotuner") as sp:
-                plan = self._plan_resolve(request)
+                plan = self._plan_resolve(request, runner)
                 sp.set(
                     tuned=self.last_plan_tuned,
                     tile=list(plan.tile),
@@ -427,9 +430,9 @@ class AutoTuner:
                     num_shards=plan.num_shards,
                 )
             return plan
-        return self._plan_resolve(request)
+        return self._plan_resolve(request, runner)
 
-    def _plan_resolve(self, request: PlanRequest) -> StencilPlan:
+    def _plan_resolve(self, request: PlanRequest, runner) -> StencilPlan:
         rec = None
         if not self.force:
             rec = self.db.get(
@@ -437,7 +440,7 @@ class AutoTuner:
             )
         self.last_plan_tuned = rec is not None
         if rec is None:
-            rec = self.tune(request)
+            rec = self.tune(request, runner=runner)
         self.last_record = rec
         return rec.winner_plan
 
@@ -447,7 +450,7 @@ _DEFAULT: AutoTuner | None = None
 
 def default_tuner() -> AutoTuner:
     """Process-wide tuner over the default planner and persistent DB —
-    what ``stencil_pallas(tune=True)`` resolves to."""
+    what a call's ``tune=True`` resolves to."""
     global _DEFAULT
     if _DEFAULT is None:
         _DEFAULT = AutoTuner()
@@ -455,7 +458,7 @@ def default_tuner() -> AutoTuner:
 
 
 def resolve_tuner(tune) -> AutoTuner | None:
-    """The kernel layer's ``tune=`` knob: ``None``/``False`` → no tuning,
+    """The call entry's ``tune=`` knob: ``None``/``False`` → no tuning,
     ``True`` → the default tuner, an :class:`AutoTuner` → itself."""
     if tune is None or tune is False:
         return None
@@ -513,197 +516,3 @@ def format_record(rec: TuneRecord) -> str:
         f"{rec.rank_correlation:+.3f} over {len(rec.candidates)} candidates",
     ]
     return "\n".join(lines)
-
-
-# -- CLI -------------------------------------------------------------------
-
-
-def smoke() -> int:
-    """CI gate: tune one tiny grid end-to-end (k=2, n=3 reps, interpret
-    mode on CPU), assert the §11 promises — never_slower holds, the
-    record round-trips, a Planner with the DB attached serves the
-    measured winner on a warm hit in < 1 ms without re-measuring."""
-    import time
-
-    from repro.core.cache_fitting import star_stencil
-
-    db = TunedPlanDB(persistent=False)
-    tuner = AutoTuner(
-        db=db,
-        planner=Planner(cache=PlanCache(persistent=False)),
-        k=2, reps=3, warmup=1,
-    )
-    kw = dict(
-        shape=(16, 16, 128), offsets=star_stencil(3, 1),
-        vmem_budget=256 * 1024, aligned=True,
-    )
-    t0 = time.perf_counter()
-    rec = tuner.tune(**kw)
-    tune_s = time.perf_counter() - t0
-    assert rec.never_slower, "never_slower gate failed"
-    assert rec.speedup_vs_analytic >= 1.0
-    assert len(rec.candidates) >= 1
-    assert TuneRecord.from_dict(rec.to_dict()) == rec, "record round-trip"
-    print(format_record(rec))
-
-    # Warm preference: the planner serves the measured winner, fast.
-    planner = Planner(cache=PlanCache(persistent=False), tuned_db=db)
-    measured_before = db.stats["misses"]
-    warm = []
-    for _ in range(3):  # best-of-3: absorb one-time fingerprint warm-up
-        t0 = time.perf_counter()
-        served = planner.plan(**kw)
-        warm.append((time.perf_counter() - t0) * 1e3)
-        assert planner.last_plan_tuned, "warm hit not served from tuned DB"
-        assert served == rec.winner_plan
-    assert db.stats["misses"] == measured_before, "warm hit re-measured"
-    warm_ms = min(warm)
-    assert warm_ms < 1.0, f"warm tuned hit took {warm_ms:.2f} ms"
-    print(
-        f"tune smoke: {len(rec.candidates)} candidates in {tune_s:.2f} s, "
-        f"winner {rec.winner} ({rec.speedup_vs_analytic:.3f}x), "
-        f"warm_hit={warm_ms:.3f} ms  OK"
-    )
-
-    # §15 variant race: a fused chain must put the window flip and the
-    # bf16/int8 storage variants on the track.  The int8-quantized ring
-    # rows are advisory — measured, recorded, never the winner — and the
-    # never-slower gate must still hold over the eligible rows.
-    t0 = time.perf_counter()
-    chain = tuner.tune(
-        shape=(32, 256), offsets=star_stencil(2, 1), time_steps=3,
-        vmem_budget=256 * 1024, aligned=True,
-    )
-    chain_s = time.perf_counter() - t0
-    assert chain.never_slower, "chain never_slower gate failed"
-    kinds = {c.window_kind for c in chain.candidates}
-    assert kinds >= {"ring", "trapezoid"}, f"window race missing: {kinds}"
-    named = {
-        dt for c in chain.candidates if c.stage_dtypes
-        for dt in c.stage_dtypes if dt is not None
-    }
-    assert "int8" in named and "bfloat16" in named, (
-        f"dtype variants missing from the race: {named}"
-    )
-    assert all(
-        c.advisory for c in chain.candidates if c.stage_dtypes
-    ), "a numerics-changing dtype row raced as winner-eligible"
-    assert not chain.candidates[chain.winner].advisory
-    assert TuneRecord.from_dict(chain.to_dict()) == chain
-    print(format_record(chain))
-    print(
-        f"tune smoke (§15 chain): {len(chain.candidates)} rows in "
-        f"{chain_s:.2f} s, windows={sorted(kinds)}, "
-        f"advisory dtypes={sorted(named)}  OK"
-    )
-    return 0
-
-
-def _parse_shape(s: str) -> tuple[int, ...]:
-    for sep in ("x", ","):
-        if sep in s:
-            return tuple(int(p) for p in s.split(sep) if p)
-    return (int(s),)
-
-
-def main(argv: list[str] | None = None) -> int:
-    ap = argparse.ArgumentParser(
-        prog="python -m repro.plan.tune",
-        description=(
-            "Race the planner's top-k candidate plans on the live backend "
-            "and persist the measured winner (DESIGN.md §11)."
-        ),
-    )
-    ap.add_argument("shape", nargs="?", default="64x64x128",
-                    help="grid shape, e.g. 64x64x128")
-    ap.add_argument("--stencil", default="star:2",
-                    help="star:R or box:R (default star:2)")
-    ap.add_argument("--geom", default="none",
-                    help="cache geometry a,z,w for the analytic model "
-                         "(default none = explicitly managed memory; pass "
-                         "the same value used with repro.plan.explain so "
-                         "the request keys match)")
-    ap.add_argument("--dtype-bytes", type=int, default=4)
-    ap.add_argument("--budget", type=int, default=None,
-                    help="VMEM budget in bytes (default: planner default)")
-    ap.add_argument("--time-steps", type=int, default=1,
-                    help="tune the T-application fused chain (§8)")
-    ap.add_argument("--num-shards", type=int, default=1,
-                    help="tune the §10 column-sharded launch over N devices")
-    ap.add_argument("--aligned", action="store_true",
-                    help="restrict tiles to lane/sublane-aligned extents")
-    ap.add_argument("-k", type=int, default=4,
-                    help="candidates to race (default 4)")
-    ap.add_argument("--reps", type=int, default=5,
-                    help="timed reps per candidate (default 5)")
-    ap.add_argument("--warmup", type=int, default=1,
-                    help="un-timed warm-up calls per candidate (default 1)")
-    ap.add_argument("--db", default=None,
-                    help="tuned DB dir (default $REPRO_TUNED_DB_DIR or "
-                         "~/.cache/repro/tuned)")
-    ap.add_argument("--memory-only", action="store_true",
-                    help="do not persist the record to disk")
-    ap.add_argument("--force", action="store_true",
-                    help="re-measure even when a warm entry exists")
-    ap.add_argument("--devices", type=int, default=None,
-                    help="force N host platform devices (sets XLA_FLAGS; "
-                         "needed for --num-shards > 1 on CPU)")
-    ap.add_argument("--json", action="store_true",
-                    help="dump the tune record JSON")
-    ap.add_argument("--smoke", action="store_true",
-                    help="run the CI smoke gates instead")
-    args = ap.parse_args(argv)
-
-    if args.devices:
-        # Must land before the first jax import (lazy imports everywhere
-        # in repro.plan exist exactly so this still works here).
-        import os
-        assert "jax" not in sys.modules, (
-            "--devices must be set before jax is imported"
-        )
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.devices}"
-        ).strip()
-
-    if args.smoke:
-        return smoke()
-
-    from repro.core.cache_fitting import box_stencil, star_stencil
-
-    shape = _parse_shape(args.shape)
-    kind, _, r = args.stencil.partition(":")
-    r = int(r or 2)
-    if kind == "star":
-        offs = star_stencil(len(shape), r)
-    elif kind == "box":
-        offs = box_stencil(len(shape), r)
-    else:
-        raise SystemExit(f"unknown stencil spec {args.stencil!r}")
-
-    db = TunedPlanDB(db_dir=args.db, persistent=not args.memory_only)
-    tuner = AutoTuner(
-        db=db, k=args.k, reps=args.reps, warmup=args.warmup,
-        force=args.force,
-    )
-    geometry = None if args.geom.lower() == "none" else _parse_shape(args.geom)
-    tuner.plan(
-        shape=shape, offsets=offs, dtype_bytes=args.dtype_bytes,
-        vmem_budget=args.budget, geometry=geometry,
-        time_steps=args.time_steps, num_shards=args.num_shards,
-        aligned=args.aligned,
-    )
-    rec = tuner.last_record
-    if args.json:
-        import json
-        print(json.dumps(rec.to_dict(), indent=2, sort_keys=True))
-        return 0
-    served = "warm DB hit (no re-measurement)" if tuner.last_plan_tuned \
-        else "measured fresh"
-    print(format_record(rec))
-    print(f"  served: {served}")
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

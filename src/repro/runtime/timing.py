@@ -1,8 +1,8 @@
 """Measurement harness: wall-clock timing of jax callables, done right.
 
-Every measured number in this repo — the BENCH_PR*.json emitters, the
-``repro.plan.tune`` autotune loop — flows through :func:`measure`, so the
-methodology is defined once:
+Every host-timed number in this repo — the ``repro.plan.tune`` autotune
+loop first of all — flows through :func:`measure`, so the methodology is
+defined once:
 
 * **warm-up excluded**: the first ``warmup`` calls run (and block) before
   the clock starts, so jit tracing/compilation and first-touch allocation

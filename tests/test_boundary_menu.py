@@ -19,9 +19,9 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from repro import ir  # noqa: E402
+from repro.entry import run  # noqa: E402
 from repro.ir.verify import IRVerifyError  # noqa: E402
 from repro.kernels.ref import stencil_ref  # noqa: E402
-from repro.kernels.stencil import multi_stencil_pallas  # noqa: E402
 
 N_DEV = len(jax.devices())
 
@@ -43,8 +43,7 @@ def _u(shape=(24, 32), seed=0):
 def _chain(u, offs, w, steps, kind, value, **kw):
     prog = ir.chain_program([(offs, w)] * steps, u.ndim, boundary=kind,
                             value=value)
-    return multi_stencil_pallas([u], None, None, program=prog,
-                                interpret=True, **kw)
+    return run(prog, u, interpret=True, **kw)
 
 
 def _ref_chain(u, offs, w, steps, kind, value):
@@ -118,8 +117,7 @@ def test_mixed_bc_chain_matches_oracle():
         [(STAR, STAR_W), (BOX, BOX_W)], 2,
         boundary=[("robin", (0.4, 0.6)), ("neumann", 0.0)],
     )
-    got = multi_stencil_pallas([u], None, None, program=prog,
-                               tile=(16, 32), interpret=True)
+    got = run(prog, u, tile=(16, 32), interpret=True)
     ref = stencil_ref(u, STAR, STAR_W, boundary="robin", value=(0.4, 0.6))
     ref = stencil_ref(ref, BOX, BOX_W, boundary="neumann")
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),

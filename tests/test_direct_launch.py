@@ -24,9 +24,10 @@ from repro.core.tiling import (
     window_extents,
 )
 from repro.kernels.ref import star_weights_2nd_order
+from repro.entry import run, stencil_iterate
 from repro.kernels.stencil import (
-    _launch_geometry, _padded_call, _stencil_call, embed_inputs,
-    input_buffer, launch_pads, stencil_iterate, stencil_pallas,
+    _stencil_call, embed_inputs, input_buffer, launch_geometry, launch_pads,
+    padded_call,
 )
 
 KEY = jax.random.PRNGKey(7)
@@ -44,7 +45,7 @@ def _launch(us, offsets_w, tile, sweep, direct, stages_w=None, bcs_w=None,
             dtypes_w=None, window_kind="ring", pipelined=True):
     """One interpreted launch, from the caller's arrays (``direct``) or
     from the zero-filled launch buffer, trimmed to the grid."""
-    offsets, weights, stages, lo_w, hi_w = _launch_geometry(
+    offsets, weights, stages, lo_w, hi_w = launch_geometry(
         offsets_w, stages_w, tile, bcs_w, dtypes_w
     )
     u0 = us[0]
@@ -53,7 +54,7 @@ def _launch(us, offsets_w, tile, sweep, direct, stages_w=None, bcs_w=None,
     else:
         pads = launch_pads(u0.shape, tile, lo_w, hi_w, u0.dtype.itemsize)
         ins = embed_inputs(us, pads, pad_free=bcs_w is not None)
-    out = _padded_call(
+    out = padded_call(
         ins, jnp.zeros((u0.ndim,), jnp.int32), offsets, weights, stages,
         lo_w, hi_w, tile, sweep, pipelined, True, u0.shape,
         window_kind=window_kind, direct=direct,
@@ -231,12 +232,12 @@ def test_sharded_launch_keeps_its_buffer():
         u.shape, (1, 8, 256), halo, 4, num_shards=2
     ) == "pad"
     with obs.recording() as rec:
-        sharded = stencil_pallas(
-            u, offs, wts, tile=(1, 8, 256), sweep_axis=0, num_shards=2,
+        sharded = stencil_iterate(
+            u, offs, wts, 1, tile=(1, 8, 256), sweep_axis=0, num_shards=2,
             shard_axis=1,
         )
-        one = stencil_pallas(
-            u, offs, wts, tile=(1, 8, 256), sweep_axis=0, num_shards=1,
+        one = stencil_iterate(
+            u, offs, wts, 1, tile=(1, 8, 256), sweep_axis=0, num_shards=1,
         )
     bufs = [s.args["input_buffer"] for s in rec.spans
             if s.name == "kernel_launch"]
@@ -255,9 +256,10 @@ def test_direct_input_counter_and_span_attribute():
     with obs.recording() as rec:
         stencil_iterate(u, STAR, STAR_W, 2, tile=(1, 16, 256),
                         sweep_axis=0)
-        stencil_pallas(u[:, :, :130], STAR, STAR_W, tile=(1, 16, 130),
-                       sweep_axis=0)
-        ir.run_program(periodic, u, tile=(1, 16, 256), sweep_axis=0)
+        stencil_iterate(
+            u[:, :, :130], STAR, STAR_W, 1, tile=(1, 16, 130), sweep_axis=0,
+        )
+        run(periodic, u, tile=(1, 16, 256), sweep_axis=0)
     bufs = [s.args["input_buffer"] for s in rec.spans
             if s.name == "kernel_launch"]
     assert bufs == ["direct", "pad", "wrap"]
@@ -355,7 +357,7 @@ MG27_PLANS = {
 
 @pytest.mark.parametrize("n", sorted(MG27_PLANS))
 def test_sharded_mg27_plans_unchanged(n):
-    """The request ``ir.run_program(A, u, num_shards=4)`` plans with."""
+    """The request ``run(A, u, num_shards=4)`` plans with."""
     from repro.plan import PlanCache, Planner
 
     path = os.path.join(

@@ -1,4 +1,4 @@
-"""The kernel entry's memo of resolved launches (DESIGN.md §17): a
+"""The call entry's memo of resolved launches (DESIGN.md §17): a
 repeated call signature skips lowering and planning and runs the bound
 launches; anything that can change the launches is a miss."""
 
@@ -10,14 +10,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import entry as call_entry
 from repro import ir, obs
 from repro.core.cache_fitting import star_stencil
-from repro.kernels import stencil as kstencil
-from repro.kernels.stencil import (
-    _stencil_call,
-    multi_stencil_pallas,
-    stencil_iterate,
-)
+from repro.entry import run, stencil_iterate
+from repro.kernels.stencil import _stencil_call
 from repro.plan import default_planner
 from repro.plan.tunedb import TunedPlanDB
 
@@ -29,9 +26,9 @@ STAR_W = [0.4, 0.15, 0.15, 0.15, 0.15]
 def _fresh_memo():
     """Every test starts from an empty memo and no recorder."""
     assert obs.active() is None
-    kstencil._memo.clear()
+    call_entry._memo.clear()
     yield
-    kstencil._memo.clear()
+    call_entry._memo.clear()
 
 
 def _u(shape=(16, 128), dtype=jnp.float32, seed=0):
@@ -48,6 +45,7 @@ def _entry_kinds():
                              boundary="dirichlet", value=0.5)
     rhs_offs = [star_stencil(2, 1), star_stencil(2, 2)]
     rhs_w = [[0.3] * len(rhs_offs[0]), [0.1] * len(rhs_offs[1])]
+    rhs = ir.rhs_program(rhs_offs, rhs_w, d=2)
 
     def sharded():
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -60,7 +58,7 @@ def _entry_kinds():
                                   boundary="periodic")
         u = jax.device_put(_u((32, 16, 128)),
                            NamedSharding(make_column_mesh(4), P("columns")))
-        return (lambda x: ir.run_program(prog, x, num_shards=4,
+        return (lambda x: run(prog, x, num_shards=4,
                                          shard_axis=0)), (u,)
 
     return {
@@ -69,14 +67,14 @@ def _entry_kinds():
             (_u((16, 16, 128)),),
         ),
         "run_program": lambda: (
-            (lambda x: ir.run_program(star_prog, x)), (_u(),)
+            (lambda x: run(star_prog, x)), (_u(),)
         ),
         "multi_rhs": lambda: (
-            (lambda a, b: multi_stencil_pallas([a, b], rhs_offs, rhs_w)),
+            (lambda a, b: run(rhs, [a, b])),
             (_u((24, 140)), _u((24, 140), seed=1)),
         ),
         "boundary_chain": lambda: (
-            (lambda x: ir.run_program(chain, x)), (_u((24, 128)),)
+            (lambda x: run(chain, x)), (_u((24, 128)),)
         ),
         "sharded_4dev": sharded,
     }
@@ -133,7 +131,7 @@ def test_hit_matches_a_fresh_resolve(kind):
     call, arrays = _entry_kinds()[kind]()
     call(*arrays)
     hit = call(*arrays)
-    kstencil._memo.clear()
+    call_entry._memo.clear()
     fresh = call(*arrays)
     assert hit.dtype == fresh.dtype
     assert bool(jnp.all(hit == fresh))
@@ -163,7 +161,6 @@ VARIANTS = {
     "tile": lambda: _iterate(tile=(8, 128)),
     "vmem_budget": lambda: _iterate(vmem_budget=1 << 20),
     "sweep_axis": lambda: _iterate(sweep_axis=1),
-    "pipelined": lambda: _iterate(pipelined=False),
     "window_kind": lambda: _iterate(window_kind="trapezoid"),
 }
 
@@ -184,23 +181,22 @@ def test_interpret_is_keyed_as_resolved():
 def test_an_equal_new_program_misses_and_a_freed_one_is_not_kept():
     """A program is keyed by identity (its weak reference checked on a
     hit): an equal new object is a miss, and the memo keeps no program
-    alive."""
+    alive.  ``stencil_iterate`` builds its program once per spelling, so
+    an equal spelling in new objects is a hit."""
     u = _u()
     prog = ir.stencil_program(STAR, STAR_W, 1, d=2)
     same = ir.stencil_program(STAR, STAR_W, 1, d=2)
     assert prog == same and prog is not same
-    assert _miss_after_warm(lambda: ir.run_program(prog, u),
-                            lambda: ir.run_program(same, u)) == "miss"
+    assert _miss_after_warm(lambda: run(prog, u),
+                            lambda: run(same, u)) == "miss"
     ref = weakref.ref(prog)
     del prog
     gc.collect()
     assert ref() is None
-    # The serialized form is keyed by value.
-    text = same.serialize()
+    # A stencil_iterate spelling is keyed by value.
     assert _miss_after_warm(
-        lambda: multi_stencil_pallas([u], None, None, program=text),
-        lambda: multi_stencil_pallas([u], None, None,
-                                     program="".join(list(text))),
+        lambda: stencil_iterate(u, STAR, STAR_W, 1),
+        lambda: stencil_iterate(u, STAR.copy(), list(STAR_W), 1),
     ) == "hit"
 
 
@@ -210,11 +206,11 @@ def test_a_reused_program_id_misses():
     apart and the lookup misses (and drops the stale entry)."""
     prog = ir.stencil_program(STAR, STAR_W, 1, d=2)
     other = ir.stencil_program(STAR, [0.5] + STAR_W[1:], 1, d=2)
-    ir.run_program(prog, _u())
-    ((key, entry),) = kstencil._memo.items()
-    assert kstencil._memo_get(key, prog) is entry
-    assert kstencil._memo_get(key, other) is None
-    assert not kstencil._memo
+    run(prog, _u())
+    ((key, entry),) = call_entry._memo.items()
+    assert call_entry._memo_get(key, prog) is entry
+    assert call_entry._memo_get(key, other) is None
+    assert not call_entry._memo
 
 
 def test_planner_generation_invalidates(monkeypatch):
@@ -271,7 +267,7 @@ def test_hit_does_not_retrace(kind):
     they nor ``_stencil_call`` trace again."""
     call, arrays = _entry_kinds()[kind]()
     call(*arrays).block_until_ready()
-    (entry,) = kstencil._memo.values()
+    (entry,) = call_entry._memo.values()
     bound = [launch.call for launch in entry.launches]
     sizes = [b._cache_size() for b in bound]
     plain = _stencil_call._cache_size()
@@ -279,7 +275,7 @@ def test_hit_does_not_retrace(kind):
         call(*arrays).block_until_ready()
     assert [b._cache_size() for b in bound] == sizes
     assert _stencil_call._cache_size() == plain
-    (again,) = kstencil._memo.values()
+    (again,) = call_entry._memo.values()
     assert again is entry
 
 
@@ -288,7 +284,7 @@ def test_memo_keeps_no_caller_array_alive():
     ref = weakref.ref(u)
     out = _iterate(u)
     out2 = _iterate(u)
-    assert kstencil._memo
+    assert call_entry._memo
     del u, out, out2
     gc.collect()
     assert ref() is None
@@ -296,17 +292,17 @@ def test_memo_keeps_no_caller_array_alive():
 
 def test_lru_bound_holds(monkeypatch):
     """The memo keeps its newest ``_MEMO_SIZE`` signatures."""
-    monkeypatch.setattr(kstencil, "_MEMO_SIZE", 2)
+    monkeypatch.setattr(call_entry, "_MEMO_SIZE", 2)
     u = _u()
     budgets = [1 << 20, 2 << 20, 3 << 20]
     for b in budgets:
         _iterate(u, tile=(8, 128), vmem_budget=b)
-    assert len(kstencil._memo) == 2
+    assert len(call_entry._memo) == 2
     with obs.recording() as rec:
         _iterate(u, tile=(8, 128), vmem_budget=budgets[-1])
         _iterate(u, tile=(8, 128), vmem_budget=budgets[0])
     assert [r.args["memo"] for r in _roots(rec)] == ["hit", "miss"]
-    assert len(kstencil._memo) == 2
+    assert len(call_entry._memo) == 2
 
 
 def test_report_reconciles_memo_counters(tmp_path):

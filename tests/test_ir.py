@@ -4,9 +4,9 @@ Covers: serialization round-trips and the canonical plan-key normal form
 (every spelling of one computation — ``time_steps=``, ``stages=``, an
 explicit program — shares a single serialized key); the shape-inference
 pass pinned against the legacy §9 halo arithmetic for T ∈ {1, 2, 3};
-verify/lowering legality errors; bit-wise parity of the legacy frontends
-with their program spellings (the acceptance criterion of the IR
-refactor); boundary-op lowering to in-kernel correction taps (dirichlet
+verify/lowering legality errors; bit-wise parity of ``stencil_iterate``
+and of the engine's multi-RHS launch with their program spellings run
+through ``repro.entry.run``; boundary-op lowering to in-kernel correction taps (dirichlet
 / neumann / reflect vs the :func:`repro.kernels.ref.stencil_ref`
 oracle, single-stage and fused, single-device and on the 4-device mesh
 with zero host-side ``jnp.pad`` on the hot path); and the
@@ -34,16 +34,12 @@ from repro.ir import (
     infer_halos,
     plan_program_key,
     rhs_program,
-    run_program,
     stencil_program,
     summarize_program,
 )
+from repro.entry import run, stencil_iterate
 from repro.kernels.ref import stencil_ref
-from repro.kernels.stencil import (
-    multi_stencil_pallas,
-    stencil_iterate,
-    stencil_pallas,
-)
+from repro.kernels.stencil import _stencil_call
 from repro.plan import PlanCache, Planner, PlanRequest
 
 KEY = jax.random.PRNGKey(7)
@@ -87,6 +83,11 @@ def test_spellings_share_one_plan_key():
     assert a.canonical().serialize() == key
     assert b.canonical().serialize() == key
     assert c.canonical().serialize() == key
+    # A boundary op is part of the computation: it splits the plan key.
+    plain = PlanRequest.make(shape=(32, 32), stages=[OFFS_S1] * 3)
+    neu = PlanRequest.make(shape=(32, 32), stages=[OFFS_S1] * 3,
+                           bcs=["neumann"] * 3)
+    assert neu.cache_key() != plain.cache_key()
 
 
 def test_zero_boundary_drops_from_plan_key():
@@ -263,7 +264,7 @@ def test_lower_multi_rhs_folds_coeffs():
 
 
 # ---------------------------------------------------------------------------
-# Bit-parity: legacy spellings vs their program form (acceptance).
+# Bit-parity: stencil_iterate's spellings vs their program form.
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("T", [1, 2, 3])
@@ -271,7 +272,7 @@ def test_program_spelling_bitwise_equals_stages(T):
     u = jax.random.normal(KEY, (50, 45), jnp.float32)
     stages = CHAIN3[:T]
     legacy = stencil_iterate(u, stages=stages, tile=(8, 16), sweep_axis=0)
-    prog = run_program(
+    prog = run(
         chain_program(stages, d=2), u, tile=(8, 16), sweep_axis=0
     )
     np.testing.assert_array_equal(np.asarray(legacy), np.asarray(prog))
@@ -279,10 +280,10 @@ def test_program_spelling_bitwise_equals_stages(T):
 
 def test_program_spelling_bitwise_equals_time_steps():
     u = jax.random.normal(KEY, (30, 40), jnp.float32)
-    legacy = stencil_pallas(
-        u, OFFS_S1, list(W_S1), time_steps=3, tile=(8, 16), sweep_axis=0
+    legacy = stencil_iterate(
+        u, OFFS_S1, list(W_S1), 3, tile=(8, 16), sweep_axis=0,
     )
-    prog = run_program(
+    prog = run(
         stencil_program(OFFS_S1, W_S1, time_steps=3, d=2),
         u, tile=(8, 16), sweep_axis=0,
     )
@@ -291,26 +292,34 @@ def test_program_spelling_bitwise_equals_time_steps():
 
 def test_program_spelling_bitwise_equals_multi_rhs():
     ua = jax.random.normal(KEY, (30, 40), jnp.float32)
+    """The §5 sum as a program equals the engine's multi-RHS launch bit
+    for bit, and the oracle's sum of the two applications."""
     ub = jax.random.normal(jax.random.PRNGKey(8), (30, 40), jnp.float32)
-    legacy = multi_stencil_pallas(
-        [ua, ub], [OFFS_S1, OFFS_S2], [list(W_S1), list(W_S2)],
-        tile=(8, 16), sweep_axis=0,
+    specs = tuple(
+        (tuple(map(tuple, np.asarray(o).tolist())), tuple(map(float, w)))
+        for o, w in ((OFFS_S1, W_S1), (OFFS_S2, W_S2))
     )
-    prog = run_program(
+    launch = _stencil_call((ua, ub), specs, (8, 16), 0, True, True)
+    prog = run(
         rhs_program([OFFS_S1, OFFS_S2], [W_S1, W_S2], d=2),
         {"u0": ua, "u1": ub}, tile=(8, 16), sweep_axis=0,
     )
-    np.testing.assert_array_equal(np.asarray(legacy), np.asarray(prog))
+    np.testing.assert_array_equal(np.asarray(launch), np.asarray(prog))
+    ref = stencil_ref(ua, OFFS_S1, list(W_S1)) + stencil_ref(
+        ub, OFFS_S2, list(W_S2)
+    )
+    np.testing.assert_allclose(np.asarray(prog), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
 
 
 def test_explicit_zero_boundary_bitwise_equals_plain():
     """A zero boundary op lowers to the engine-native fill: same bits,
     same cache key, no correction taps."""
     u = jax.random.normal(KEY, (40, 33), jnp.float32)
-    plain = run_program(
+    plain = run(
         chain_program(CHAIN3[:2], d=2), u, tile=(8, 16), sweep_axis=0
     )
-    zero = run_program(
+    zero = run(
         chain_program(CHAIN3[:2], d=2, boundary="zero"),
         u, tile=(8, 16), sweep_axis=0,
     )
@@ -330,7 +339,7 @@ def test_explicit_zero_boundary_bitwise_equals_plain():
 def test_boundary_single_stage_matches_oracle(kind, value, offs, wts):
     u = jax.random.normal(KEY, (40, 33), jnp.float32)
     prog = chain_program([(offs, wts)], d=2, boundary=kind, value=value)
-    out = run_program(prog, u, tile=(8, 16), sweep_axis=0)
+    out = run(prog, u, tile=(8, 16), sweep_axis=0)
     ref = stencil_ref(u, offs, list(wts), boundary=kind, value=value)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
@@ -346,7 +355,7 @@ def test_boundary_fused_chain_matches_stagewise_oracle(kind):
     stages = [(OFFS_S1, W_S1), (OFFS_S2, W_S2)]
     value = 0.4
     prog = chain_program(stages, d=2, boundary=kind, value=value)
-    out = run_program(prog, u, tile=(8, 16), sweep_axis=0)
+    out = run(prog, u, tile=(8, 16), sweep_axis=0)
     ref = bc_ref(u, stages, kind, value)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
@@ -358,7 +367,7 @@ def test_boundary_conv_asymmetric_halo_neumann():
     side only per axis."""
     u = jax.random.normal(KEY, (50, 45), jnp.float32)
     prog = chain_program([(OFFS_CONV, W_CONV)], d=2, boundary="neumann")
-    out = run_program(prog, u, tile=(8, 16), sweep_axis=0)
+    out = run(prog, u, tile=(8, 16), sweep_axis=0)
     ref = stencil_ref(u, OFFS_CONV, list(W_CONV), boundary="neumann")
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
@@ -370,7 +379,7 @@ def test_boundary_3d_reflect_matches_oracle():
     offs = star_stencil(3, 1)
     wts = tuple(np.linspace(0.05, 0.2, len(offs)).tolist())
     prog = chain_program([(offs, wts)], d=3, boundary="reflect")
-    out = run_program(prog, u, tile=(4, 8, 20), sweep_axis=0)
+    out = run(prog, u, tile=(4, 8, 20), sweep_axis=0)
     ref = stencil_ref(u, offs, list(wts), boundary="reflect")
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
@@ -382,7 +391,7 @@ def test_boundary_per_stage_mix():
     u = jax.random.normal(KEY, (40, 33), jnp.float32)
     stages = [(OFFS_S1, W_S1), (OFFS_S2, W_S2)]
     prog = chain_program(stages, d=2, boundary=["neumann", None])
-    out = run_program(prog, u, tile=(8, 16), sweep_axis=0)
+    out = run(prog, u, tile=(8, 16), sweep_axis=0)
     ref = stencil_ref(u, OFFS_S1, list(W_S1), boundary="neumann")
     ref = stencil_ref(ref, OFFS_S2, list(W_S2))
     np.testing.assert_allclose(
@@ -408,7 +417,7 @@ def test_neumann_program_on_mesh_no_host_pad(monkeypatch):
         [(OFFS_S1, W_S1), (OFFS_S1, W_S1)], d=2, boundary="neumann"
     )
     ref = bc_ref(u, [(OFFS_S1, W_S1)] * 2, "neumann")
-    single = run_program(prog, u, tile=(8, 16), sweep_axis=0)
+    single = run(prog, u, tile=(8, 16), sweep_axis=0)
 
     calls = []
     real_pad = jnp.pad
@@ -418,7 +427,7 @@ def test_neumann_program_on_mesh_no_host_pad(monkeypatch):
         return real_pad(*args, **kwargs)
 
     monkeypatch.setattr(jnp, "pad", counting_pad)
-    sharded = run_program(
+    sharded = run(
         prog, u, tile=(8, 16), sweep_axis=0, num_shards=4
     )
     monkeypatch.undo()

@@ -7,7 +7,7 @@ import pytest
 pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels.ops import apply_stencil
+from repro.entry import stencil_iterate
 from repro.kernels.ref import stencil_ref
 
 
@@ -23,7 +23,7 @@ def test_random_2d_stencils(shape, r, seed):
     offs = rng.integers(-r, r + 1, size=(n_pts, 2))
     w = rng.normal(size=n_pts).tolist()
     u = jax.random.normal(jax.random.PRNGKey(seed % 997), shape, jnp.float32)
-    out = apply_stencil(u, offs, w)
+    out = stencil_iterate(u, offs, w, 1)
     ref = stencil_ref(u, offs, w)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-4, rtol=1e-4)
@@ -38,5 +38,5 @@ def test_random_3d_stencils(seed):
     u = jax.random.normal(jax.random.PRNGKey(seed % 991), (6, 10, 136),
                           jnp.float32)
     np.testing.assert_allclose(
-        np.asarray(apply_stencil(u, offs, w)),
+        np.asarray(stencil_iterate(u, offs, w, 1)),
         np.asarray(stencil_ref(u, offs, w)), atol=1e-4, rtol=1e-4)

@@ -5,9 +5,9 @@ import numpy as np
 import pytest
 
 from repro.core.cache_fitting import box_stencil, star_stencil
-from repro.kernels.ops import (
-    apply_multi_rhs, apply_star_2nd_order, apply_stencil, plan_tiles,
-)
+from repro import ir
+from repro.entry import run, stencil_iterate
+from repro.kernels.ops import plan_tiles
 from repro.kernels.ref import star_weights_2nd_order, stencil_ref
 
 KEY = jax.random.PRNGKey(0)
@@ -25,7 +25,7 @@ def test_star_stencil_matches_ref(shape, r, dtype):
     u = jax.random.normal(KEY, shape, dtype)
     offs = star_stencil(d, r)
     w = np.linspace(-1, 1, len(offs)).tolist()
-    out = apply_stencil(u, offs, w)
+    out = stencil_iterate(u, offs, w, 1)
     ref = stencil_ref(u, offs, w)
     tol = 2e-5 if dtype == jnp.float32 else 6e-2
     np.testing.assert_allclose(
@@ -40,15 +40,16 @@ def test_box_stencil_matches_ref(shape):
     offs = box_stencil(2, 1)
     w = np.arange(len(offs), dtype=float).tolist()
     np.testing.assert_allclose(
-        apply_stencil(u, offs, w), stencil_ref(u, offs, w),
+        stencil_iterate(u, offs, w, 1), stencil_ref(u, offs, w),
         atol=1e-4, rtol=1e-4,
     )
 
 
 def test_paper_13pt_operator():
     u = jax.random.normal(KEY, (20, 30, 130), jnp.float32)
-    out = apply_star_2nd_order(u)
-    ref = stencil_ref(u, *star_weights_2nd_order(3, 2))
+    offs, w = star_weights_2nd_order(3, 2)
+    out = stencil_iterate(u, offs, w, 1)
+    ref = stencil_ref(u, offs, w)
     np.testing.assert_allclose(out, ref, atol=1e-4, rtol=1e-4)
 
 
@@ -58,7 +59,7 @@ def test_multi_rhs_budget_split():
     u2 = jax.random.normal(jax.random.PRNGKey(1), (24, 140), jnp.float32)
     o1, o2 = star_stencil(2, 1), star_stencil(2, 2)
     w1, w2 = [0.3] * len(o1), [0.1] * len(o2)
-    out = apply_multi_rhs([u1, u2], [o1, o2], [w1, w2])
+    out = run(ir.rhs_program([o1, o2], [w1, w2], d=2), [u1, u2])
     ref = stencil_ref(u1, o1, w1) + stencil_ref(u2, o2, w2)
     np.testing.assert_allclose(out, ref, atol=1e-5)
 
@@ -67,7 +68,7 @@ def test_explicit_tile_override():
     u = jax.random.normal(KEY, (32, 256), jnp.float32)
     offs = star_stencil(2, 1)
     w = [1.0, 0.25, 0.25, 0.25, 0.25]
-    out = apply_stencil(u, offs, w, tile=(8, 128))
+    out = stencil_iterate(u, offs, w, 1, tile=(8, 128))
     np.testing.assert_allclose(out, stencil_ref(u, offs, w), atol=1e-5)
 
 

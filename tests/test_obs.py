@@ -1,9 +1,9 @@
 """Telemetry subsystem (DESIGN.md §12): recorder, trace export, report
 reconciliation, instrumentation of plan/cache/tune/launch layers, the
 near-zero disabled path, and the obs-adjacent satellites (cache stats(),
-interpret-fallback counting, explain --json, bench_history)."""
+interpret-fallback counting, explain --json), and that recording changes
+no result."""
 
-import importlib.util
 import json
 import os
 import subprocess
@@ -181,18 +181,17 @@ def test_profiler_session_is_detected(tmp_path):
 
 def _stencil_calls(kind):
     """Two spellings of one warm call of a public entry: ``stencil_iterate``
-    on one device, or a periodic 27-point ``ir.run_program`` over a
+    on one device, or a periodic 27-point ``repro.entry.run`` over a
     4-device mesh.  The second spelling (offsets as int32, or an equal new
     program) is a second key of the entry's memo that resolves to the
     same plan and launches."""
     import jax
     import jax.numpy as jnp
 
-    from repro import ir
-    from repro.kernels import stencil as kstencil
-    from repro.kernels.stencil import stencil_iterate
+    from repro import entry, ir
+    from repro.entry import run, stencil_iterate
 
-    kstencil._memo.clear()  # the second key misses whatever ran before
+    entry._memo.clear()  # the second key misses whatever ran before
     if kind == "stencil_iterate":
         offs = star_stencil(3, 1)
         w = [1.0 / len(offs)] * len(offs)
@@ -212,8 +211,8 @@ def _stencil_calls(kind):
     mesh = make_column_mesh(4)
     u = jax.device_put(jnp.ones((32, 16, 128), jnp.float32),
                        NamedSharding(mesh, P("columns")))
-    return (lambda: ir.run_program(prog, u, num_shards=4, shard_axis=0),
-            lambda: ir.run_program(prog2, u, num_shards=4, shard_axis=0))
+    return (lambda: run(prog, u, num_shards=4, shard_axis=0),
+            lambda: run(prog2, u, num_shards=4, shard_axis=0))
 
 
 CALL_SPANS = ("stencil_call", "ir_lower", "plan", "kernel_launch")
@@ -389,6 +388,7 @@ def test_cache_stats_callable_and_degrade(tmp_path):
 
 
 def test_tunedb_stats_callable_and_degrade(tmp_path):
+    from repro.entry import run
     from repro.plan.tune import AutoTuner
 
     blocker = tmp_path / "not-a-dir"
@@ -399,7 +399,8 @@ def test_tunedb_stats_callable_and_degrade(tmp_path):
     tuner = AutoTuner(db=db, planner=Planner(cache=PlanCache(
         persistent=False)), k=2, reps=1, warmup=0)
     with obs.recording() as rec:
-        tuner.plan(shape=(16, 16, 128), offsets=star_stencil(3, 1),
+        tuner.plan(runner=run, shape=(16, 16, 128),
+                   offsets=star_stencil(3, 1),
                    vmem_budget=128 * 1024, aligned=True)
     assert db.degraded is True
     assert db.stats()["degraded"] is True
@@ -422,7 +423,7 @@ def test_traced_tuned_sharded_run_reconciles(tmp_path):
     import jax.numpy as jnp
 
     from repro.kernels.ref import stencil_ref
-    from repro.kernels.stencil import stencil_iterate
+    from repro.entry import stencil_iterate
     from repro.obs.report import main as report_main
     from repro.plan.tune import AutoTuner
 
@@ -438,13 +439,13 @@ def test_traced_tuned_sharded_run_reconciles(tmp_path):
         planner=Planner(cache=PlanCache(persistent=False)),
         k=2, reps=2, warmup=1,
     )
-    out = stencil_iterate(u, offs, w, 3, num_shards=4, tune=tuner,
-                          trace=trace)
+    with obs.recording(trace):
+        out = stencil_iterate(u, offs, w, 3, num_shards=4, tune=tuner)
     ref = np.asarray(u)
     for _ in range(3):
         ref = np.asarray(stencil_ref(jnp.asarray(ref), offs, w))
     np.testing.assert_allclose(np.asarray(out), ref, atol=2e-5, rtol=2e-5)
-    assert not obs.enabled(), "trace= must restore the disabled state"
+    assert not obs.enabled(), "recording must restore the disabled state"
 
     doc = validate_trace(json.load(open(trace)))
     summary = summarize(doc)
@@ -484,7 +485,7 @@ def test_report_prints_no_device_time():
 
 
 # ---------------------------------------------------------------------------
-# Satellites: explain --json, bench_history.
+# Satellites: explain --json; recording is pure.
 # ---------------------------------------------------------------------------
 
 def test_explain_json_round_trips(monkeypatch, tmp_path, capsys):
@@ -513,24 +514,32 @@ def test_explain_json_round_trips(monkeypatch, tmp_path, capsys):
     assert sum(s["chosen"] for s in scores) == 1
 
 
-def test_bench_history_verifies_chain(capsys):
-    spec = importlib.util.spec_from_file_location(
-        "bench_history", ROOT / "scripts" / "bench_history.py"
+def test_recording_changes_no_bits_and_warm_hit_stays_fast(tmp_path):
+    """Recording a call changes none of its bits, and a warm plan-cache
+    hit stays under 5 ms (median of 50) with a recorder installed."""
+    import time
+
+    import jax.numpy as jnp
+
+    from repro.entry import stencil_iterate
+
+    offs = star_stencil(3, 1)
+    w = [1.0 / len(offs)] * len(offs)
+    u = jnp.asarray(
+        np.random.default_rng(0).standard_normal((16, 32, 128)),
+        jnp.float32,
     )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    assert mod.main(["--root", str(ROOT)]) == 0
-    out = capsys.readouterr().out
-    assert "all gates hold" in out
-    # --json mode carries the same verdict machine-readably
-    assert mod.main(["--root", str(ROOT), "--json"]) == 0
-    doc = json.loads(capsys.readouterr().out)
-    assert doc["ok"] is True
-    assert max(len(r["chain"]) for r in doc["rows"]) >= 2
-    # a broken gate is detected
-    assert mod.gates_ok({"a_ok": True, "b_ok": False, "x": 1.0}) is False
-    _, problems = mod.verify_chain(
-        {"pr": 3, "acceptance": {"ok": True},
-         "pr2_thing": {"pr": 1, "acceptance": {"ok": True}}}
-    )
-    assert any("chain gap" in p for p in problems)
+    plain = stencil_iterate(u, offs, w, 3, num_shards=4)
+    planner = Planner(cache=PlanCache(persistent=False))
+    kw = dict(shape=(16, 32, 128), offsets=offs, vmem_budget=4 << 20,
+              aligned=True, time_steps=3)
+    planner.plan(**kw)
+    with obs.recording(str(tmp_path / "t.json")):
+        traced = stencil_iterate(u, offs, w, 3, num_shards=4)
+        warm = []
+        for _ in range(50):
+            t0 = time.perf_counter()
+            planner.plan(**kw)
+            warm.append((time.perf_counter() - t0) * 1e3)
+    assert np.array_equal(np.asarray(plain), np.asarray(traced))
+    assert sorted(warm)[len(warm) // 2] < 5.0, sorted(warm)

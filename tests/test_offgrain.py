@@ -25,8 +25,9 @@ import pytest
 from repro import ir, obs
 from repro.core.cache_fitting import star_stencil
 from repro.core.tiling import axis_grain, direct_input, grid_slack
+from repro.entry import run, stencil_iterate
 from repro.kernels.ref import star_weights_2nd_order, stencil_ref
-from repro.kernels.stencil import launch_pads, stencil_iterate, stencil_pallas
+from repro.kernels.stencil import launch_pads
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir))
 if ROOT not in sys.path:
@@ -50,7 +51,7 @@ def _ref(u, offs, wts, steps=1, boundary="zero"):
 
 
 def _jacobi(**kw):
-    return (lambda u: stencil_pallas(u, JACOBI, JACOBI_W, **kw),
+    return (lambda u: stencil_iterate(u, JACOBI, JACOBI_W, 1, **kw),
             lambda u: _ref(u, JACOBI, JACOBI_W))
 
 
@@ -61,7 +62,7 @@ def _star(steps=1, **kw):
 
 def _program(boundary):
     prog = ir.stencil_program(JACOBI, JACOBI_W, 1, d=2, boundary=boundary)
-    return (lambda u: ir.run_program(prog, u),
+    return (lambda u: run(prog, u),
             lambda u: _ref(u, JACOBI, JACOBI_W, boundary=boundary))
 
 
@@ -166,8 +167,8 @@ def test_offgrain_counter_reconciles():
     from repro.obs.report import reconcile, render, summarize
 
     with obs.recording() as rec:
-        stencil_pallas(_normal((60, 200)), JACOBI, JACOBI_W)
-        stencil_pallas(_normal((64, 256)), JACOBI, JACOBI_W)
+        stencil_iterate(_normal((60, 200)), JACOBI, JACOBI_W, 1)
+        stencil_iterate(_normal((64, 256)), JACOBI, JACOBI_W, 1)
         stencil_iterate(_normal((9, 11, 131)), STAR, STAR_W, 4)
     slacks = [s.args["grid_slack"] for s in rec.spans
               if s.name == "kernel_launch"]

@@ -122,16 +122,16 @@ def test_validate_reports_miss_reduction(planner):
 
 
 def test_kernel_accepts_plan(planner):
-    """stencil_pallas(plan=...) drives the sweep engine with the planned
+    """stencil_iterate(plan=...) drives the sweep engine with the planned
     tile and matches the oracle."""
+    from repro.entry import stencil_iterate
     from repro.kernels.ref import star_weights_2nd_order, stencil_ref
-    from repro.kernels.stencil import stencil_pallas
 
     offs, w = star_weights_2nd_order(3, 2)
     plan = planner.plan(shape=(16, 24, 128), offsets=offs,
                         vmem_budget=256 * 1024)
     u = jax.random.normal(jax.random.PRNGKey(0), (16, 24, 128), jnp.float32)
-    out = stencil_pallas(u, offs, w, plan=plan, interpret=True)
+    out = stencil_iterate(u, offs, w, 1, plan=plan, interpret=True)
     ref = stencil_ref(u, offs, w)
     assert float(jnp.abs(out - ref).max()) < 1e-4
 

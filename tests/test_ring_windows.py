@@ -25,8 +25,8 @@ from repro.core.tiling import (
     fused_stage_bytes,
     sublane_unit,
 )
+from repro.entry import stencil_iterate
 from repro.kernels.ref import stencil_ref
-from repro.kernels.stencil import stencil_iterate
 from repro.plan import PlanCache, Planner
 from repro.plan.schema import PlanRequest, StencilPlan, validate_plan_call
 
@@ -471,7 +471,7 @@ def test_single_step_plans_have_no_frontier(planner):
 def test_mixed_precision_plan_beats_f32_depth(planner):
     """bf16 windows double the legal lane grain: at a budget that caps
     the f32 trapezoid at depth 2, the bf16 ring chain reaches depth 4
-    (the BENCH_PR9 headline, pinned as a test).  The budget sits inside
+    (the §14 depth-uncapping headline).  The budget sits inside
     the 525,000-548,000 B window where that holds with every buffer
     charged at its DMA-grain rounded size."""
     offs = star_stencil(3, 2)
@@ -486,3 +486,44 @@ def test_mixed_precision_plan_beats_f32_depth(planner):
     assert max(d for d, _, _ in trap.depth_scores) == 2
     assert max(d for d, _, _ in ring.depth_scores) >= 4
     assert ring.fused_depth >= 4
+    # The deeper chain's modeled HBM traffic: at least a 1.5x cut.
+    assert trap.traffic_bytes / ring.traffic_bytes >= 1.5
+
+
+def test_ring_depth_table_never_shallower(planner):
+    """Same-dtype f32 T=8 star(3,2)@128³ chains across a budget sweep:
+    the ring's deepest feasible fusion never falls below the
+    trapezoid's, and goes strictly deeper at some budget.  On bare
+    (unrounded) extents a 500,000-1,790,000 B sweep gave trapezoid/ring
+    depths 3/3, 4/4, 5/6, 5/7; §16 charges every buffer at its DMA-grain
+    rounded size, so the sweep moves up to 900,000-2,400,000 B, where it
+    gives 3/3, 4/4, 4/5, 5/6."""
+    kw = dict(shape=(128, 128, 128), offsets=star_stencil(3, 2),
+              time_steps=8, n_operands=2, aligned=True)
+    rows = []
+    for budget in (900_000, 1_400_000, 2_000_000, 2_400_000):
+        trap = planner.plan(window_kind="trapezoid", vmem_budget=budget,
+                            **kw)
+        ring = planner.plan(window_kind="ring", vmem_budget=budget, **kw)
+        rows.append((max(d for d, _, _ in ring.depth_scores),
+                     max(d for d, _, _ in trap.depth_scores)))
+    assert all(r >= t for r, t in rows), rows
+    assert any(r > t for r, t in rows), rows
+
+
+def test_int8_frontiers_fuse_deeper_than_f32(planner):
+    """§15 storage priced like §14: at a budget where the all-f32 ring
+    caps the T=8 star(3,2)@128³ chain at depth 3, int8 intermediate
+    frontiers fuse depth 4, and the deeper chain cuts modeled HBM
+    traffic by at least 1.15x.  On bare extents this regime sat at
+    700,000 B; with every buffer charged at its DMA-grain rounded size
+    (§16) it spans 1,082,000-1,499,999 B, with the same tiles and
+    traffic, so the budget sits inside that window."""
+    kw = dict(shape=(128, 128, 128), offsets=star_stencil(3, 2),
+              time_steps=8, vmem_budget=1_200_000, n_operands=1,
+              pipelined=False, aligned=True, window_kind="ring")
+    f32 = planner.plan(**kw)
+    q8 = planner.plan(dtypes=["int8"] * 7 + ["float32"], **kw)
+    assert f32.fused_depth == 3
+    assert q8.fused_depth == 4
+    assert f32.traffic_bytes / q8.traffic_bytes >= 1.15

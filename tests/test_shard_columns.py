@@ -10,8 +10,8 @@ import numpy as np
 import pytest
 
 from repro.core.cache_fitting import star_stencil
+from repro.entry import stencil_iterate
 from repro.kernels.ref import stencil_ref
-from repro.kernels.stencil import stencil_iterate, stencil_pallas
 from repro.launch.mesh import make_column_mesh
 from repro.parallel.shard_columns import pick_shard_axis
 from repro.plan import PlanCache, Planner
@@ -43,9 +43,9 @@ def test_sharded_bitwise_parity_t1(shape, tile, num_shards):
     if N_DEV < num_shards:
         pytest.skip(f"needs {num_shards} devices")
     u = _u(shape)
-    base = stencil_pallas(u, OFFS, WEIGHTS, tile=tile, sweep_axis=0)
-    sh = stencil_pallas(
-        u, OFFS, WEIGHTS, tile=tile, sweep_axis=0, num_shards=num_shards,
+    base = stencil_iterate(u, OFFS, WEIGHTS, 1, tile=tile, sweep_axis=0)
+    sh = stencil_iterate(
+        u, OFFS, WEIGHTS, 1, tile=tile, sweep_axis=0, num_shards=num_shards,
     )
     assert bool(jnp.all(sh == base))
 
@@ -99,8 +99,8 @@ def test_planner_driven_sharded_launch():
         shape=u.shape, offsets=OFFS, vmem_budget=1 << 20, num_shards=2,
     )
     assert plan.num_shards == 2 and plan.shard_axis is not None
-    sh = stencil_pallas(u, OFFS, WEIGHTS, plan=plan)  # plan carries shards
-    base = stencil_pallas(u, OFFS, WEIGHTS, plan=plan, num_shards=1)
+    sh = stencil_iterate(u, OFFS, WEIGHTS, 1, plan=plan)  # plan carries shards
+    base = stencil_iterate(u, OFFS, WEIGHTS, 1, plan=plan, num_shards=1)
     assert bool(jnp.all(sh == base))
 
 
@@ -109,9 +109,11 @@ def test_explicit_mesh_matches_num_shards():
     u = _u((16, 24, 130), seed=4)
     tile = (4, 8, 64)
     mesh = make_column_mesh(2)
-    a = stencil_pallas(u, OFFS, WEIGHTS, tile=tile, sweep_axis=0, mesh=mesh)
-    b = stencil_pallas(
-        u, OFFS, WEIGHTS, tile=tile, sweep_axis=0, num_shards=2,
+    a = stencil_iterate(
+        u, OFFS, WEIGHTS, 1, tile=tile, sweep_axis=0, mesh=mesh,
+    )
+    b = stencil_iterate(
+        u, OFFS, WEIGHTS, 1, tile=tile, sweep_axis=0, num_shards=2,
     )
     assert bool(jnp.all(a == b))
 
@@ -124,9 +126,9 @@ def test_more_shards_than_columns():
     tile = (4, 16, 64)  # 2 columns on axis 1 < 4 shards
     if N_DEV < 4:
         pytest.skip("needs 4 devices")
-    base = stencil_pallas(u, OFFS, WEIGHTS, tile=tile, sweep_axis=0)
-    sh = stencil_pallas(
-        u, OFFS, WEIGHTS, tile=tile, sweep_axis=0, num_shards=4,
+    base = stencil_iterate(u, OFFS, WEIGHTS, 1, tile=tile, sweep_axis=0)
+    sh = stencil_iterate(
+        u, OFFS, WEIGHTS, 1, tile=tile, sweep_axis=0, num_shards=4,
         shard_axis=1,
     )
     assert bool(jnp.all(sh == base))
@@ -136,9 +138,9 @@ def test_one_shard_is_the_single_device_path():
     """num_shards=1 never touches shard_map (no mesh, no devices needed)."""
     u = _u((16, 24, 130), seed=6)
     tile = (4, 8, 64)
-    a = stencil_pallas(u, OFFS, WEIGHTS, tile=tile, sweep_axis=0)
-    b = stencil_pallas(
-        u, OFFS, WEIGHTS, tile=tile, sweep_axis=0, num_shards=1,
+    a = stencil_iterate(u, OFFS, WEIGHTS, 1, tile=tile, sweep_axis=0)
+    b = stencil_iterate(
+        u, OFFS, WEIGHTS, 1, tile=tile, sweep_axis=0, num_shards=1,
     )
     assert bool(jnp.all(a == b))
 
@@ -149,13 +151,13 @@ def test_explicit_axis_pin_survives_planner_collision():
     when the planner's independent choice of the other axis collides —
     the explicit pin wins and the free axis is re-derived."""
     u = _u((64, 24, 16), seed=8)
-    base = stencil_pallas(u, OFFS, WEIGHTS, vmem_budget=1 << 20)
-    pinned_shard = stencil_pallas(
-        u, OFFS, WEIGHTS, vmem_budget=1 << 20, num_shards=2, shard_axis=1,
+    base = stencil_iterate(u, OFFS, WEIGHTS, 1, vmem_budget=1 << 20)
+    pinned_shard = stencil_iterate(
+        u, OFFS, WEIGHTS, 1, vmem_budget=1 << 20, num_shards=2, shard_axis=1,
     )
     assert bool(jnp.allclose(pinned_shard, base, atol=1e-5))
-    pinned_sweep = stencil_pallas(
-        u, OFFS, WEIGHTS, vmem_budget=1 << 20, num_shards=2, sweep_axis=0,
+    pinned_sweep = stencil_iterate(
+        u, OFFS, WEIGHTS, 1, vmem_budget=1 << 20, num_shards=2, sweep_axis=0,
     )
     assert bool(jnp.allclose(pinned_sweep, base, atol=1e-5))
 
@@ -205,13 +207,13 @@ def test_mesh_axis_name_shares_cache_key():
 def test_shard_axis_validation():
     u = _u((16, 24, 130), seed=7)
     with pytest.raises(ValueError, match="sweep axis"):
-        stencil_pallas(
-            u, OFFS, WEIGHTS, tile=(4, 8, 64), sweep_axis=1, shard_axis=1,
+        stencil_iterate(
+            u, OFFS, WEIGHTS, 1, tile=(4, 8, 64), sweep_axis=1, shard_axis=1,
             num_shards=2,
         )
     with pytest.raises(ValueError, match="out of range"):
-        stencil_pallas(
-            u, OFFS, WEIGHTS, tile=(4, 8, 64), sweep_axis=0, shard_axis=5,
+        stencil_iterate(
+            u, OFFS, WEIGHTS, 1, tile=(4, 8, 64), sweep_axis=0, shard_axis=5,
             num_shards=2,
         )
 
@@ -220,7 +222,7 @@ def test_1d_grid_cannot_shard():
     u = jnp.ones(128)
     offs = np.array([[-1], [0], [1]])
     with pytest.raises(ValueError, match="cross axis"):
-        stencil_pallas(u, offs, [1.0, 1.0, 1.0], num_shards=2)
+        stencil_iterate(u, offs, [1.0, 1.0, 1.0], 1, num_shards=2)
 
 
 def test_pick_shard_axis_prefers_most_columns():
@@ -249,3 +251,23 @@ def test_plan_v4_shard_fields():
     # Round trip with the shard fields intact.
     again = type(p4).from_json(p4.to_json())
     assert again == p4
+
+
+def test_modeled_parallel_efficiency_at_eight_shards():
+    """The planner's shard scoring for the 13-point star at 256³: the
+    modeled parallel efficiency ``traffic₁ / (S · per_shard_traffic)``
+    stays >= 0.85 at 8 shards for one application, per-shard traffic
+    falls with every doubling of the shards (T ∈ {1, 3}), and a 1-shard
+    request plans exactly as an unsharded one."""
+    planner = Planner(cache=PlanCache(persistent=False))
+    for time_steps in (1, 3):
+        kw = dict(shape=(256, 256, 256), offsets=star_stencil(3, 2),
+                  vmem_budget=16 << 20, aligned=True, time_steps=time_steps)
+        base = planner.plan(**kw)
+        plans = {s: planner.plan(**kw, num_shards=s) for s in (1, 2, 4, 8)}
+        assert plans[1].to_dict() == base.to_dict()
+        per_shard = [plans[s].per_shard_traffic_bytes for s in (1, 2, 4, 8)]
+        assert all(a > b for a, b in zip(per_shard, per_shard[1:]))
+        if time_steps == 1:
+            eff8 = base.traffic_bytes / (8 * plans[8].per_shard_traffic_bytes)
+            assert eff8 >= 0.85, eff8

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import ir
 from repro.core.cache_fitting import star_stencil
 from repro.core.tiling import (
     chain_flops,
@@ -24,12 +25,9 @@ from repro.core.tiling import (
     tile_traffic_bytes,
     tile_vmem_bytes,
 )
+from repro.entry import run, stencil_iterate
 from repro.kernels.ref import stencil_ref
-from repro.kernels.stencil import (
-    multi_stencil_pallas,
-    stencil_iterate,
-    stencil_pallas,
-)
+from repro.kernels.stencil import _stencil_call
 from repro.plan import (
     PlanCache,
     PlanMismatchError,
@@ -61,7 +59,9 @@ def chain_ref(u, stages):
 
 def engine_iter(u, stages, tile, sweep_axis):
     for offs, w in stages:
-        u = stencil_pallas(u, offs, list(w), tile=tile, sweep_axis=sweep_axis)
+        u = stencil_iterate(
+            u, offs, list(w), 1, tile=tile, sweep_axis=sweep_axis,
+        )
     return u
 
 
@@ -131,9 +131,15 @@ def test_homogeneous_spellings_agree():
 
 @pytest.mark.parametrize("pipelined", [True, False])
 def test_stage_chain_pipelining_invariant(pipelined):
+    """The fused chain's launch, with and without the pipelined slab
+    fetch (the plan's choice; here the launcher's own argument)."""
     u = jax.random.normal(KEY, (40, 33), jnp.float32)
-    out = stencil_iterate(u, stages=CHAIN3, tile=(8, 16), sweep_axis=0,
-                          pipelined=pipelined)
+    specs = tuple(
+        (tuple(map(tuple, np.asarray(o).tolist())), tuple(map(float, w)))
+        for o, w in CHAIN3
+    )
+    out = _stencil_call((u,), specs[:1], (8, 16), 0, pipelined, True,
+                        stages_w=specs)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(chain_ref(u, CHAIN3)), atol=2e-5)
 
@@ -164,8 +170,8 @@ def test_stage_api_validation():
         stencil_iterate(u, stages=CHAIN3, time_steps=2)
     with pytest.raises(ValueError, match="needs"):
         stencil_iterate(u)
-    with pytest.raises(ValueError, match="single RHS"):
-        multi_stencil_pallas([u, u], None, None, tile=(8, 8), stages=CHAIN3)
+    with pytest.raises(ValueError, match="program inputs"):
+        run(ir.chain_program(CHAIN3, 2), [u, u], tile=(8, 8))
     with pytest.raises(ValueError, match="at least one"):
         stencil_iterate(u, stages=[], tile=(8, 8))
     with pytest.raises(ValueError, match="offsets but"):

@@ -12,17 +12,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import ir
 from repro.core.cache_fitting import star_stencil
 from repro.core.tiling import (
-    TARGET_VMEM_BYTES, axis_grain, kernel_vmem_bytes, select_tile,
-    surface_to_volume, tile_traffic_bytes, tile_vmem_bytes,
+    TARGET_VMEM_BYTES, axis_grain, halo_from_offsets, kernel_vmem_bytes,
+    select_tile, surface_to_volume, tile_traffic_bytes, tile_vmem_bytes,
     vmem_capacity_bytes, window_extents,
 )
-from repro.kernels.ops import apply_stencil, traffic_report
+from repro.entry import run, stencil_iterate
+from repro.kernels.ops import traffic_report
 from repro.kernels.ref import stencil_ref
-from repro.kernels.stencil import (
-    halo_from_offsets, launch_pads, multi_stencil_pallas,
-)
+from repro.kernels.stencil import _stencil_call, launch_pads
 
 KEY = jax.random.PRNGKey(0)
 
@@ -44,8 +44,8 @@ def test_sweep_axis_parity(shape, tile, axis, pipelined):
     u = jax.random.normal(KEY, shape, jnp.float32)
     offs = star_stencil(d, 2)
     w = np.linspace(-1, 1, len(offs)).tolist()
-    out = apply_stencil(u, offs, w, tile=tile, sweep_axis=axis,
-                        pipelined=pipelined)
+    spec = (tuple(map(tuple, offs.tolist())), tuple(w))
+    out = _stencil_call((u,), (spec,), tile, axis, pipelined, True)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(stencil_ref(u, offs, w)),
         atol=2e-5, rtol=2e-5,
@@ -58,7 +58,7 @@ def test_asymmetric_halo_parity():
     w = [0.1, 0.2, 0.3, 0.4, 0.5]
     u = jax.random.normal(KEY, (50, 40), jnp.float32)
     assert halo_from_offsets([offs], 2) == [(3, 0), (0, 1)]
-    out = apply_stencil(u, offs, w, tile=(8, 16), sweep_axis=0)
+    out = stencil_iterate(u, offs, w, 1, tile=(8, 16), sweep_axis=0)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(stencil_ref(u, offs, w)), atol=1e-5)
 
@@ -69,8 +69,8 @@ def test_multi_rhs_shared_sweep():
     u2 = jax.random.normal(jax.random.PRNGKey(1), (30, 70), jnp.float32)
     o1, o2 = star_stencil(2, 1), star_stencil(2, 2)
     w1, w2 = [0.3] * len(o1), [0.1] * len(o2)
-    out = multi_stencil_pallas(
-        [u1, u2], [o1, o2], [w1, w2], tile=(8, 32), sweep_axis=0)
+    out = run(ir.rhs_program([o1, o2], [w1, w2], d=2), [u1, u2],
+              tile=(8, 32), sweep_axis=0)
     ref = stencil_ref(u1, o1, w1) + stencil_ref(u2, o2, w2)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
@@ -79,7 +79,7 @@ def test_explicit_tile_not_dividing_grid():
     u = jax.random.normal(KEY, (21, 45), jnp.float32)
     offs = star_stencil(2, 1)
     w = [1.0, 0.25, 0.25, 0.25, 0.25]
-    out = apply_stencil(u, offs, w, tile=(6, 17), sweep_axis=0)
+    out = stencil_iterate(u, offs, w, 1, tile=(6, 17), sweep_axis=0)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(stencil_ref(u, offs, w)), atol=1e-5)
 

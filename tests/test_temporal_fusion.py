@@ -21,12 +21,10 @@ from repro.core.tiling import (
     tile_traffic_bytes,
     tile_vmem_bytes,
 )
+from repro import ir
+from repro.entry import run, stencil_iterate
 from repro.kernels.ref import stencil_ref
-from repro.kernels.stencil import (
-    multi_stencil_pallas,
-    stencil_iterate,
-    stencil_pallas,
-)
+from repro.kernels.stencil import _stencil_call
 from repro.plan import PlanCache, PlanMismatchError, Planner
 
 KEY = jax.random.PRNGKey(0)
@@ -74,8 +72,9 @@ def test_fused_asymmetric_halo(T, pipelined):
     offs = np.array([[-3, 0], [-2, 0], [-1, 0], [0, 0], [0, 1]])
     w = [0.1, 0.2, 0.3, -0.2, 0.25]
     u = jax.random.normal(KEY, (50, 40), jnp.float32)
-    out = stencil_iterate(u, offs, w, T, tile=(8, 16), sweep_axis=0,
-                          pipelined=pipelined)
+    spec = (tuple(map(tuple, offs.tolist())), tuple(w))
+    out = _stencil_call((u,), (spec,), (8, 16), 0, pipelined, True,
+                        stages_w=(spec,) * T)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(iterate_ref(u, offs, w, T)), atol=2e-5)
 
@@ -107,12 +106,13 @@ def test_fused_chunked_launches(planner):
     )
 
 
-def test_stencil_pallas_time_steps_equals_iterate():
+def test_time_steps_spelling_equals_program():
     offs = star_stencil(2, 1)
     w = [0.1, 0.2, 0.3, 0.4, -0.5]
     u = jax.random.normal(KEY, (30, 40), jnp.float32)
-    a = stencil_pallas(u, offs, w, tile=(8, 16), sweep_axis=0, time_steps=2)
-    b = stencil_iterate(u, offs, w, 2, tile=(8, 16), sweep_axis=0)
+    a = stencil_iterate(u, offs, w, 2, tile=(8, 16), sweep_axis=0)
+    b = run(ir.stencil_program(offs, w, 2, d=2), u, tile=(8, 16),
+            sweep_axis=0)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b))
 
 
@@ -120,9 +120,15 @@ def test_fusion_rejects_multi_rhs():
     u = jax.random.normal(KEY, (16, 16), jnp.float32)
     offs = star_stencil(2, 1)
     w = [0.1] * len(offs)
-    with pytest.raises(ValueError, match="single RHS"):
-        multi_stencil_pallas([u, u], [offs, offs], [w, w], tile=(8, 8),
-                             time_steps=2)
+    # A second application of a multi-RHS sum has no launch form.
+    rhs = ir.rhs_program([offs, offs], [w, w], d=2)
+    twice = ir.Program(d=2, ops=rhs.ops[:-1] + (
+        ir.Apply(result="v", operand="q",
+                 offsets=tuple(map(tuple, offs.tolist())), weights=tuple(w)),
+        ir.Store(operand="v"),
+    ))
+    with pytest.raises(ValueError, match="multi-RHS"):
+        run(twice, [u, u], tile=(8, 8))
     with pytest.raises(ValueError, match="time_steps"):
         stencil_iterate(u, offs, w, 0, tile=(8, 8))
 
@@ -206,6 +212,17 @@ def test_fused_never_worse_than_single_pass(planner, shape, budget, aligned, T):
     assert 1 <= plan.fused_depth <= T
 
 
+def test_cache_regime_declines_to_fuse(planner):
+    """In the paper's 16 KiB cache-fitting regime the T=3 chain of the
+    13-point star at 256³ does not pay: the planner declines to fuse, and
+    so the plan neither cuts traffic nor has recompute to stream away."""
+    plan = planner.plan(shape=(256, 256, 256), offsets=star_stencil(3, 2),
+                        vmem_budget=16 * 1024, aligned=False, time_steps=3)
+    assert plan.fused_depth == 1
+    assert plan.single_pass_traffic_bytes / plan.traffic_bytes == 1.0
+    assert plan.recompute_flops / plan.modeled_flops == 1.0
+
+
 def test_plan_traffic_prices_executed_chain(planner):
     """The remainder launch reuses the plan's one tile, so the frozen
     traffic must equal the executed chain's model — not the cheaper figure
@@ -267,7 +284,7 @@ def test_plan_mismatch_shape(planner):
     plan = planner.plan(shape=(32, 64), offsets=offs)
     u = jax.random.normal(KEY, (16, 64), jnp.float32)
     with pytest.raises(PlanMismatchError, match="shape"):
-        stencil_pallas(u, offs, w, plan=plan)
+        stencil_iterate(u, offs, w, 1, plan=plan)
 
 
 def test_plan_mismatch_offsets(planner):
@@ -277,7 +294,7 @@ def test_plan_mismatch_offsets(planner):
     u = jax.random.normal(KEY, (32, 64), jnp.float32)
     other = star_stencil(2, 2)
     with pytest.raises(PlanMismatchError, match="offsets"):
-        stencil_pallas(u, other, [0.1] * len(other), plan=plan)
+        stencil_iterate(u, other, [0.1] * len(other), 1, plan=plan)
 
 
 def test_plan_mismatch_dtype(planner):
@@ -286,7 +303,7 @@ def test_plan_mismatch_dtype(planner):
     plan = planner.plan(shape=(32, 64), offsets=offs, dtype_bytes=4)
     u = jax.random.normal(KEY, (32, 64), jnp.float32).astype(jnp.bfloat16)
     with pytest.raises(PlanMismatchError, match="dtype_bytes"):
-        stencil_pallas(u, offs, w, plan=plan)
+        stencil_iterate(u, offs, w, 1, plan=plan)
 
 
 def test_plan_mismatch_time_steps(planner):
@@ -303,7 +320,7 @@ def test_matching_plan_accepted(planner):
     w = [0.2, 0.1, -0.1, 0.3, 0.15]
     plan = planner.plan(shape=(32, 64), offsets=offs, vmem_budget=128 * 1024)
     u = jax.random.normal(KEY, (32, 64), jnp.float32)
-    out = stencil_pallas(u, offs, w, plan=plan)
+    out = stencil_iterate(u, offs, w, 1, plan=plan)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(stencil_ref(u, offs, w)), atol=1e-5)
 
@@ -335,7 +352,7 @@ def test_unsupported_backend_kernel_end_to_end(monkeypatch):
     w = [0.1, 0.2, 0.3, 0.4, -0.5]
     u = jax.random.normal(KEY, (24, 32), jnp.float32)
     with pytest.raises(_backend.UnsupportedBackendError, match="stencil"):
-        stencil_pallas(u, offs, w, tile=(8, 16), sweep_axis=0)
+        stencil_iterate(u, offs, w, 1, tile=(8, 16), sweep_axis=0)
 
 
 def test_conv1d_backend_fallback(monkeypatch):

@@ -24,8 +24,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro import ir, obs
 from repro.core.cache_fitting import star_stencil
+from repro.entry import run, stencil_iterate
 from repro.kernels.ref import star_weights_2nd_order
-from repro.kernels.stencil import stencil_iterate, stencil_pallas
 from repro.launch.mesh import make_column_mesh
 
 STAR, STAR_W = star_weights_2nd_order(3, 2)
@@ -37,7 +37,7 @@ BF16_CHAIN = ["bfloat16", "bfloat16", "bfloat16", "float32"]
 ONE_CHIP = {
     "star_512_single": (
         (512, 512, 512), jnp.float32,
-        lambda u: stencil_pallas(u, STAR, STAR_W, interpret=False),
+        lambda u: stencil_iterate(u, STAR, STAR_W, 1, interpret=False),
     ),
     "star_256_fused_T3": (
         (256, 256, 256), jnp.float32,
@@ -51,7 +51,9 @@ ONE_CHIP = {
     ),
     "jacobi_2d_16384": (
         (16384, 16384), jnp.float32,
-        lambda u: stencil_pallas(u, JACOBI_2D, JACOBI_2D_W, interpret=False),
+        lambda u: stencil_iterate(
+            u, JACOBI_2D, JACOBI_2D_W, 1, interpret=False,
+        ),
     ),
 }
 
@@ -134,8 +136,8 @@ def test_one_chip_phase_compiles(chip, case):
 DIRECT = {
     "star_512_single": (
         (1, 512, 512),
-        lambda u: stencil_pallas(
-            u, STAR, STAR_W, tile=(1, 512, 512), sweep_axis=0,
+        lambda u: stencil_iterate(
+            u, STAR, STAR_W, 1, tile=(1, 512, 512), sweep_axis=0,
             interpret=False,
         ),
     ),
@@ -198,7 +200,7 @@ def test_four_chip_periodic_program_scopes(chip):
         sharding=NamedSharding(mesh, P("columns")),
     )
     text, launches = _compile(
-        lambda u: ir.run_program(prog, u, mesh=mesh, shard_axis=0,
+        lambda u: run(prog, u, mesh=mesh, shard_axis=0,
                                  interpret=False),
         arg,
     )
@@ -220,32 +222,32 @@ POLYBENCH_W = [0.2] * 5
 OFF_GRAIN = {
     "jacobi_2d_2800": (
         (2800, 2800), 1,
-        lambda u, mesh: ir.run_program(
+        lambda u, mesh: run(
             ir.stencil_program(JACOBI_2D, POLYBENCH_W, 1, d=2), u,
             interpret=False,
         ),
     ),
     "jacobi_2d_1300": (
         (1300, 1300), 1,
-        lambda u, mesh: stencil_pallas(
-            u, JACOBI_2D, POLYBENCH_W, interpret=False
+        lambda u, mesh: stencil_iterate(
+            u, JACOBI_2D, POLYBENCH_W, 1, interpret=False,
         ),
     ),
     "star_511": (
         (511, 511, 511), 1,
-        lambda u, mesh: stencil_pallas(u, STAR, STAR_W, interpret=False),
+        lambda u, mesh: stencil_iterate(u, STAR, STAR_W, 1, interpret=False),
     ),
     "star_512x510x510_4chip": (
         (512, 510, 510), 4,
-        lambda u, mesh: stencil_pallas(
-            u, STAR, STAR_W, mesh=mesh, shard_axis=0, interpret=False
+        lambda u, mesh: stencil_iterate(
+            u, STAR, STAR_W, 1, mesh=mesh, shard_axis=0, interpret=False,
         ),
     ),
     # The planner alone would split the 200 lanes into 50-lane slabs.
     "star_16x20x200_4chip": (
         (16, 20, 200), 4,
-        lambda u, mesh: stencil_pallas(
-            u, STAR, STAR_W, mesh=mesh, shard_axis=0, interpret=False
+        lambda u, mesh: stencil_iterate(
+            u, STAR, STAR_W, 1, mesh=mesh, shard_axis=0, interpret=False,
         ),
     ),
 }
@@ -301,7 +303,7 @@ def test_unaligned_explicit_tile_refused_before_compile(chip):
     w = np.full(len(offs), 0.2).tolist()
     with pytest.raises(ValueError, match="grain on axis 1"):
         jax.jit(
-            lambda u: stencil_pallas(
-                u, offs, w, tile=(8, 64), sweep_axis=0, interpret=False
+            lambda u: stencil_iterate(
+                u, offs, w, 1, tile=(8, 64), sweep_axis=0, interpret=False,
             )
         ).lower(arg)

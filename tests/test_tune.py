@@ -1,7 +1,7 @@
 """Measured-cost autotune loop (§11): candidate enumeration invariants,
 TunedPlanDB robustness (corrupt / stale-schema / fingerprint-mismatch
 entries), sharded tuning, the planner's measured-winner preference, the
-``stencil_pallas(tune=...)`` plumb-through, and the shared timing
+``stencil_iterate(tune=...)`` plumb-through, and the shared timing
 harness."""
 
 import json
@@ -16,8 +16,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.cache_fitting import star_stencil
+from repro.entry import run, stencil_iterate
 from repro.kernels.ref import stencil_ref
-from repro.kernels.stencil import stencil_pallas
 from repro.plan import (
     TUNEDB_SCHEMA, AutoTuner, PlanCache, PlanRequest, Planner, StencilPlan,
     TunedPlanDB, TuneRecord, resolve_tuner,
@@ -53,7 +53,7 @@ def tuned():
     """One real tune pass shared by every test that only needs a record."""
     db = TunedPlanDB(persistent=False)
     tuner = _tuner(db)
-    rec = tuner.tune(_request())
+    rec = tuner.tune(_request(), runner=run)
     return db, tuner, rec
 
 
@@ -118,7 +118,7 @@ def test_planner_prefers_measured_winner_without_remeasuring(tuned):
         assert planner.last_plan_tuned
         assert served == rec.winner_plan
     assert db.stats["misses"] == misses_before, "warm hit re-measured"
-    # The <1ms contract is gated tightly in the tune smoke + BENCH_PR6;
+    # The <1ms contract is gated tightly in the tune smoke;
     # here a loose bound guards against a re-tune hiding in the hit path.
     assert min(warm) < 0.05
 
@@ -135,10 +135,10 @@ def test_planner_miss_falls_back_to_analytic_unchanged():
 
 def test_autotuner_plan_warm_vs_fresh(tuned):
     db, tuner, rec = tuned
-    assert tuner.plan(_request()) == rec.winner_plan
+    assert tuner.plan(_request(), runner=run) == rec.winner_plan
     assert tuner.last_plan_tuned        # served from the DB, not re-raced
     force = _tuner(db, force=True)
-    assert force.plan(_request()) is not None
+    assert force.plan(_request(), runner=run) is not None
     assert not force.last_plan_tuned    # force=True re-measures
 
 
@@ -172,7 +172,7 @@ def test_corrupt_entry_dropped_and_retuned(tmp_path, tuned):
     assert not os.path.exists(path)          # poisoned entry dropped
     # ... and the autotuner heals it with a fresh measurement.
     tuner = _tuner(cold)
-    assert tuner.plan(_request()) is not None
+    assert tuner.plan(_request(), runner=run) is not None
     assert not tuner.last_plan_tuned         # tuned fresh, not served stale
     assert cold.get(rec.key, rec.fingerprint) is not None
 
@@ -259,7 +259,7 @@ def tuned_chain():
     advisory bf16/int8 storage variants (DESIGN.md §15)."""
     db = TunedPlanDB(persistent=False)
     tuner = _tuner(db)
-    rec = tuner.tune(_chain_request())
+    rec = tuner.tune(_chain_request(), runner=run)
     return db, tuner, rec
 
 
@@ -315,7 +315,7 @@ def test_v1_stale_entry_dropped_and_retuned(tmp_path, tuned_chain):
     assert cold.stats["stale_schema"] == 1
     assert not os.path.exists(path)
     tuner = _tuner(cold)
-    assert tuner.plan(_chain_request()) is not None
+    assert tuner.plan(_chain_request(), runner=run) is not None
     assert not tuner.last_plan_tuned     # healed by a fresh measurement
     healed = cold.get(rec.key, rec.fingerprint)
     assert healed is not None and healed.schema == TUNEDB_SCHEMA
@@ -351,7 +351,7 @@ def test_pinned_window_kind_skips_the_flip():
     """A request that already pins ring/trapezoid races no flip — the
     user's choice is part of the planning problem, not a knob."""
     db = TunedPlanDB(persistent=False)
-    rec = _tuner(db).tune(_chain_request(window_kind="ring"))
+    rec = _tuner(db).tune(_chain_request(window_kind="ring"), runner=run)
     assert all(c.window_kind == "ring" for c in rec.candidates)
 
 
@@ -361,7 +361,7 @@ def test_dtyped_request_races_no_dtype_variants():
     db = TunedPlanDB(persistent=False)
     rec = _tuner(db).tune(_chain_request(
         dtypes=["bfloat16", "bfloat16", "float32"],
-    ))
+    ), runner=run)
     assert all(not c.advisory for c in rec.candidates)
     # The final "float32" restates the input dtype: None-normalized.
     assert all(
@@ -378,7 +378,7 @@ def test_sharded_request_tunes_sharded_launch():
     if len(jax.devices()) < 2:
         pytest.skip("needs a multi-device mesh")
     tuner = _tuner()
-    rec = tuner.tune(_request(num_shards=2))
+    rec = tuner.tune(_request(num_shards=2), runner=run)
     assert rec.never_slower
     assert rec.winner_plan.num_shards == 2
     assert all(c.shard_axis is not None for c in rec.candidates)
@@ -389,20 +389,20 @@ def test_sharded_request_tunes_sharded_launch():
     )
 
 
-# -- kernel plumb-through ----------------------------------------------------
+# -- entry plumb-through -----------------------------------------------------
 
 
-def test_stencil_pallas_tune_parity_and_warm_reuse():
+def test_entry_tune_parity_and_warm_reuse():
     u = jax.random.normal(jax.random.PRNGKey(0), (16, 16, 128), jnp.float32)
     offs = star_stencil(3, 1)
     w = [1.0 / len(offs)] * len(offs)
     tuner = _tuner()
-    out = stencil_pallas(u, offs, w, vmem_budget=256 * 1024, tune=tuner)
+    out = stencil_iterate(u, offs, w, 1, vmem_budget=256 * 1024, tune=tuner)
     assert not tuner.last_plan_tuned         # first call measured fresh
     ref = stencil_ref(u, offs, w)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
-    again = stencil_pallas(u, offs, w, vmem_budget=256 * 1024, tune=tuner)
+    again = stencil_iterate(u, offs, w, 1, vmem_budget=256 * 1024, tune=tuner)
     assert tuner.last_plan_tuned             # second call: warm DB hit
     np.testing.assert_array_equal(np.asarray(out), np.asarray(again))
 
@@ -413,10 +413,10 @@ def test_tune_mutually_exclusive_with_pinned_decisions():
     w = [1.0 / len(offs)] * len(offs)
     tuner = _tuner()
     with pytest.raises(ValueError, match="tune="):
-        stencil_pallas(u, offs, w, tile=(8, 16, 128), tune=tuner)
+        stencil_iterate(u, offs, w, 1, tile=(8, 16, 128), tune=tuner)
     plan = Planner(cache=PlanCache(persistent=False)).plan(_request())
     with pytest.raises(ValueError, match="tune="):
-        stencil_pallas(u, offs, w, plan=plan, tune=tuner)
+        stencil_iterate(u, offs, w, 1, plan=plan, tune=tuner)
 
 
 def test_resolve_tuner():
